@@ -64,8 +64,8 @@ def _make_case(b, hkv, rep, d, page, pages_per_slot, ctx, seed=0,
     pool = 1 + b * pages_per_slot
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (b, 1, hq, d), dtype)
-    kp = jax.random.normal(ks[1], (pool, page, hkv, d), dtype)
-    vp = jax.random.normal(ks[2], (pool, page, hkv, d), dtype)
+    kp = jax.random.normal(ks[1], (pool, hkv, page, d), dtype)
+    vp = jax.random.normal(ks[2], (pool, hkv, page, d), dtype)
     host = np.random.default_rng(seed)
     perm = host.permutation(np.arange(1, pool))
     table = perm[:b * pages_per_slot].reshape(b, pages_per_slot)
@@ -83,8 +83,8 @@ def _make_prefill_case(b, hkv, rep, d, page, pages_per_slot, c0, chunk,
     pool = 1 + b * pages_per_slot
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (b, chunk, hq, d), dtype)
-    kp = jax.random.normal(ks[1], (pool, page, hkv, d), dtype)
-    vp = jax.random.normal(ks[2], (pool, page, hkv, d), dtype)
+    kp = jax.random.normal(ks[1], (pool, hkv, page, d), dtype)
+    vp = jax.random.normal(ks[2], (pool, hkv, page, d), dtype)
     host = np.random.default_rng(seed)
     perm = host.permutation(np.arange(1, pool))
     table = perm[:b * pages_per_slot].reshape(b, pages_per_slot)

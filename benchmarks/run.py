@@ -112,6 +112,8 @@ def write_summary(smoke: bool, path: str = "BENCH_summary.json") -> int:
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of benchmark names")

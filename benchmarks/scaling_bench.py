@@ -36,7 +36,8 @@ def _worker(n_data: int, steps: int, batch: int, seq: int) -> None:
 
     from repro.config import RLConfig, TrainConfig, ModelConfig, ATTN, MLP
     from repro.models import init_params
-    from repro.parallel import ExecutionPlan, make_sharded_train_step
+    from repro.parallel import (ExecutionPlan, make_mesh,
+                                make_sharded_train_step)
     from repro.training import init_state
 
     cfg = ModelConfig(name="scaling-lm", family="dense", num_layers=2,
@@ -46,7 +47,7 @@ def _worker(n_data: int, steps: int, batch: int, seq: int) -> None:
                       attn_impl="naive", remat=False, rope_theta=1e4)
     rl = RLConfig(loss_type="gepo", group_size=4, beta_kl=0.0)
     tc = TrainConfig(learning_rate=1e-3, total_steps=steps + 1)
-    mesh = jax.make_mesh((n_data, 1), ("data", "model"))
+    mesh = make_mesh((n_data, 1), ("data", "model"))
     plan = ExecutionPlan(mesh=mesh, mode="train")
 
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
@@ -80,8 +81,10 @@ def run(sizes=None, steps=None, batch=None, seq=None) -> List[str]:
     seq = seq or 17
     rows = ["table,setting,tokens_per_s,step_ms"]
     for d in sizes:
+        # a virtual-device rehearsal on the CPU: never a second process
+        # onto an accelerator the parent may hold
         env = dict(
-            os.environ,
+            os.environ, JAX_PLATFORMS="cpu",
             XLA_FLAGS=f"--xla_force_host_platform_device_count={d}",
             PYTHONPATH=os.pathsep.join(
                 [p for p in (os.environ.get("PYTHONPATH"),) if p]

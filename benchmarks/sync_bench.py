@@ -138,7 +138,7 @@ def _mesh_rows() -> List[str]:
     from repro.data import ArithmeticTask, PromptPipeline, Tokenizer
     from repro.hetero.nodes import LearnerNode, SamplerNode
     from repro.models import init_params
-    from repro.parallel import ExecutionPlan, make_debug_mesh
+    from repro.parallel import ExecutionPlan, make_debug_mesh, make_mesh
     from repro.training import init_state
     from repro.transport import ChunkSubscriber, Manifest
 
@@ -151,8 +151,7 @@ def _mesh_rows() -> List[str]:
     tok = Tokenizer()
 
     learner_plan = ExecutionPlan(mesh=make_debug_mesh(2, 2), mode="train")
-    sampler_plan = ExecutionPlan(mesh=jax.make_mesh((1, 2),
-                                                    ("data", "model")),
+    sampler_plan = ExecutionPlan(mesh=make_mesh((1, 2), ("data", "model")),
                                  mode="serve")
     state = init_state(cfg, tc, init_params(cfg, jax.random.PRNGKey(0)))
     store = PolicyStore()
@@ -194,8 +193,7 @@ def _mesh_rows() -> List[str]:
 
     # elastic re-fit: the same version lands on a *changed* plan from the
     # local cache (no new chunk bytes), byte-identical again
-    refit_plan = ExecutionPlan(mesh=jax.make_mesh((2, 1),
-                                                  ("data", "model")),
+    refit_plan = ExecutionPlan(mesh=make_mesh((2, 1), ("data", "model")),
                                mode="serve")
     before = sampler.subscriber.chunks_fetched
     sampler.sync(plan=refit_plan)
@@ -229,8 +227,10 @@ def run() -> List[str]:
 
 def _mesh_rows_subprocess() -> List[str]:
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    # a virtual-device rehearsal on the CPU: never a second process onto
+    # an accelerator the parent may hold
     env = dict(
-        os.environ,
+        os.environ, JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
         PYTHONPATH=os.pathsep.join(
             [p for p in (os.environ.get("PYTHONPATH"),) if p]

@@ -28,7 +28,8 @@ SCRIPT = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.launch.roofline import parse_collectives_loop_aware
 
-    mesh = jax.make_mesh((8,), ("data",))
+    from repro.parallel import make_mesh
+    mesh = make_mesh((8,), ("data",))
     B, G = 256, 8
     sh = NamedSharding(mesh, P("data"))
 
@@ -59,7 +60,10 @@ SCRIPT = textwrap.dedent("""
 
 
 def run() -> list:
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # a virtual-device lowering on the CPU, never a second process onto
+    # an accelerator the parent may hold
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     res = subprocess.run([sys.executable, "-c", SCRIPT],
                          capture_output=True, text=True, env=env,

@@ -45,6 +45,12 @@ def smoke(name: str, **over) -> ModelConfig:
     return smoke_variant(get_config(name), **over)
 
 
+def config_for(name: str, full_width: bool = False) -> ModelConfig:
+    """What the launchers run: the published config with ``full_width``,
+    its smoke-sized variant otherwise."""
+    return get_config(name) if full_width else smoke(name)
+
+
 def supports_shape(name: str, shape_name: str) -> bool:
     cfg = get_config(name)
     if shape_name == "long_500k":

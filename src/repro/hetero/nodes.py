@@ -32,7 +32,7 @@ from repro.parallel import ExecutionPlan, plan_from_flag
 from repro.sampling import (ContinuousEngine, build_engine,
                             rollout_from_results, token_logps)
 from repro.serving.api import Request, SamplingParams
-from repro.training import TrainState, jit_train_step
+from repro.training import TrainState, jit_train_step, optimizer_of
 from repro.transport import ChunkSubscriber, SimulatedLink, publish_params
 
 
@@ -372,16 +372,20 @@ class LearnerNode:
         # learner execution plan (defaults to the TrainConfig.mesh knob).
         # The sharded step donates the TrainState, so the node takes a
         # plan-placed *copy*: the caller's state (often a warm start
-        # shared across runs) stays alive.
+        # shared across runs) stays alive. The optimizer is the one the
+        # state was built for.
         self.plan = plan or plan_from_flag(tc.mesh, "train")
-        self.state = self.plan.device_put_state(cfg, state, "adamw",
+        optimizer = optimizer_of(state)
+        self.state = self.plan.device_put_state(cfg, state, optimizer,
                                                 copy=True)
         self.store = store
-        self.step_fn = jit_train_step(cfg, rl, tc, plan=self.plan)
+        self.step_fn = jit_train_step(cfg, rl, tc, optimizer=optimizer,
+                                      plan=self.plan)
         self.buffer: List[Tuple[float, RolloutBatch]] = []
         self.step = 0
         self.discarded = 0
         self.history = MetricsHistory()
+        self.batch_shape: Optional[Tuple[int, int]] = None  # last tokens
         # cumulative publish telemetry (net-new bytes/chunks streamed)
         self.bytes_streamed = 0
         self.chunks_streamed = 0
@@ -423,9 +427,15 @@ class LearnerNode:
                 "mask": jnp.asarray(batch.mask),
                 "sampler_lp": jnp.asarray(batch.sampler_lp),
                 "rewards": jnp.asarray(batch.rewards)})
+            self.batch_shape = tuple(batch.tokens.shape)
+            t0 = time.perf_counter()
             self.state, metrics = self.step_fn(self.state, jb)
-            self.step += 1
+            jax.block_until_ready(self.state)
             out = {k: float(v) for k, v in metrics.items()}
+            # host clock around the finished step (compile included on
+            # a new batch shape)
+            out["step_s"] = time.perf_counter() - t0
+            self.step += 1
         out["staleness"] = float(self.step - 1 - batch.version)
         out["buffer_len"] = float(len(self.buffer))
         self.history.append(self.step, out)

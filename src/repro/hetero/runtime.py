@@ -157,7 +157,9 @@ def run_online(cfg: ModelConfig, rl: RLConfig, tc: TrainConfig,
     hcfg = HeteroConfig(num_samplers=1, max_delay_steps=0,
                         delay_distribution="constant", delay_min_s=0.0,
                         delay_median_s=0.0, seed=seed)
-    store = PolicyStore()
+    # the sampler reads the learner's params directly, never through the
+    # store: it holds only the newest published version
+    store = PolicyStore(keep=1)
     learner = LearnerNode(cfg, rl, tc, hcfg, state, store,
                           plan=learner_plan)
     pipeline = PromptPipeline(task, tok, prompts_per_batch, rl.group_size)
@@ -166,11 +168,15 @@ def run_online(cfg: ModelConfig, rl: RLConfig, tc: TrainConfig,
                           logprob_impl=tc.logprob_impl, plan=sampler_plan)
     eval_scores: List[float] = []
     for step in range(num_steps):
-        # strict synchrony: re-placed from the learner every step (the
-        # learner's sharded step donates the previous buffers right after)
-        sampler.params = sampler.plan.device_put_params(
-            cfg, learner.state.params)
-        sampler.version = learner.step
+        # strict synchrony: the node and its engine serve the learner's
+        # current params. The learner's step donates them right after,
+        # so they are pushed anew before every generation and never read
+        # once donated.
+        with sampler._lock:
+            sampler.params = sampler.plan.device_put_params(
+                cfg, learner.state.params)
+            sampler.version = learner.step
+            sampler._push_params_locked()
         batch = sampler.generate_batch(float(step))
         learner.receive(float(step), batch)
         b = learner.pop_eligible(float(step))

@@ -63,19 +63,19 @@ def _fwd_kernel(logits_ref, tgt_ref, logp_ref, ent_ref, lse_ref,
         tacc_scr[...] = jnp.zeros_like(tacc_scr)
 
     x = logits_ref[...].astype(jnp.float32)              # (bt, bv)
-    tgt = tgt_ref[...]                                   # (bt,)
+    tgt = tgt_ref[...]                                   # (bt, 1)
 
     m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, x.max(axis=1))
+    m_new = jnp.maximum(m_prev, x.max(axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(x - m_new[:, None])
-    l_scr[...] = l_scr[...] * alpha + p.sum(axis=1)
-    s1_scr[...] = s1_scr[...] * alpha + (p * x).sum(axis=1)
+    p = jnp.exp(x - m_new)
+    l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
+    s1_scr[...] = s1_scr[...] * alpha + (p * x).sum(axis=1, keepdims=True)
     m_scr[...] = m_new
 
     cols = iv * bv + jax.lax.broadcasted_iota(jnp.int32, (bt, bv), 1)
-    hit = cols == tgt[:, None]
-    tacc_scr[...] += jnp.where(hit, x, 0.0).sum(axis=1)
+    hit = cols == tgt
+    tacc_scr[...] += jnp.where(hit, x, 0.0).sum(axis=1, keepdims=True)
 
     @pl.when(iv == nv - 1)
     def _finish():
@@ -88,30 +88,32 @@ def _fwd_kernel(logits_ref, tgt_ref, logp_ref, ent_ref, lse_ref,
         lse_ref[...] = lse.astype(lse_ref.dtype)
 
 
+# Per-token operands travel as (T, 1) columns: a (bt, 1) block is tiled
+# like the (bt, bv) logits tile's rows, where a rank-1 (bt,) block would
+# have to match XLA's 1024-element tiling of 1-D arrays on TPU.
+
+
+def _col(bt: int) -> pl.BlockSpec:
+    return pl.BlockSpec((bt, 1), lambda it, iv: (it, 0))
+
+
 def _pallas_fwd(logits, targets, block_t, block_v, interpret):
+    """logits (T, V), targets (T, 1) -> (logp, ent, lse), each (T, 1)."""
     t, v = logits.shape
     bt = min(block_t, t)
     bv = min(block_v, v)
     assert t % bt == 0 and v % bv == 0, (t, v, bt, bv)
     nt, nv = t // bt, v // bv
-
+    col = jax.ShapeDtypeStruct((t, 1), jnp.float32)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, bt=bt, bv=bv, nv=nv),
         grid=(nt, nv),
-        in_specs=[
-            pl.BlockSpec((bt, bv), lambda it, iv: (it, iv)),
-            pl.BlockSpec((bt,), lambda it, iv: (it,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bt,), lambda it, iv: (it,)),
-            pl.BlockSpec((bt,), lambda it, iv: (it,)),
-            pl.BlockSpec((bt,), lambda it, iv: (it,)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((t,), jnp.float32),
-                   jax.ShapeDtypeStruct((t,), jnp.float32),
-                   jax.ShapeDtypeStruct((t,), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((bt,), jnp.float32)] * 4,
+        in_specs=[pl.BlockSpec((bt, bv), lambda it, iv: (it, iv)), _col(bt)],
+        out_specs=[_col(bt)] * 3,
+        out_shape=[col] * 3,
+        scratch_shapes=[pltpu.VMEM((bt, 1), jnp.float32)] * 4,
         interpret=interpret,
+        name="fused_logprob_fwd",
     )(logits, targets)
 
 
@@ -124,50 +126,52 @@ def _bwd_kernel(logits_ref, tgt_ref, lse_ref, mu_ref, glp_ref, gent_ref,
                 dlogits_ref, *, bt: int, bv: int):
     iv = pl.program_id(1)
     x = logits_ref[...].astype(jnp.float32)              # (bt, bv)
-    p = jnp.exp(x - lse_ref[...][:, None])
+    p = jnp.exp(x - lse_ref[...])                        # columns (bt, 1)
     cols = iv * bv + jax.lax.broadcasted_iota(jnp.int32, (bt, bv), 1)
-    hit = (cols == tgt_ref[...][:, None]).astype(jnp.float32)
-    d = (glp_ref[...][:, None] * (hit - p)
-         - gent_ref[...][:, None] * p * (x - mu_ref[...][:, None]))
+    hit = (cols == tgt_ref[...]).astype(jnp.float32)
+    d = (glp_ref[...] * (hit - p)
+         - gent_ref[...] * p * (x - mu_ref[...]))
     dlogits_ref[...] = d.astype(dlogits_ref.dtype)
 
 
 def _pallas_bwd(logits, targets, lse, mu, g_lp, g_ent, block_t, block_v,
                 interpret):
+    """Every per-token operand is a (T, 1) column."""
     t, v = logits.shape
     bt = min(block_t, t)
     bv = min(block_v, v)
     nt, nv = t // bt, v // bv
-    vec = pl.BlockSpec((bt,), lambda it, iv: (it,))
     return pl.pallas_call(
         functools.partial(_bwd_kernel, bt=bt, bv=bv),
         grid=(nt, nv),
-        in_specs=[pl.BlockSpec((bt, bv), lambda it, iv: (it, iv)),
-                  vec, vec, vec, vec, vec],
+        in_specs=[pl.BlockSpec((bt, bv), lambda it, iv: (it, iv))]
+        + [_col(bt)] * 5,
         out_specs=pl.BlockSpec((bt, bv), lambda it, iv: (it, iv)),
         out_shape=jax.ShapeDtypeStruct((t, v), logits.dtype),
         interpret=interpret,
+        name="fused_logprob_bwd",
     )(logits, targets, lse, mu, g_lp, g_ent)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
 def _fused_logprob_vjp(logits, targets, block_t, block_v, interpret):
-    logp, ent, _ = _pallas_fwd(logits, targets, block_t, block_v, interpret)
-    return logp, ent
+    logp, ent, _ = _pallas_fwd(logits, targets[:, None], block_t, block_v,
+                               interpret)
+    return logp[:, 0], ent[:, 0]
 
 
 def _fused_fwd_rule(logits, targets, block_t, block_v, interpret):
-    logp, ent, lse = _pallas_fwd(logits, targets, block_t, block_v,
-                                 interpret)
+    logp, ent, lse = _pallas_fwd(logits, targets[:, None], block_t,
+                                 block_v, interpret)
     # O(T) residuals only: μ = E_p[x] = lse − H
-    return (logp, ent), (logits, targets, lse, lse - ent)
+    return (logp[:, 0], ent[:, 0]), (logits, targets, lse, lse - ent)
 
 
 def _fused_bwd_rule(block_t, block_v, interpret, res, cots):
     logits, targets, lse, mu = res
     g_lp, g_ent = cots
-    dlogits = _pallas_bwd(logits, targets, lse, mu, g_lp, g_ent,
-                          block_t, block_v, interpret)
+    dlogits = _pallas_bwd(logits, targets[:, None], lse, mu, g_lp[:, None],
+                          g_ent[:, None], block_t, block_v, interpret)
     return dlogits, np.zeros(targets.shape, jax.dtypes.float0)
 
 

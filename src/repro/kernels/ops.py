@@ -1,13 +1,14 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode for
-correctness validation; on a real TPU ``interpret=False`` compiles via
-Mosaic. ``use_pallas()`` gates which backend the model layer picks.
+On a TPU the kernels compile via Mosaic; elsewhere they execute in
+interpret mode for correctness validation. ``on_tpu()`` decides which
+backend the "auto" dispatchers pick.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import warnings
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +44,18 @@ def flash_attention(q, k, v, *, causal: bool = True,
 PAGED_IMPLS = ("auto", "pallas", "ref", "gather")
 
 
+def _logical_view(kp, vp, page_table):
+    """Each slot's dense (B, npages·page_size, Hkv, D) k/v view, gathered
+    from (num_pages, Hkv, page_size, D) pools through its block table."""
+    b, npages = page_table.shape
+    hkv, page_size, d = kp.shape[1:]
+
+    def view(pool):
+        return (pool[page_table].transpose(0, 1, 3, 2, 4)
+                .reshape(b, npages * page_size, hkv, d))
+    return view(kp), view(vp)
+
+
 @functools.partial(jax.jit, static_argnames=("kind", "window", "softcap",
                                              "impl", "interpret"))
 def paged_decode(q, kp, vp, page_table, lengths, *, kind: str = "causal",
@@ -53,7 +66,7 @@ def paged_decode(q, kp, vp, page_table, lengths, *, kind: str = "causal",
     """Decode-step attention against paged KV pools — the serving hot loop.
 
     q (B, 1, Hq, D) one query token per slot (``decode_attention``'s
-    layout); kp/vp (num_pages, page_size, Hkv, D) page pools; page_table
+    layout); kp/vp (num_pages, Hkv, page_size, D) page pools; page_table
     (B, npages); lengths (B,) valid tokens per slot (current token's k/v
     already scattered). Returns (B, 1, Hq, D).
 
@@ -84,12 +97,7 @@ def paged_decode(q, kp, vp, page_table, lengths, *, kind: str = "causal",
     eff_window = window if kind == "local" else None
     if impl == "gather":
         from repro.models.attention import decode_attention
-        b = q.shape[0]
-        npages, page_size = page_table.shape[1], kp.shape[1]
-        lview = npages * page_size
-        kv_shape = (b, lview, kp.shape[2], kp.shape[3])
-        kc = kp[page_table].reshape(kv_shape)
-        vc = vp[page_table].reshape(kv_shape)
+        kc, vc = _logical_view(kp, vp, page_table)
         return decode_attention(q, kc, vc, pos=lengths - 1, kind=kind,
                                 window=window, softcap=softcap)
     if impl == "ref":
@@ -114,7 +122,7 @@ def paged_prefill(q, kp, vp, page_table, positions, *, kind: str = "causal",
     admission's hot loop.
 
     q (B, C, Hq, D) one C-token query chunk per slot; kp/vp
-    (num_pages, page_size, Hkv, D) page pools (the chunk's k/v already
+    (num_pages, Hkv, page_size, D) page pools (the chunk's k/v already
     scattered in); page_table (B, npages); positions (B, C) absolute
     query positions, ``starts[slot] + arange(C)`` — contiguous per slot.
     Returns (B, C, Hq, D).
@@ -143,12 +151,8 @@ def paged_prefill(q, kp, vp, page_table, positions, *, kind: str = "causal",
     eff_window = window if kind == "local" else None
     if impl == "gather":
         from repro.models.attention import attention
-        b = q.shape[0]
-        npages, page_size = page_table.shape[1], kp.shape[1]
-        lview = npages * page_size
-        kv_shape = (b, lview, kp.shape[2], kp.shape[3])
-        kc = kp[page_table].reshape(kv_shape)             # slot's logical view
-        vc = vp[page_table].reshape(kv_shape)
+        kc, vc = _logical_view(kp, vp, page_table)
+        b, lview = kc.shape[:2]
         pos_k = jnp.broadcast_to(jnp.arange(lview), (b, lview))
         # the Pallas flash kernel assumes pos_q = arange(Sq): chunked
         # prefill runs at an offset, so it drops to the jnp twin
@@ -171,7 +175,7 @@ def _fold_layers(q, kp, vp, page_table, lengths):
     """Fold a leading layer axis into the slot axis so ONE kernel launch
     serves every layer's pools.
 
-    q (L, B, ...), kp/vp (L, P, page, Hkv, D), page_table (B, W),
+    q (L, B, ...), kp/vp (L, P, Hkv, page, D), page_table (B, W),
     lengths (B,) → per-layer operands stacked along slots: the pools
     concatenate to (L·P, ...), and layer l's table rows offset by l·P so
     they index the l-th pool slab. Slots never mix across grid steps, so
@@ -200,7 +204,7 @@ def paged_decode_layers(q, kp, vp, page_table, lengths, *,
                         interpret: Optional[bool] = None):
     """``paged_decode`` over all layers' pools in ONE launch.
 
-    q (L, B, 1, Hq, D) per-layer queries; kp/vp (L, P, page, Hkv, D)
+    q (L, B, 1, Hq, D) per-layer queries; kp/vp (L, P, Hkv, page, D)
     stacked pools (the scanned-block layout of ``init_paged_cache``);
     page_table (B, W) and lengths (B,) shared by every layer. Returns
     (L, B, 1, Hq, D), bit-exact vs L separate ``paged_decode`` calls.
@@ -230,7 +234,7 @@ def paged_prefill_layers(q, kp, vp, page_table, positions, *,
                          attn_impl: str = "chunked", chunk: int = 512,
                          interpret: Optional[bool] = None):
     """``paged_prefill`` over all layers' pools in ONE launch: q
-    (L, B, C, Hq, D), kp/vp (L, P, page, Hkv, D), positions (B, C)
+    (L, B, C, Hq, D), kp/vp (L, P, Hkv, page, D), positions (B, C)
     shared across layers. Returns (L, B, C, Hq, D), bit-exact vs L
     separate calls — same layer-folding as ``paged_decode_layers``."""
     lyr, b = q.shape[0], q.shape[1]
@@ -271,6 +275,49 @@ def _largest_divisor(n: int, cap: int, mult: int) -> int:
     return 0
 
 
+LOGPROB_IMPLS = ("pallas", "chunked", "naive")
+
+
+def logprob_tiles(t: int, v: int, block_t: int = 256,
+                  block_v: int = 2048) -> Tuple[int, int]:
+    """(token tile, vocab tile) of the Pallas kernel for flat (t, v)
+    logits: the largest hardware-aligned divisors of the actual shape
+    (t = B·(S−1) and 256-aligned padded vocabs rarely divide the default
+    blocks). (0, 0) when no aligned tile divides."""
+    bt = _largest_divisor(t, block_t, 8) or (t if t < 8 else 0)
+    bv = _largest_divisor(v, block_v, 128) or (v if v < 128 else 0)
+    return (bt, bv) if bt and bv else (0, 0)
+
+
+def logprob_backend(shape: Tuple[int, ...], impl: Optional[str] = None,
+                    block_t: int = 256, block_v: int = 2048) -> str:
+    """The backend ``fused_token_logprob`` runs for logits of ``shape``:
+    "pallas", "chunked" or "naive".
+
+    ``impl`` None (auto) means Pallas on TPU and chunked elsewhere; on
+    TPU a shape the kernel cannot tile runs chunked with a warning.
+    A forced "pallas" raises for such a shape, on any backend."""
+    if impl not in LOGPROB_IMPLS + (None,):
+        raise ValueError(f"unknown logprob impl {impl!r}")
+    if impl is not None:
+        want = impl
+    else:
+        want = "pallas" if on_tpu() else "chunked"
+    if want != "pallas":
+        return want
+    t, v = int(np.prod(shape[:-1])), shape[-1]
+    if logprob_tiles(t, v, block_t, block_v) != (0, 0):
+        return "pallas"
+    msg = (f"fused log-prob: no Pallas tiling of ({t} tokens, vocab {v}) "
+           f"with token tiles of 8·k <= {block_t} and vocab tiles of "
+           f"128·k <= {block_v}")
+    if impl == "pallas":
+        raise ValueError(msg)
+    warnings.warn(msg + "; running the chunked jnp backend instead",
+                  RuntimeWarning, stacklevel=3)
+    return "chunked"
+
+
 @functools.partial(jax.jit, static_argnames=("impl", "block_t", "block_v",
                                              "chunk", "interpret"))
 def fused_token_logprob(logits, targets, *, impl: Optional[str] = None,
@@ -284,10 +331,11 @@ def fused_token_logprob(logits, targets, *, impl: Optional[str] = None,
     ``logits`` with a streaming backward (no V-sized f32 activation in
     either pass; see ``repro.kernels.fused_logprob``).
 
-    ``impl`` selects the backend:
-      - None (default): Pallas on TPU, chunked pure-JAX elsewhere;
-      - "pallas" / "chunked": forced (pallas still falls back to
-        chunked when T or V doesn't divide by the block sizes);
+    ``impl`` selects the backend (``logprob_backend`` resolves it):
+      - None (default): Pallas on TPU, chunked pure-JAX elsewhere; a
+        shape the kernel cannot tile runs chunked with a warning;
+      - "pallas" / "chunked": forced ("pallas" raises when T or V has
+        no aligned tile that divides it);
       - "naive": the materializing log-softmax reference
         (``repro.core.logprob``) — for A/B benchmarks and debugging.
 
@@ -295,40 +343,30 @@ def fused_token_logprob(logits, targets, *, impl: Optional[str] = None,
     carry any id — the padding contract of ``repro.core.logprob``).
     """
     from repro.core.logprob import token_logprob_and_entropy
-    if impl not in (None, "pallas", "chunked", "naive"):
-        raise ValueError(f"unknown logprob impl {impl!r}")
-    if impl == "naive":
-        return token_logprob_and_entropy(logits, targets)
-    if impl is None:
-        impl = "pallas" if on_tpu() else "chunked"
     if logits.ndim == 1:                       # single token, no batch dim
         lp, ent = fused_token_logprob(
             logits[None], targets.reshape((1,)), impl=impl,
             block_t=block_t, block_v=block_v, chunk=chunk,
             interpret=interpret)
         return lp.reshape(targets.shape), ent.reshape(targets.shape)
+    backend = logprob_backend(logits.shape, impl, block_t, block_v)
+    if backend == "naive":
+        return token_logprob_and_entropy(logits, targets)
     lead, v = logits.shape[:-1], logits.shape[-1]
-    if impl == "pallas":
-        # the kernel takes flat (T, V); shrink the tiles to the largest
-        # hardware-aligned divisors of the actual shape (t = B·(S−1) and
-        # 256-aligned padded vocabs rarely divide the default blocks).
-        # NOTE pallas_call has no GSPMD partitioning rules: on a
-        # multi-device mesh, call this under shard_map so the kernel
-        # sees per-device (T, V) shards — under plain GSPMD the flatten
-        # below would merge a data-sharded batch axis into the token
-        # axis and replicate the logits. The chunked branch is
-        # GSPMD-native (shard-local token-axis slices) and is what the
-        # CPU dry-run grid lowers.
-        t = int(np.prod(lead))
-        bt = _largest_divisor(t, block_t, 8) or (t if t < 8 else 0)
-        bv = _largest_divisor(v, block_v, 128) or (v if v < 128 else 0)
-        if bt and bv:
-            interp = (not on_tpu()) if interpret is None else interpret
-            lp, ent = _fused_logprob(logits.reshape((-1, v)),
-                                     targets.reshape((-1,)),
-                                     block_t=bt, block_v=bv,
-                                     interpret=interp)
-            return lp.reshape(lead), ent.reshape(lead)
+    if backend == "pallas":
+        # the kernel takes flat (T, V). NOTE pallas_call has no GSPMD
+        # partitioning rules: on a multi-device mesh, call this under
+        # shard_map so the kernel sees per-device (T, V) shards — under
+        # plain GSPMD the flatten below would merge a data-sharded batch
+        # axis into the token axis and replicate the logits. The chunked
+        # branch is GSPMD-native (shard-local token-axis slices) and is
+        # what the CPU dry-run grid lowers.
+        bt, bv = logprob_tiles(int(np.prod(lead)), v, block_t, block_v)
+        interp = (not on_tpu()) if interpret is None else interpret
+        lp, ent = _fused_logprob(logits.reshape((-1, v)),
+                                 targets.reshape((-1,)),
+                                 block_t=bt, block_v=bv, interpret=interp)
+        return lp.reshape(lead), ent.reshape(lead)
     # chunked keeps the (..., T, V) layout: the token axis is chunked in
     # place so data-sharded batch axes never get flattened into the
     # sliced axis (GSPMD would otherwise replicate the whole logits)

@@ -6,6 +6,11 @@ token per slot against that slot's KV pages *in place* — the pools from
 ``(B, pages_per_slot·page_size, Hkv, D)`` logical view (the legacy path's
 O(pool) HBM traffic per token; see ``repro.kernels.ops.paged_decode``).
 
+Pool layout: ``(num_pages, Hkv, page_size, D)``. One kv head of one page
+is then a contiguous ``(page_size, D)`` tile, the block the kernels DMA —
+tile-aligned on TPU, where a ``(page_size, 1, D)`` slice of a
+``(page_size, Hkv, D)`` page is not.
+
 Kernel layout:
 
 - grid ``(slot, kv_head, logical_page)`` with the page axis innermost so
@@ -99,9 +104,9 @@ def _kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)               # (rep, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)            # (page, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)                # (rep, D)
+        k = k_ref[...].astype(jnp.float32)                # (page, D)
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * scale                                     # (rep, page)
@@ -109,12 +114,12 @@ def _kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
             s = softcap * jnp.tanh(s / softcap)
         s, v = _mask_scores_and_values(s, v, j, page_size, length, window)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
+        m_prev = m_scr[...]                               # (rep, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_scr[...] = l_scr[...] * alpha + p.sum(axis=1)
-        acc_scr[...] = (acc_scr[...] * alpha[:, None]
+        p = jnp.exp(s - m_new)
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
+        acc_scr[...] = (acc_scr[...] * alpha
                         + jax.lax.dot_general(
                             p, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
@@ -123,7 +128,7 @@ def _kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j == npages - 1)
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def paged_attention(q: jax.Array, kp: jax.Array, vp: jax.Array,
@@ -134,13 +139,13 @@ def paged_attention(q: jax.Array, kp: jax.Array, vp: jax.Array,
     """Decode-step attention against paged KV pools, in place.
 
     q (B, Hq, D) single query token per slot; kp/vp
-    (num_pages, page_size, Hkv, D) page pools; page_table (B, npages)
+    (num_pages, Hkv, page_size, D) page pools; page_table (B, npages)
     int32 slot→physical-page map; lengths (B,) int32 valid tokens per
     slot (``pos + 1`` — the current token's k/v must already be
     scattered into the pools). Returns (B, Hq, D) in q.dtype.
     """
     b, hq, d = q.shape
-    num_pages, page_size, hkv, dk = kp.shape
+    num_pages, hkv, page_size, dk = kp.shape
     assert d == dk and hq % hkv == 0, (q.shape, kp.shape)
     rep = hq // hkv
     npages = page_table.shape[1]
@@ -157,20 +162,18 @@ def paged_attention(q: jax.Array, kp: jax.Array, vp: jax.Array,
         length = lengths_ref[s]
         last_live = jnp.maximum(pl.cdiv(length, page_size) - 1, 0)
         jj = jnp.minimum(j, last_live)
-        return (table_ref[s, jj], 0, h, 0)
+        return (table_ref[s, jj], h, 0, 0)
 
+    q_block = pl.BlockSpec((None, None, rep, d), q_map)
+    kv_block = pl.BlockSpec((None, None, page_size, d), kv_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, hkv, npages),
-        in_specs=[
-            pl.BlockSpec((1, 1, rep, d), q_map),
-            pl.BlockSpec((1, page_size, 1, d), kv_map),
-            pl.BlockSpec((1, page_size, 1, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rep, d), q_map),
+        in_specs=[q_block, kv_block, kv_block],
+        out_specs=q_block,
         scratch_shapes=[
-            pltpu.VMEM((rep,), jnp.float32),
-            pltpu.VMEM((rep,), jnp.float32),
+            pltpu.VMEM((rep, 1), jnp.float32),
+            pltpu.VMEM((rep, 1), jnp.float32),
             pltpu.VMEM((rep, d), jnp.float32),
         ],
     )
@@ -181,6 +184,7 @@ def paged_attention(q: jax.Array, kp: jax.Array, vp: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
         interpret=interpret,
+        name="paged_decode",
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), qr, kp, vp)
     return out.reshape(b, hq, d)
 
@@ -195,7 +199,7 @@ def paged_decode_ref(q: jax.Array, kp: jax.Array, vp: jax.Array,
     kernel; this is the CPU oracle and the GSPMD-native lowering path
     (the per-page gather partitions cleanly with kv-heads on 'model')."""
     b, hq, d = q.shape
-    page_size, hkv = kp.shape[1], kp.shape[2]
+    hkv, page_size = kp.shape[1], kp.shape[2]
     rep = hq // hkv
     npages = page_table.shape[1]
     scale = d ** -0.5
@@ -210,9 +214,9 @@ def paged_decode_ref(q: jax.Array, kp: jax.Array, vp: jax.Array,
     def body(j, carry):
         m_run, l_run, acc = carry
         phys = jax.lax.dynamic_slice_in_dim(table, j, 1, axis=1)[:, 0]
-        k = kp[phys]                                      # (B, page, Hkv, D)
+        k = kp[phys]                                      # (B, Hkv, page, D)
         v = vp[phys]
-        s = jnp.einsum("bgrd,bpgd->bgrp", qg, k,
+        s = jnp.einsum("bgrd,bgpd->bgrp", qg, k,
                        preferred_element_type=jnp.float32) * scale
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
@@ -223,13 +227,13 @@ def paged_decode_ref(q: jax.Array, kp: jax.Array, vp: jax.Array,
         s = jnp.where(valid[:, None, None, :], s, NEG_INF)
         # zero masked values so garbage/NaN in dead tails (scratch page
         # included) can never reach a live slot through 0 * NaN
-        v = jnp.where(valid[:, :, None, None], v, jnp.zeros((), v.dtype))
+        v = jnp.where(valid[:, None, :, None], v, jnp.zeros((), v.dtype))
         m_new = jnp.maximum(m_run, s.max(axis=-1))
         alpha = jnp.exp(m_run - m_new)
         p = jnp.exp(s - m_new[..., None])
         l_new = l_run * alpha + p.sum(axis=-1)
         acc = acc * alpha[..., None] + jnp.einsum(
-            "bgrp,bpgd->bgrd", p.astype(v.dtype), v,
+            "bgrp,bgpd->bgrd", p.astype(v.dtype), v,
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc
 
@@ -269,9 +273,9 @@ def _prefill_kernel(table_ref, lengths_ref, starts_ref, q_ref, k_ref, v_ref,
     @pl.when(live)
     def _body():
         rows = bq * rep
-        q = q_ref[0, :, 0].reshape(rows, -1).astype(jnp.float32)
-        k = k_ref[0, :, 0].astype(jnp.float32)            # (page, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)                # (rows, D)
+        k = k_ref[...].astype(jnp.float32)                # (page, D)
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * scale                                     # (rows, page)
@@ -293,12 +297,12 @@ def _prefill_kernel(table_ref, lengths_ref, starts_ref, q_ref, k_ref, v_ref,
             jnp.int32, (page_size, 1), 0)
         v = jnp.where(col_v < length, v, 0.0)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
+        m_prev = m_scr[...]                               # (rows, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_scr[...] = l_scr[...] * alpha + p.sum(axis=1)
-        acc_scr[...] = (acc_scr[...] * alpha[:, None]
+        p = jnp.exp(s - m_new)
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
+        acc_scr[...] = (acc_scr[...] * alpha
                         + jax.lax.dot_general(
                             p, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
@@ -307,8 +311,7 @@ def _prefill_kernel(table_ref, lengths_ref, starts_ref, q_ref, k_ref, v_ref,
     @pl.when(j == npages - 1)
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
-        o_ref[0, :, 0] = o.reshape(bq, rep, -1)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def paged_prefill(q: jax.Array, kp: jax.Array, vp: jax.Array,
@@ -320,7 +323,7 @@ def paged_prefill(q: jax.Array, kp: jax.Array, vp: jax.Array,
 
     q (B, C, Hq, D): a C-token query chunk per slot whose row i sits at
     absolute position ``starts[slot] + i``; kp/vp
-    (num_pages, page_size, Hkv, D) page pools with the chunk's k/v
+    (num_pages, Hkv, page_size, D) page pools with the chunk's k/v
     already scattered in; page_table (B, npages) int32; lengths (B,)
     int32 total valid tokens per slot (``starts + C`` for a full chunk);
     starts (B,) int32 chunk offsets. Returns (B, C, Hq, D) in q.dtype.
@@ -331,18 +334,21 @@ def paged_prefill(q: jax.Array, kp: jax.Array, vp: jax.Array,
     compute — bytes scale with ``pages_for(starts + C)``.
     """
     b, c, hq, d = q.shape
-    num_pages, page_size, hkv, dk = kp.shape
+    num_pages, hkv, page_size, dk = kp.shape
     assert d == dk and hq % hkv == 0, (q.shape, kp.shape)
     rep = hq // hkv
     npages = page_table.shape[1]
     from repro.kernels.flash_attention import _fit_block
     bq = _fit_block(c, block_q)
     nq = c // bq
-    qr = q.reshape(b, c, hkv, rep, d)
+    # kv-head-major rows: one grid step's (bq query tokens × rep grouped
+    # heads) are bq·rep contiguous rows of a (rows, D) tile
+    qr = (q.reshape(b, c, hkv, rep, d).transpose(0, 2, 1, 3, 4)
+          .reshape(b, hkv, c * rep, d))
 
     def q_map(s, iq, h, j, table_ref, lengths_ref, starts_ref):
         del table_ref, lengths_ref, starts_ref, j
-        return (s, iq, h, 0, 0)
+        return (s, h, iq, 0)
 
     def kv_map(s, iq, h, j, table_ref, lengths_ref, starts_ref):
         # clamp the logical page into the tile's causal/window reach:
@@ -356,20 +362,18 @@ def paged_prefill(q: jax.Array, kp: jax.Array, vp: jax.Array,
         if window is not None:
             first = jnp.clip((q0 - window + 1) // page_size, 0, last)
         jj = jnp.clip(j, first, last)
-        return (table_ref[s, jj], 0, h, 0)
+        return (table_ref[s, jj], h, 0, 0)
 
+    q_block = pl.BlockSpec((None, None, bq * rep, d), q_map)
+    kv_block = pl.BlockSpec((None, None, page_size, d), kv_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, nq, hkv, npages),
-        in_specs=[
-            pl.BlockSpec((1, bq, 1, rep, d), q_map),
-            pl.BlockSpec((1, page_size, 1, d), kv_map),
-            pl.BlockSpec((1, page_size, 1, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, bq, 1, rep, d), q_map),
+        in_specs=[q_block, kv_block, kv_block],
+        out_specs=q_block,
         scratch_shapes=[
-            pltpu.VMEM((bq * rep,), jnp.float32),
-            pltpu.VMEM((bq * rep,), jnp.float32),
+            pltpu.VMEM((bq * rep, 1), jnp.float32),
+            pltpu.VMEM((bq * rep, 1), jnp.float32),
             pltpu.VMEM((bq * rep, d), jnp.float32),
         ],
     )
@@ -378,11 +382,13 @@ def paged_prefill(q: jax.Array, kp: jax.Array, vp: jax.Array,
                           softcap=softcap, page_size=page_size,
                           npages=npages, bq=bq, rep=rep),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, c, hkv, rep, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, c * rep, d), q.dtype),
         interpret=interpret,
+        name="paged_prefill",
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       starts.astype(jnp.int32), qr, kp, vp)
-    return out.reshape(b, c, hq, d)
+    return (out.reshape(b, hkv, c, rep, d).transpose(0, 2, 1, 3, 4)
+            .reshape(b, c, hq, d))
 
 
 def paged_prefill_ref(q: jax.Array, kp: jax.Array, vp: jax.Array,
@@ -394,7 +400,7 @@ def paged_prefill_ref(q: jax.Array, kp: jax.Array, vp: jax.Array,
     batch-max live page count — no dense (B, npages·page_size, Hkv, D)
     view is ever materialized, so temp bytes scale with live pages."""
     b, c, hq, d = q.shape
-    page_size, hkv = kp.shape[1], kp.shape[2]
+    hkv, page_size = kp.shape[1], kp.shape[2]
     rep = hq // hkv
     npages = page_table.shape[1]
     scale = d ** -0.5
@@ -409,9 +415,9 @@ def paged_prefill_ref(q: jax.Array, kp: jax.Array, vp: jax.Array,
     def body(j, carry):
         m_run, l_run, acc = carry
         phys = jax.lax.dynamic_slice_in_dim(table, j, 1, axis=1)[:, 0]
-        k = kp[phys]                                      # (B, page, Hkv, D)
+        k = kp[phys]                                      # (B, Hkv, page, D)
         v = vp[phys]
-        s = jnp.einsum("bcgrd,bpgd->bgrcp", qg, k,
+        s = jnp.einsum("bcgrd,bgpd->bgrcp", qg, k,
                        preferred_element_type=jnp.float32) * scale
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
@@ -421,13 +427,13 @@ def paged_prefill_ref(q: jax.Array, kp: jax.Array, vp: jax.Array,
             ok &= idx[None, None, :] > pos_q[:, :, None] - window
         s = jnp.where(ok[:, None, None], s, NEG_INF)      # (B,g,r,C,page)
         valid = idx[None, :] < lengths[:, None]           # (B, page)
-        v = jnp.where(valid[:, :, None, None], v, jnp.zeros((), v.dtype))
+        v = jnp.where(valid[:, None, :, None], v, jnp.zeros((), v.dtype))
         m_new = jnp.maximum(m_run, s.max(axis=-1))
         alpha = jnp.exp(m_run - m_new)
         p = jnp.exp(s - m_new[..., None])
         l_new = l_run * alpha + p.sum(axis=-1)
         acc = acc * alpha[..., None] + jnp.einsum(
-            "bgrcp,bpgd->bgrcd", p.astype(v.dtype), v,
+            "bgrcp,bgpd->bgrcd", p.astype(v.dtype), v,
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc
 

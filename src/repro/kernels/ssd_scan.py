@@ -26,39 +26,48 @@ def _kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_scr, *,
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    a = a_ref[0].astype(jnp.float32)                     # scalar (per head)
-    x = x_ref[0].astype(jnp.float32)                     # (L, P)
-    dt = dt_ref[0].astype(jnp.float32)                   # (L,)
-    b = b_ref[0].astype(jnp.float32)                     # (L, N)
-    c = c_ref[0].astype(jnp.float32)                     # (L, N)
+    a = a_ref[pl.program_id(0)]                          # scalar (per head)
+    x = x_ref[...].astype(jnp.float32)                   # (L, P)
+    dt = dt_ref[...].astype(jnp.float32)                 # (L, 1)
+    b = b_ref[...].astype(jnp.float32)                   # (L, N)
+    c = c_ref[...].astype(jnp.float32)                   # (L, N)
 
-    la = a * dt                                          # (L,) <= 0
-    cum = jnp.cumsum(la)                                 # inclusive
-    u = x * dt[:, None]                                  # (L, P)
+    # Inclusive cumsum of the log decay as a column and as a row, by
+    # masked reductions (Mosaic has no cumsum): row[j] = sum_{i<=j} la[i]
+    # reduces over sublanes; the column goes through the diagonal.
+    la = a * dt                                          # (L, 1) <= 0
+    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    la_ij = jnp.broadcast_to(la, (chunk, chunk))         # [i, j] = la[i]
+    cum_row = jnp.where(ii <= jj, la_ij, 0.0).sum(axis=0, keepdims=True)
+    la_row = jnp.where(ii == jj, la_ij, 0.0).sum(axis=0, keepdims=True)
+    cum = jnp.where(jj <= ii, jnp.broadcast_to(la_row, (chunk, chunk)),
+                    0.0).sum(axis=1, keepdims=True)      # (L, 1)
+    total = la.sum(axis=0, keepdims=True)                # (1, 1)
+    u = x * dt                                           # (L, P)
 
     # intra-chunk quadratic form
-    dec = cum[:, None] - cum[None, :]                    # (L, L)
-    mask = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    mask = ii >= jj
+    dec = jnp.where(mask, cum - cum_row, 0.0)            # (L, L)
     w = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    w = jnp.where(mask, w * jnp.exp(jnp.where(mask, dec, 0.0)), 0.0)
+    w = jnp.where(mask, w * jnp.exp(dec), 0.0)
     y = jax.lax.dot_general(w, u, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
 
     # inter-chunk contribution from the carried state (P, N)
     state = state_scr[...]
-    y += jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y += jnp.exp(cum) * jax.lax.dot_general(
         c, state, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     # state update for the next chunk
-    w_end = jnp.exp(cum[-1] - cum)                       # (L,)
-    state_scr[...] = (state * jnp.exp(cum[-1])
+    w_end = jnp.exp(total - cum)                         # (L, 1)
+    state_scr[...] = (state * jnp.exp(total)
                       + jax.lax.dot_general(
-                          u * w_end[:, None], b, (((0,), (0,)), ((), ())),
+                          u * w_end, b, (((0,), (0,)), ((), ())),
                           preferred_element_type=jnp.float32))
-    y_ref[0] = y.astype(y_ref.dtype)
+    y_ref[...] = y.astype(y_ref.dtype)
 
 
 def ssd_scan(x: jax.Array, dt: jax.Array, a_log_neg: jax.Array,
@@ -74,29 +83,40 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a_log_neg: jax.Array,
     hg = h // g
 
     xr = x.transpose(0, 2, 1, 3).reshape(bsz * h, s, p)
-    dtr = dt.transpose(0, 2, 1).reshape(bsz * h, s)
+    # dt travels as a (S, 1) column per head: its (L, 1) block is tiled
+    # like the (L, P) rows of x, where a (1, L) block of a (B·H, S) array
+    # is not tile-aligned on TPU
+    dtr = dt.transpose(0, 2, 1).reshape(bsz * h, s, 1)
     br = b.transpose(0, 2, 1, 3).reshape(bsz * g, s, n)
     cr = c.transpose(0, 2, 1, 3).reshape(bsz * g, s, n)
-    ar = jnp.tile(a_log_neg, bsz)                        # (B*H,)
+    # per-head decay rates ride in SMEM as scalar prefetch
+    ar = jnp.tile(a_log_neg.astype(jnp.float32), bsz)   # (B*H,)
 
-    def bc_index(bh, ic):
+    def row_index(bh, ic, a_ref):
+        return (bh, ic, 0)
+
+    def bc_index(bh, ic, a_ref):
         batch = bh // h
         head = bh % h
         return (batch * g + head // hg, ic, 0)
 
-    y = pl.pallas_call(
-        functools.partial(_kernel, chunk=l),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(bsz * h, nc),
         in_specs=[
-            pl.BlockSpec((1,), lambda bh, ic: (bh,)),
-            pl.BlockSpec((1, l, p), lambda bh, ic: (bh, ic, 0)),
-            pl.BlockSpec((1, l), lambda bh, ic: (bh, ic)),
-            pl.BlockSpec((1, l, n), bc_index),
-            pl.BlockSpec((1, l, n), bc_index),
+            pl.BlockSpec((None, l, p), row_index),
+            pl.BlockSpec((None, l, 1), row_index),
+            pl.BlockSpec((None, l, n), bc_index),
+            pl.BlockSpec((None, l, n), bc_index),
         ],
-        out_specs=pl.BlockSpec((1, l, p), lambda bh, ic: (bh, ic, 0)),
-        out_shape=jax.ShapeDtypeStruct((bsz * h, s, p), x.dtype),
+        out_specs=pl.BlockSpec((None, l, p), row_index),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
+    )
+    y = pl.pallas_call(
+        functools.partial(_kernel, chunk=l),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((bsz * h, s, p), x.dtype),
         interpret=interpret,
+        name="ssd_scan",
     )(ar, xr, dtr, br, cr)
     return y.reshape(bsz, h, s, p).transpose(0, 2, 1, 3)

@@ -1,5 +1,3 @@
-from repro.parallel.mesh import (HBM_BW, ICI_BW, PEAK_BF16_FLOPS,
-                                 make_debug_mesh, make_production_mesh)
+from repro.parallel.mesh import make_debug_mesh, make_production_mesh
 
-__all__ = ["make_production_mesh", "make_debug_mesh", "PEAK_BF16_FLOPS",
-           "HBM_BW", "ICI_BW"]
+__all__ = ["make_production_mesh", "make_debug_mesh"]

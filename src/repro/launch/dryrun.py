@@ -30,6 +30,7 @@ from repro.configs import ALL, ARCHS, get_config, supports_shape
 from repro.launch import step_fns as sf
 from repro.launch.costmodel import bytes_estimate, flops_estimate
 from repro.parallel import ExecutionPlan, data_axes, make_production_mesh
+from repro.parallel.mesh import PRODUCTION_DEVICE_KIND
 from repro.parallel.axes import act_sharding_for
 from repro.launch.roofline import (entry_io_bytes, model_flops,
                                    normalize_cost_analysis,
@@ -157,7 +158,8 @@ def lower_combo(arch: str, shape_name: str, mesh, *,
     flops_ideal = flops_estimate(cfg, shape, ideal=True) / n_dev
     byt = bytes_estimate(cfg, shape, n_dev,
                          optimizer=sf.optimizer_for(cfg))
-    terms = roofline(flops_impl, byt["total"], coll_total)
+    terms = roofline(flops_impl, byt["total"], coll_total,
+                     PRODUCTION_DEVICE_KIND)
     mflops = model_flops(cfg, shape)
     record = {
         "arch": arch, "shape": shape_name, "mode": mode,

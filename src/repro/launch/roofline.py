@@ -16,7 +16,7 @@ import re
 from typing import Dict, Tuple
 
 from repro.config import ModelConfig, ShapeConfig
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_BF16_FLOPS
+from repro.parallel.mesh import peak_rates
 
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
@@ -177,10 +177,11 @@ def model_flops(cfg: ModelConfig, shape: ShapeConfig) -> float:
 
 
 def roofline(flops_per_dev: float, bytes_per_dev: float,
-             coll_bytes_per_dev: float) -> Dict[str, float]:
-    t_c = flops_per_dev / PEAK_BF16_FLOPS
-    t_m = bytes_per_dev / HBM_BW
-    t_n = coll_bytes_per_dev / ICI_BW
+             coll_bytes_per_dev: float, device_kind: str) -> Dict[str, float]:
+    peak = peak_rates(device_kind)
+    t_c = flops_per_dev / peak["bf16_flops"]
+    t_m = bytes_per_dev / peak["hbm_bw"]
+    t_n = coll_bytes_per_dev / peak["ici_bw"]
     dom = max((t_c, "compute"), (t_m, "memory"), (t_n, "collective"))
     return {"compute_s": t_c, "memory_s": t_m, "collective_s": t_n,
             "bottleneck": dom[1]}

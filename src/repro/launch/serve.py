@@ -1,6 +1,7 @@
 """Serving driver: what a HeteroRL *sampler node* runs. CPU-scale by
-default (smoke config); the full-size serving path is exercised
-shape-exactly by ``dryrun.py`` (prefill_32k / decode_32k / long_500k).
+default (smoke config); ``--full-width`` serves the arch's published
+config (random-init weights), and ``dryrun.py`` lowers the full-size
+serving shapes (prefill_32k / decode_32k / long_500k).
 
 All deployment knobs live in one ``ServeConfig`` (engine kind, slots,
 page size, decode horizon, pool size, mesh, admission limits) — the
@@ -25,18 +26,22 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro import obs
-from repro.config import RLConfig, ServeConfig
-from repro.configs import smoke
+from repro.compile_cache import enable_compile_cache
+from repro.config import ModelConfig, RLConfig, ServeConfig
+from repro.configs import config_for
 from repro.data import ArithmeticTask, Tokenizer, encode_prompts
-from repro.models import encode, init_params
-from repro.parallel import plan_from_flag
+from repro.models import encode
+from repro.parallel import ExecutionPlan, plan_from_flag
 from repro.sampling import build_engine
 from repro.serving.api import Request, SamplingParams
+
+PROMPT_WIDTH = 8                     # ArithmeticTask prompt width
 
 
 def parse_serve_config(args: argparse.Namespace) -> ServeConfig:
@@ -54,9 +59,24 @@ def parse_serve_config(args: argparse.Namespace) -> ServeConfig:
         spec_rescore=not args.no_spec_rescore)
 
 
-def main() -> None:
+class Deployment(NamedTuple):
+    """What the flags resolve to: the model, its random-init params placed
+    on the serve plan, and the sampling profile."""
+    cfg: ModelConfig
+    serve: ServeConfig
+    rl: RLConfig
+    plan: ExecutionPlan
+    params: Any
+    memory: Optional[jax.Array]
+    key: jax.Array
+
+
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
+    ap.add_argument("--full-width", action="store_true",
+                    help="the arch's published config instead of its "
+                         "smoke-sized variant")
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--rounds", type=int, default=3)
@@ -111,24 +131,20 @@ def main() -> None:
     ap.add_argument("--trace-out", default="",
                     help="write a Chrome-trace/Perfetto JSON here on exit "
                          "(implies --obs)")
-    args = ap.parse_args()
-    args.prompt_width = 8            # ArithmeticTask prompt width below
+    args = ap.parse_args(argv)
+    args.prompt_width = PROMPT_WIDTH
+    return args
 
-    if args.obs or args.trace_out:
-        obs.configure(True)
 
-    cfg = smoke(args.arch)
+def load(args: argparse.Namespace) -> Deployment:
+    cfg = config_for(args.arch, args.full_width)
     serve = parse_serve_config(args)
     rl = RLConfig(temperature=args.temperature, top_k=args.top_k,
                   top_p=args.top_p, max_new_tokens=args.max_new,
                   engine=serve.engine)
-    tok = Tokenizer()
-    task = ArithmeticTask(max_operand=99, ops="+-", prompt_width=8,
-                          seed=serve.seed)
     plan = plan_from_flag(serve.mesh, "serve")
-    print(f"[serve] {plan.describe()}")
     key = jax.random.PRNGKey(serve.seed)
-    params = plan.device_put_params(cfg, init_params(cfg, key))
+    params = plan.init_params(cfg, key)
 
     memory = None
     if cfg.is_encdec:
@@ -139,6 +155,34 @@ def main() -> None:
         memory = 0.02 * jax.random.normal(
             key, (args.batch, cfg.memory_seq, cfg.d_model)
         ).astype(cfg.dtype)
+    return Deployment(cfg, serve, rl, plan, params, memory, key)
+
+
+def make_task(seed: int) -> ArithmeticTask:
+    return ArithmeticTask(max_operand=99, ops="+-", prompt_width=PROMPT_WIDTH,
+                          seed=seed)
+
+
+def make_requests(task: ArithmeticTask, tok: Tokenizer, n: int,
+                  params: SamplingParams, rid0: int = 0
+                  ) -> Tuple[list, List[Request]]:
+    """``n`` fresh problems and their requests, ids from ``rid0``."""
+    probs = task.sample_batch(n)
+    prompts = encode_prompts(tok, probs)
+    return probs, [Request(rid=rid0 + i, prompt=row, params=params)
+                   for i, row in enumerate(prompts)]
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    enable_compile_cache()
+    args = parse_args(argv)
+    if args.obs or args.trace_out:
+        obs.configure(True)
+
+    cfg, serve, rl, plan, params, memory, key = load(args)
+    print(f"[serve] {plan.describe()}")
+    tok = Tokenizer()
+    task = make_task(serve.seed)
 
     if args.listen:
         import asyncio
@@ -165,13 +209,9 @@ def main() -> None:
     total_tok, rid = 0, 0
     t0 = time.time()
     for r in range(args.rounds):
-        probs = task.sample_batch(args.batch)
-        prompts = encode_prompts(tok, probs)
+        probs, reqs = make_requests(task, tok, args.batch, sp, rid)
+        rid += len(reqs)
         key, k = jax.random.split(key)
-        reqs = []
-        for row in prompts:
-            reqs.append(Request(rid=rid, prompt=row, params=sp))
-            rid += 1
         t1 = time.time()
         results = engine.generate(reqs, key=k)
         dt = time.time() - t1

@@ -1,14 +1,20 @@
 """Production training driver.
 
-Runs real RL training end-to-end: at CPU scale with a reduced (smoke)
-config by default, or lowering the full config on the production mesh when
-``--dryrun`` (see ``dryrun.py`` for the full sweep). This is example (b)'s
-"end-to-end driver": it trains a small model for a few hundred steps with
-any of the paper's loss types, online or heterogeneous.
+Runs real RL training end-to-end with any of the paper's loss types,
+online or heterogeneous: at CPU scale with the arch's reduced (smoke)
+config by default, or at its published width with ``--full-width`` (the
+config ``repro.configs.get_config`` returns; random-init weights, the
+built-in arithmetic task and tokenizer). At full width the learner uses
+Adafactor: AdamW's two f32 moments do not fit Qwen3-1.7B on one 16 GB
+TPU v5e. ``dryrun.py`` lowers the full configs on the production mesh.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-1.7b \
       --loss gepo --steps 200 --mode hetero --max-delay 64
+
+One TPU v5e at full width:
+  PYTHONPATH=src python -m repro.launch.train --full-width --sft-steps 2 \
+      --steps 3 --eval-every 1000
 
 Multi-device (one unified ExecutionPlan drives SFT, RL learner and
 samplers; on CPU export the host-device override first):
@@ -20,20 +26,21 @@ from __future__ import annotations
 import argparse
 import json
 import time
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.config import HeteroConfig, RLConfig, TrainConfig
-from repro.configs import smoke
+from repro.configs import config_for
 from repro.core.diagnostics import best_last_gap
 from repro.data import ArithmeticTask, Tokenizer
 from repro.data.tasks import EOS
 from repro.hetero import HeteroRuntime, run_online
-from repro.models import init_params
 from repro.parallel import plan_from_flag
-from repro.training import init_state, jit_sft_step
+from repro.training import init_state, jit_sft_step, optimizer_of
 
 
 def make_eval_fn(cfg, rl, task, tok, n_prompts=32, seed=1234):
@@ -59,7 +66,7 @@ def make_eval_fn(cfg, rl, task, tok, n_prompts=32, seed=1234):
 def sft_warmstart(cfg, tc, task, tok, state, steps=400, batch=64, seed=0):
     """Supervised warm start (the paper RL-tunes a pretrained model)."""
     rng = np.random.default_rng(seed)
-    step_fn = jit_sft_step(cfg, tc)
+    step_fn = jit_sft_step(cfg, tc, optimizer=optimizer_of(state))
     width = task.prompt_width + 8
     for _ in range(steps):
         probs = task.sample_batch(batch)
@@ -77,9 +84,12 @@ def sft_warmstart(cfg, tc, task, tok, state, steps=400, batch=64, seed=0):
     return state, float(loss)
 
 
-def main() -> None:
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
+    ap.add_argument("--full-width", action="store_true",
+                    help="the arch's published config instead of its "
+                         "smoke-sized variant; the learner uses Adafactor")
     ap.add_argument("--loss", default="gepo")
     ap.add_argument("--mode", default="online",
                     choices=["online", "hetero"])
@@ -111,9 +121,13 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--eval-every", type=int, default=10)
     ap.add_argument("--out", default=None)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    cfg = smoke(args.arch)
+
+def train(args: argparse.Namespace):
+    """SFT warm start, then RL as ``args`` say. Returns (summary, metrics
+    history, learner node)."""
+    cfg = config_for(args.arch, args.full_width)
     beta = args.beta_kl if args.beta_kl is not None else (
         0.0 if args.mode == "online" else 0.005)   # paper §4.1
     rl = RLConfig(loss_type=args.loss, group_size=args.group_size,
@@ -131,10 +145,13 @@ def main() -> None:
           f"samplers {sampler_plan.describe()}")
 
     key = jax.random.PRNGKey(args.seed)
-    params = init_params(cfg, key)
+    params = learner_plan.init_params(cfg, key)
     tc_sft = TrainConfig(learning_rate=1e-2, total_steps=args.sft_steps,
                          logprob_impl=args.logprob_impl, mesh=args.mesh)
-    state = init_state(cfg, tc_sft, params, plan=learner_plan)
+    optimizer = "adafactor" if args.full_width else "adamw"
+    state = init_state(cfg, tc_sft, params, optimizer=optimizer,
+                       plan=learner_plan)
+    del params                  # the state owns them; SFT donates it
     t0 = time.time()
     state, sft_loss = sft_warmstart(cfg, tc_sft, task, tok, state,
                                     steps=args.sft_steps, seed=args.seed)
@@ -177,6 +194,13 @@ def main() -> None:
         "staleness_mean": float(np.nanmean(hist.get("staleness"))),
         "wall_s": round(time.time() - t0, 1),
     }
+    return summary, hist, learner
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    enable_compile_cache()
+    args = parse_args(argv)
+    summary, _, _ = train(args)
     print("[train] " + json.dumps(summary, indent=1))
     if args.out:
         with open(args.out, "w") as f:

@@ -76,7 +76,7 @@ def _self_attn(cfg: ModelConfig, p: Dict, x: jax.Array, *, kind: str,
     if cache is not None and "kp" in cache:               # paged KV cache
         b, sq = x.shape[0], x.shape[1]
         kp, vp = cache["kp"], cache["vp"]
-        page_size = kp.shape[1]
+        page_size = kp.shape[2]                           # (P, Hkv, page, D)
         page = positions // page_size                     # (B, Sq) logical
         off = positions % page_size
         # logical pages past the block-table width (only padded prefill
@@ -84,8 +84,8 @@ def _self_attn(cfg: ModelConfig, p: Dict, x: jax.Array, *, kind: str,
         # below drops the write instead of clamping onto a live page
         phys = jnp.take_along_axis(page_table, page, axis=1, mode="fill",
                                    fill_value=jnp.iinfo(jnp.int32).min)
-        kp = kp.at[phys, off].set(k.astype(kp.dtype))
-        vp = vp.at[phys, off].set(v.astype(vp.dtype))
+        kp = kp.at[phys, :, off].set(k.astype(kp.dtype))
+        vp = vp.at[phys, :, off].set(v.astype(vp.dtype))
         if sq == 1:                                       # decode
             # hot loop: attend the pools in place (or via the bit-exact
             # gather fallback) — repro.kernels.ops.paged_decode. The

@@ -33,15 +33,6 @@ from jax.sharding import PartitionSpec as P
 from repro.config import ModelConfig
 from repro.models.layers import swiglu
 
-# jax >= 0.6 exposes shard_map at top level with ``check_vma``; older
-# releases ship jax.experimental.shard_map with ``check_rep``.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _CHECK_KW = {"check_vma": False}
-else:                                                   # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = {"check_rep": False}
-
 
 def moe_ffn_ep(cfg: ModelConfig, p: Dict, x: jax.Array
                ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
@@ -81,11 +72,11 @@ def moe_ffn_ep(cfg: ModelConfig, p: Dict, x: jax.Array
     x_spec = P(dp if dp else None, None, None)
 
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(x_spec, P(None, None),
                   w_up_spec, w_up_spec, w_dn_spec),
         out_specs=(x_spec, P(), P(), P()),
-        **_CHECK_KW)
+        check_vma=False)
     def inner(x_loc, router, w_gate, w_up, w_down):
         b_loc, s, d = x_loc.shape
         t_loc = b_loc * s

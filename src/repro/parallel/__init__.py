@@ -5,9 +5,10 @@ One mesh/sharding path for train, sample, and dry-run: logical-axis rules
 every executing surface — learner train step, sampler engines, checkpoint
 round-trips, the lowering-only dry-run — consumes for placement.
 """
-from repro.parallel.mesh import (HBM_BW, ICI_BW, PEAK_BF16_FLOPS,
-                                 data_axes, local_mesh, make_debug_mesh,
-                                 make_production_mesh, mesh_from_flag)
+from repro.parallel.mesh import (PEAKS, data_axes, local_mesh,
+                                 make_debug_mesh, make_mesh,
+                                 make_production_mesh, mesh_from_flag,
+                                 peak_rates)
 from repro.parallel.plan import (ExecutionPlan, local_plan, make_plan,
                                  plan_for_params, plan_from_flag)
 from repro.parallel.step import make_sharded_sft_step, make_sharded_train_step
@@ -16,7 +17,6 @@ __all__ = [
     "ExecutionPlan", "make_plan", "local_plan", "plan_from_flag",
     "plan_for_params",
     "make_sharded_train_step", "make_sharded_sft_step",
-    "make_production_mesh", "make_debug_mesh", "local_mesh",
-    "mesh_from_flag", "data_axes",
-    "PEAK_BF16_FLOPS", "HBM_BW", "ICI_BW",
+    "make_mesh", "make_production_mesh", "make_debug_mesh", "local_mesh",
+    "mesh_from_flag", "data_axes", "PEAKS", "peak_rates",
 ]

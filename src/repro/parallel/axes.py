@@ -116,7 +116,7 @@ def cache_specs(cfg: ModelConfig, cache: Any, mode: str,
     decode (partial-softmax flash-decode, inserted by GSPMD) is the only
     layout that fits. Per-device cache = total / (dp × model).
 
-    Paged pools (``kp``/``vp``, shape (nb, pages, page, Hkv, hd)) instead
+    Paged pools (``kp``/``vp``, shape (nb, pages, Hkv, page, hd)) instead
     shard kv-heads over 'model' — pages are the unit of allocator locality,
     so splitting inside a page would defeat the block table; ``fit_spec``
     falls back to replication when Hkv doesn't divide. The paged-decode
@@ -135,8 +135,8 @@ def cache_specs(cfg: ModelConfig, cache: Any, mode: str,
 
     def spec_for(path, leaf) -> P:
         names = [str(getattr(p, "key", "")) for p in path]
-        if "kp" in names or "vp" in names:      # (nb, pages, page, Hkv, hd)
-            return P(None, None, None, "model", None)
+        if "kp" in names or "vp" in names:      # (nb, pages, Hkv, page, hd)
+            return P(None, None, "model", None, None)
         if "k" in names or "v" in names or "k_mem" in names or "v_mem" in names:
             # (nb, B, S, Hkv, hd)
             return P(None, batch_axes, seq_axes, None, None)

@@ -19,20 +19,44 @@ module does not touch jax device state.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Dict, Tuple
 
 import jax
+from jax.sharding import AxisType
 
-# TPU v5e hardware constants (per chip) used by the roofline analysis.
-PEAK_BF16_FLOPS = 197e12          # FLOP/s
-HBM_BW = 819e9                    # bytes/s
-ICI_BW = 50e9                     # bytes/s per link
+
+# Published per-chip peaks keyed by ``Device.device_kind`` (Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+# ICI over 4 links). A device missing here is an error, never a default.
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+# The production mesh below is a v5e pod; the dry-run models that chip.
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
+
+
+def peak_rates(device_kind: str) -> Dict[str, float]:
+    """Per-chip peak FLOP/s and bytes/s of ``device_kind``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}"
+                       f"; known: {sorted(PEAKS)}") from None
+
+
+def make_mesh(shape: Tuple[int, ...],
+              names: Tuple[str, ...]) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: placement is propagated
+    by GSPMD from the plan's in/out shardings and in-trace constraints,
+    which ``Explicit`` axes (the ``jax.make_mesh`` default) reject."""
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2, *,
@@ -40,15 +64,15 @@ def make_debug_mesh(n_data: int = 2, n_model: int = 2, *,
     """Small mesh for CI-scale dry-run tests (requires
     --xla_force_host_platform_device_count >= product)."""
     if multi_pod:
-        return jax.make_mesh((2, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return make_mesh((2, n_data, n_model), ("pod", "data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))
 
 
 @functools.lru_cache(maxsize=1)
 def local_mesh() -> jax.sharding.Mesh:
     """The (data=1, model=1) mesh backing single-device execution plans.
     Cached so every caller sees the same Mesh object (stable jit keys)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def mesh_from_flag(spec: str) -> jax.sharding.Mesh:
@@ -74,8 +98,8 @@ def mesh_from_flag(spec: str) -> jax.sharding.Mesh:
     if len(dims) == 2:
         if dims == (1, 1):
             return local_mesh()
-        return jax.make_mesh(dims, ("data", "model"))
-    return jax.make_mesh(dims, ("pod", "data", "model"))
+        return make_mesh(dims, ("data", "model"))
+    return make_mesh(dims, ("pod", "data", "model"))
 
 
 def data_axes(mesh: jax.sharding.Mesh) -> Tuple[str, ...]:

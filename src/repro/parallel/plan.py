@@ -132,6 +132,13 @@ class ExecutionPlan:
                 for k, v in mbs.items()}
 
     # ---- placement / gather ---------------------------------------------
+    def init_params(self, cfg: ModelConfig, key: jax.Array) -> Any:
+        """Random-init params (``repro.models.init_params``'s values) born
+        on the plan's shardings, in one program: no leaf ever sits whole
+        on one device, and no per-leaf f32 temporaries pile up behind
+        asynchronous eager dispatch."""
+        return _init_fn(self, cfg)(key)
+
     def device_put_params(self, cfg: ModelConfig, params: Any, *,
                           copy: bool = False) -> Any:
         """Place a param tree onto the plan. ``copy=True`` forces fresh
@@ -174,6 +181,13 @@ def _param_shardings(plan: ExecutionPlan, cfg: ModelConfig) -> Any:
     return axes.to_named_fit(plan.mesh,
                              axes.param_specs(cfg, plan.mode, plan.mesh),
                              abstract_params(cfg))
+
+
+@functools.lru_cache(maxsize=8)
+def _init_fn(plan: ExecutionPlan, cfg: ModelConfig):
+    from repro.models import init_params
+    return jax.jit(functools.partial(init_params, cfg),
+                   out_shardings=_param_shardings(plan, cfg))
 
 
 @functools.lru_cache(maxsize=64)

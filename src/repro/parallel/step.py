@@ -68,24 +68,20 @@ def make_sharded_train_step(cfg: ModelConfig, rl: RLConfig, tc: TrainConfig,
 
 def make_sharded_sft_step(cfg: ModelConfig, tc: TrainConfig,
                           plan: ExecutionPlan, *,
+                          optimizer: str = "adamw",
                           donate: bool = True) -> Callable:
     """(state, tokens, mask) -> (state, loss) with plan shardings and a
     donated ``TrainState`` — the SFT warm-start twin of the RL step."""
-    from repro.optim import (adamw_update, clip_by_global_norm,
-                             warmup_schedule)
-    from repro.training import TrainState, sft_loss_fn
-    state_sh = plan.state_shardings(cfg, "adamw")
+    from repro.training import apply_update, sft_loss_fn
+    state_sh = plan.state_shardings(cfg, optimizer)
 
     def step(state, tokens, mask):
         loss, grads = jax.value_and_grad(
             lambda p: sft_loss_fn(cfg, p, tokens, mask,
                                   logprob_impl=tc.logprob_impl))(
             state.params)
-        grads, _ = clip_by_global_norm(grads, tc.grad_clip)
-        lr = warmup_schedule(tc, state.step)
-        new_params, new_opt = adamw_update(tc, grads, state.opt,
-                                           state.params, lr)
-        return TrainState(new_params, new_opt, state.step + 1), loss
+        new_state, _, _ = apply_update(tc, optimizer, state, grads)
+        return new_state, loss
 
     @functools.lru_cache(maxsize=8)
     def build(tok_shape, mask_shape):

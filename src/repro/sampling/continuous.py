@@ -75,7 +75,7 @@ def _copy_page_jit(cfg: ModelConfig, plan, pool, src, dst):
     if plan is not None:
         pool = plan.constrain_cache(cfg, pool)
 
-    def cp(leaf):                       # (nb, pages, page, Hkv, D)
+    def cp(leaf):                       # (nb, pages, Hkv, page, D)
         return leaf.at[:, dst].set(leaf[:, src])
 
     return jax.tree_util.tree_map(cp, pool)
@@ -416,6 +416,9 @@ class ContinuousEngine:
 
     def update_params(self, params: Any) -> None:
         self.params = params
+        # cached prefix pages hold KV computed under the old weights
+        if self.prefix_cache is not None:
+            self.prefix_cache.clear()
 
     def stats(self) -> Dict[str, float]:
         out: Dict[str, float] = dict(self.sched.stats)

@@ -6,7 +6,7 @@ the whole generation. Here the sequence axis is instead carved into
 fixed-size *pages* owned by a global pool:
 
 - **page pools** — per attention layer, ``kp``/``vp`` of shape
-  ``(num_blocks, num_pages, page_size, Hkv, head_dim)`` (stacked on the
+  ``(num_blocks, num_pages, Hkv, page_size, head_dim)`` (stacked on the
   scanned super-block axis exactly like the dense cache, so the model's
   block scan is unchanged);
 - **block table** — ``(num_slots, pages_per_slot)`` int32 mapping a decode
@@ -122,7 +122,7 @@ def init_paged_pool(cfg: ModelConfig, num_pages: int, page_size: int, *,
         if kind not in (ATTN, LOCAL):
             raise ValueError(
                 f"paged cache supports attention layers only, got {kind!r}")
-        shape = (nb, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim)
+        shape = (nb, num_pages, cfg.num_kv_heads, page_size, cfg.head_dim)
         pool[f"layer_{i}"] = {"self": {"kp": jnp.zeros(shape, dt),
                                        "vp": jnp.zeros(shape, dt)}}
     return pool

@@ -183,7 +183,7 @@ def accept_drafts(logits: jax.Array, window_tokens: jax.Array,
 
 
 def stacked_pools(cfg: ModelConfig, pool) -> Tuple[jax.Array, jax.Array]:
-    """Assemble the (L, pages, page, Hkv, D) stacked-pool layout
+    """Assemble the (L, pages, Hkv, page, D) stacked-pool layout
     ``paged_*_layers`` folds, from the engine pool's scanned-block
     layout (per-pattern-position ``layer_{i}`` leaves each stacked on
     the super-block axis). Layer order is block-major — exactly the

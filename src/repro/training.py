@@ -22,8 +22,9 @@ from repro.core import group_advantages, policy_loss
 from repro.core.logprob import token_logprob_from_logits
 from repro.kernels.ops import fused_token_logprob
 from repro.models import forward
-from repro.optim import (adafactor_init, adafactor_update, adamw_init,
-                         adamw_update, clip_by_global_norm, warmup_schedule)
+from repro.optim import (AdafactorState, AdamWState, adafactor_init,
+                         adafactor_update, adamw_init, adamw_update,
+                         clip_by_global_norm, warmup_schedule)
 
 # Metrics that aggregate across grad-accum microbatches with `max` rather
 # than a mean — averaging per-microbatch maxima would understate e.g. the
@@ -46,6 +47,16 @@ class TrainState(NamedTuple):
     params: Any
     opt: Any
     step: jax.Array
+
+
+def optimizer_of(state: TrainState) -> str:
+    """The optimizer a ``TrainState`` was built for, read off its opt
+    buffers — the step, its shardings and its update rule follow it."""
+    if isinstance(state.opt, AdamWState):
+        return "adamw"
+    if isinstance(state.opt, AdafactorState):
+        return "adafactor"
+    raise TypeError(f"unknown optimizer state {type(state.opt).__name__}")
 
 
 def init_state(cfg: ModelConfig, tc: TrainConfig, params,
@@ -147,17 +158,21 @@ def train_step(cfg: ModelConfig, rl: RLConfig, tc: TrainConfig,
             state.params, batch)
 
     with jax.named_scope("optim_update"):
-        grads, gnorm = clip_by_global_norm(grads, tc.grad_clip)
-        lr = warmup_schedule(tc, state.step)
-        if optimizer == "adamw":
-            new_params, new_opt = adamw_update(tc, grads, state.opt,
-                                               state.params, lr)
-        else:
-            new_params, new_opt = adafactor_update(tc, grads, state.opt,
-                                                   state.params, lr)
+        new_state, gnorm, lr = apply_update(tc, optimizer, state, grads)
     metrics["grad_norm"] = gnorm
     metrics["lr"] = lr
-    return TrainState(new_params, new_opt, state.step + 1), metrics
+    return new_state, metrics
+
+
+def apply_update(tc: TrainConfig, optimizer: str, state: TrainState,
+                 grads: Any) -> Tuple[TrainState, jax.Array, jax.Array]:
+    """Clip, then one ``optimizer`` step at the warmup schedule's rate:
+    (new state, pre-clip grad norm, learning rate)."""
+    grads, gnorm = clip_by_global_norm(grads, tc.grad_clip)
+    lr = warmup_schedule(tc, state.step)
+    update = adamw_update if optimizer == "adamw" else adafactor_update
+    new_params, new_opt = update(tc, grads, state.opt, state.params, lr)
+    return TrainState(new_params, new_opt, state.step + 1), gnorm, lr
 
 
 def jit_train_step(cfg: ModelConfig, rl: RLConfig, tc: TrainConfig,
@@ -188,10 +203,12 @@ def sft_loss_fn(cfg: ModelConfig, params, tokens: jax.Array,
     return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
 
 
-def jit_sft_step(cfg: ModelConfig, tc: TrainConfig, plan=None):
+def jit_sft_step(cfg: ModelConfig, tc: TrainConfig, plan=None,
+                 optimizer: str = "adamw"):
     """Jitted SFT step through the same execution layer as the RL step
     (plan shardings + donated state; ``TrainConfig.mesh`` decides when no
     plan is passed)."""
     from repro.parallel import make_sharded_sft_step, plan_from_flag
     return make_sharded_sft_step(cfg, tc, plan or plan_from_flag(tc.mesh,
-                                                                 "train"))
+                                                                 "train"),
+                                 optimizer=optimizer)

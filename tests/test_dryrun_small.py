@@ -103,3 +103,12 @@ ENTRY %main (p0: f32[4]) -> f32[4] {
         out = parse_collectives_loop_aware(hlo)
         assert out["all-gather"] == 5 * 8 * 4
         assert out["all-reduce"] == 16
+
+    def test_roofline_peaks_keyed_by_device_kind(self):
+        from repro.launch.roofline import roofline
+        from repro.parallel import peak_rates
+        terms = roofline(197e12, 819e9, 0.0, "TPU v5 lite")
+        assert terms["compute_s"] == pytest.approx(1.0)
+        assert terms["memory_s"] == pytest.approx(1.0)
+        with pytest.raises(KeyError, match="no published peaks"):
+            peak_rates("cpu")

@@ -103,13 +103,23 @@ class TestDispatcher:
         np.testing.assert_allclose(np.asarray(ent), np.asarray(ent_e),
                                    rtol=1e-5, atol=1e-5)
 
-    def test_pallas_ragged_falls_back(self, rng):
+    def test_pallas_ragged_falls_back(self, rng, monkeypatch):
         ks = jax.random.split(rng, 2)
-        logits = jax.random.normal(ks[0], (50, 300))     # 300 % 256 != 0...
+        logits = jax.random.normal(ks[0], (50, 300))     # 300 % 128 != 0...
         tgt = jax.random.randint(ks[1], (50,), 0, 300)
-        # ...so impl="pallas" must still work (chunked under the hood)
-        lp, _ = ops.fused_token_logprob(logits, tgt, impl="pallas",
-                                        block_t=16, block_v=256)
+        # ...so a forced impl="pallas" refuses the shape rather than
+        # running another backend under its name
+        with pytest.raises(ValueError, match="no Pallas tiling"):
+            ops.fused_token_logprob(logits, tgt, impl="pallas",
+                                    block_t=16, block_v=256)
+        # auto on a TPU falls back to chunked, and says so
+        monkeypatch.setattr(ops, "on_tpu", lambda: True)
+        assert ops.logprob_backend((40, 384)) == "pallas"
+        with pytest.warns(RuntimeWarning, match="chunked jnp backend"):
+            assert ops.logprob_backend(logits.shape, block_t=16,
+                                       block_v=256) == "chunked"
+            lp, _ = ops.fused_token_logprob(logits, tgt, block_t=16,
+                                            block_v=256)
         lp_e = token_logprob_from_logits(logits, tgt)
         np.testing.assert_allclose(np.asarray(lp), np.asarray(lp_e),
                                    rtol=1e-5, atol=1e-5)

@@ -82,6 +82,55 @@ class TestRuntime:
         assert hist.get("staleness").max() == 0.0
         assert learner.step == 5
 
+    @pytest.mark.parametrize("optimizer,engine", [("adamw", "static"),
+                                                  ("adafactor", "continuous")])
+    def test_online_generates_with_updated_params(self, monkeypatch,
+                                                  optimizer, engine):
+        """Online step k generates with the params the learner holds
+        before its step k — the ones step k-1 produced — through the
+        node's engine, and never with a buffer the learner's step has
+        donated. The learner runs the optimizer its state was built
+        for."""
+        import dataclasses
+
+        import repro.hetero.runtime as runtime_mod
+        from repro.configs import smoke
+        from repro.hetero.nodes import LearnerNode, SamplerNode
+
+        cfg = smoke("qwen3-1.7b")
+        # the entropy bonus moves the params even when every reward of
+        # the random-init policy ties (zero advantages)
+        rl = dataclasses.replace(RL, engine=engine, entropy_bonus=0.1)
+        leaf = lambda p: np.asarray(p["embed"])          # noqa: E731
+        generated_with, learner_before = [], []
+
+        class Sampler(SamplerNode):
+            def generate_batch(self, now_s):
+                batch = super().generate_batch(now_s)
+                served = self._gen_engine.params
+                assert not any(x.is_deleted() for x in
+                               jax.tree_util.tree_leaves(served))
+                generated_with.append(leaf(served))
+                return batch
+
+        class Learner(LearnerNode):
+            def train_on(self, batch):
+                learner_before.append(leaf(self.state.params))
+                return super().train_on(batch)
+
+        monkeypatch.setattr(runtime_mod, "SamplerNode", Sampler)
+        monkeypatch.setattr(runtime_mod, "LearnerNode", Learner)
+        task = ArithmeticTask(max_operand=9, ops="+", prompt_width=5, seed=0)
+        state = init_state(cfg, TC, init_params(cfg, jax.random.PRNGKey(0)),
+                           optimizer=optimizer)
+        hist, _, learner = run_online(cfg, rl, TC, task, Tokenizer(), state,
+                                      num_steps=2, prompts_per_batch=2)
+        assert learner.step == 2
+        assert np.isfinite(hist.get("loss")).all()
+        assert not np.array_equal(learner_before[0], learner_before[1])
+        for seen, want in zip(generated_with, learner_before, strict=True):
+            np.testing.assert_array_equal(seen, want)
+
     def test_hetero_staleness_grows_with_delay(self):
         slow = _runtime(seed=5, delay_median_s=1500.0).run(12)
         fast = _runtime(seed=5, delay_median_s=60.0).run(12)

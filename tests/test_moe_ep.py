@@ -22,7 +22,8 @@ SCRIPT = textwrap.dedent("""
     from repro.models.params import init_params
     from repro.runtime_context import mesh_context
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.parallel import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = ModelConfig(name="moe-eq", family="moe", num_layers=1,
                       d_model=64, num_heads=4, num_kv_heads=2, d_ff=128,
                       vocab_size=256, block_pattern=(ATTN,),

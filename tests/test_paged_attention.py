@@ -17,13 +17,6 @@ from repro.kernels.paged_attention import paged_attention
 from repro.models import init_params
 from repro.sampling import generate, generate_continuous
 
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _CHECK_KW = {"check_vma": False}
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = {"check_rep": False}
-
 
 def _tols(dtype):
     return dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 \
@@ -38,8 +31,8 @@ def make_case(*, b=4, hkv=2, rep=4, d=32, page=8, npages=6, pool=None,
     hq = hkv * rep
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (b, 1, hq, d), dtype)
-    kp = jax.random.normal(ks[1], (pool, page, hkv, d), dtype)
-    vp = jax.random.normal(ks[2], (pool, page, hkv, d), dtype)
+    kp = jax.random.normal(ks[1], (pool, hkv, page, d), dtype)
+    vp = jax.random.normal(ks[2], (pool, hkv, page, d), dtype)
     host = np.random.default_rng(seed)
     perm = host.permutation(np.arange(1, pool))
     table = perm[:b * npages].reshape(b, npages).astype(np.int32)
@@ -82,16 +75,18 @@ class TestParity:
         positions — checked against a per-slot dense softmax built from
         the table by hand."""
         q, kp, vp, table, lengths = make_case(b=3, rep=2, seed=11)
-        page = kp.shape[1]
         out = np.asarray(paged_decode(q, kp, vp, table, lengths,
                                       impl="ref"), np.float32)
         tb, ln = np.asarray(table), np.asarray(lengths)
+        g, d = kp.shape[1], kp.shape[3]                   # (P, Hkv, page, D)
         for b in range(q.shape[0]):
-            kc = np.asarray(kp, np.float32)[tb[b]].reshape(-1, *kp.shape[2:])
-            vc = np.asarray(vp, np.float32)[tb[b]].reshape(-1, *vp.shape[2:])
+            kc = (np.asarray(kp, np.float32)[tb[b]].transpose(0, 2, 1, 3)
+                  .reshape(-1, g, d))
+            vc = (np.asarray(vp, np.float32)[tb[b]].transpose(0, 2, 1, 3)
+                  .reshape(-1, g, d))
             kc, vc = kc[:ln[b]], vc[:ln[b]]
             qb = np.asarray(q, np.float32)[b, 0]          # (Hq, D)
-            g, r = kp.shape[2], q.shape[2] // kp.shape[2]
+            r = q.shape[2] // g
             qg = qb.reshape(g, r, -1)
             s = np.einsum("grd,kgd->grk", qg, kc) / np.sqrt(qb.shape[-1])
             p = np.exp(s - s.max(-1, keepdims=True))
@@ -173,8 +168,8 @@ def make_prefill_case(*, b=3, c=8, hkv=2, rep=4, d=32, page=8, npages=6,
     pool = 1 + b * npages + 3
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (b, c, hq, d), dtype)
-    kp = jax.random.normal(ks[1], (pool, page, hkv, d), dtype)
-    vp = jax.random.normal(ks[2], (pool, page, hkv, d), dtype)
+    kp = jax.random.normal(ks[1], (pool, hkv, page, d), dtype)
+    vp = jax.random.normal(ks[2], (pool, hkv, page, d), dtype)
     host = np.random.default_rng(seed)
     perm = host.permutation(np.arange(1, pool))
     table = perm[:b * npages].reshape(b, npages).astype(np.int32)
@@ -194,12 +189,12 @@ def _prefill_oracle(q, kp, vp, table, positions, *, window=None,
     kpn, vpn = np.asarray(kp, np.float32), np.asarray(vp, np.float32)
     tb, pos = np.asarray(table), np.asarray(positions)
     b, c, hq, d = qn.shape
-    g = kpn.shape[2]
+    g = kpn.shape[1]                                   # (P, G, page, D)
     rep = hq // g
     out = np.zeros_like(qn)
     for s in range(b):
-        kc = kpn[tb[s]].reshape(-1, g, d)              # (W·page, G, D)
-        vc = vpn[tb[s]].reshape(-1, g, d)
+        kc = kpn[tb[s]].transpose(0, 2, 1, 3).reshape(-1, g, d)  # (W·page,
+        vc = vpn[tb[s]].transpose(0, 2, 1, 3).reshape(-1, g, d)  #  G, D)
         cols = np.arange(kc.shape[0])
         for i in range(c):
             ok = cols <= pos[s, i]
@@ -275,7 +270,7 @@ class TestPrefillPoisoning:
             starts=[0, 3, 9], seed=31)
         # park every page past the chunk's reach on the scratch page,
         # like the engine's table for a partially-prefilled slot
-        page = kp.shape[1]
+        page = kp.shape[2]
         tb = np.asarray(table).copy()
         pos = np.asarray(positions)
         for s in range(tb.shape[0]):
@@ -499,15 +494,15 @@ class TestTensorParallel:
         # index g·rep + r), so sharding heads over 'model' keeps each
         # shard's q heads aligned with its kv heads.
         qs = P(None, None, "model", None)
-        ps = P(None, None, "model", None)          # (pages, page, Hkv, D)
+        ps = P(None, "model", None, None)          # (pages, Hkv, page, D)
 
         def local(qx, kpx, vpx, tbl, ln):
             return paged_attention(qx[:, 0], kpx, vpx, tbl, ln,
                                    interpret=True)[:, None]
 
-        fn = _shard_map(local, mesh=mesh,
-                        in_specs=(qs, ps, ps, P(None, None), P(None)),
-                        out_specs=qs, **_CHECK_KW)
+        fn = jax.shard_map(local, mesh=mesh,
+                           in_specs=(qs, ps, ps, P(None, None), P(None)),
+                           out_specs=qs, check_vma=False)
         fn_jit = jax.jit(fn)
         out = fn_jit(q, kp, vp, table, lengths)
         oracle = paged_decode(q, kp, vp, table, lengths, impl="gather")

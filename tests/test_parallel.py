@@ -68,6 +68,25 @@ class TestExecutionPlan:
                         jax.tree_util.tree_leaves(host)):
             np.testing.assert_array_equal(np.asarray(a), b)
 
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_init_params_born_on_plan(self, rng, dtype):
+        """The one-program init gives ``init_params``'s values, already on
+        the plan's shardings (XLA fuses the scale into the sampler, so
+        f32 leaves may differ in the last ulp)."""
+        import dataclasses
+        cfg = dataclasses.replace(TINY, dtype=dtype)
+        plan = local_plan("train")
+        born = plan.init_params(cfg, rng)
+        eager = init_params(cfg, rng)
+        for a, b, sh in zip(jax.tree_util.tree_leaves(born),
+                            jax.tree_util.tree_leaves(eager),
+                            jax.tree_util.tree_leaves(
+                                plan.param_shardings(cfg)), strict=True):
+            assert a.dtype == b.dtype and a.sharding == sh
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(b, np.float32),
+                                       rtol=2 ** -22, atol=0)
+
     def test_batch_shardings_reject_unknown_keys(self):
         plan = local_plan("train")
         with pytest.raises(ValueError, match="no batch sharding rule"):

@@ -237,6 +237,25 @@ class TestPrefixReuseEndToEnd:
             assert warm.prefix_hit_tokens == 10
             assert cold.prefix_hit_tokens == 0
 
+    def test_new_params_never_reuse_old_prefix_pages(self, tiny_params, rng):
+        """After ``update_params`` (a sampler's weight push) a repeated
+        prompt is prefilled under the new weights, exactly as a fresh
+        engine holding them serves it."""
+        rl = RLConfig(temperature=1.0, top_k=0, top_p=1.0, max_new_tokens=6,
+                      engine="continuous")
+        req = Request(rid=0, prompt=_prompt(np.random.default_rng(9), 10),
+                      params=SamplingParams.from_rl(rl))
+        new = init_params(TINY, jax.random.PRNGKey(1))
+        eng = _engine(tiny_params, _serve(), rl, rng)
+        eng.generate([req], key=rng)                 # caches the prefix
+        eng.update_params(new)
+        got = eng.generate([req], key=rng)[0]
+        want = _engine(new, _serve(), rl, rng).generate([req], key=rng)[0]
+        assert got.prefix_hit_tokens == 0
+        np.testing.assert_array_equal(got.tokens, want.tokens)
+        np.testing.assert_allclose(got.logps, want.logps, rtol=1e-5,
+                                   atol=1e-5)
+
     def test_cache_evicted_under_pool_pressure(self, tiny_params, rng):
         """With an exact-budget pool (no headroom), cached prefixes must
         be evicted to admit new work — and everything still finishes

@@ -235,7 +235,7 @@ SUBPROC = textwrap.dedent("""
     from repro.config import (ATTN, MLP, HeteroConfig, ModelConfig,
                               RLConfig, TrainConfig)
     from repro.models import init_params
-    from repro.parallel import ExecutionPlan, make_debug_mesh
+    from repro.parallel import ExecutionPlan, make_debug_mesh, make_mesh
     from repro.transport import ChunkSubscriber, Manifest, publish_params
 
     cfg = ModelConfig(name="tiny", family="dense", num_layers=2,
@@ -244,9 +244,9 @@ SUBPROC = textwrap.dedent("""
                       ffn_pattern=(MLP,), dtype="float32",
                       attn_impl="naive", remat=False, rope_theta=1e4)
     learner_plan = ExecutionPlan(mesh=make_debug_mesh(2, 2), mode="train")
-    plan_12 = ExecutionPlan(mesh=jax.make_mesh((1, 2), ("data", "model")),
+    plan_12 = ExecutionPlan(mesh=make_mesh((1, 2), ("data", "model")),
                             mode="serve")
-    plan_21 = ExecutionPlan(mesh=jax.make_mesh((2, 1), ("data", "model")),
+    plan_21 = ExecutionPlan(mesh=make_mesh((2, 1), ("data", "model")),
                             mode="serve")
 
     host = init_params(cfg, jax.random.PRNGKey(0))
