@@ -34,7 +34,6 @@ MODULES = [
     ("scaling", "benchmarks.scaling_bench"),
     ("sync", "benchmarks.sync_bench"),
     ("sentinel", "benchmarks.recompile_bench"),
-    ("obs", "benchmarks.obs_bench"),
     ("spec", "benchmarks.spec_bench"),
 ]
 
@@ -53,13 +52,11 @@ MODULES = [
 # bursty/overload open-loop load and emits BENCH_serve.json;
 # "sentinel" asserts the engine's pow2-bucketed executable bound under
 # the recompile sentinel (cold run <= bound, steady run compiles zero);
-# "obs" measures tracing overhead (disabled vs enabled serve drive) and
-# validates the exported Chrome traces parse (emits BENCH_obs.json);
 # "spec" A/Bs speculative decoding (prompt-lookup drafts + k-token paged
 # verification) against sequential decode and asserts the templated k=4
 # speedup/accept-rate bars (emits BENCH_spec.json)
 SMOKE_MODULES = ("fig2", "theory", "logprob", "decode", "scaling", "sync",
-                 "serve_lat", "sentinel", "obs", "spec")
+                 "serve_lat", "sentinel", "spec")
 
 
 # One headline metric per legacy BENCH_*.json artifact (newer artifacts
@@ -72,9 +69,6 @@ _HEADLINE_PICKERS = {
     "BENCH_serve.json": lambda d: {
         "metric": "poisson_slo_tokens_per_s",
         "value": d["poisson"]["slo"]["tokens_per_s"]},
-    "BENCH_obs.json": lambda d: {
-        "metric": "trace_overhead_pct",
-        "value": d["overhead"]["overhead_pct"]},
 }
 
 

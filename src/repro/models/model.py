@@ -338,7 +338,8 @@ def _embed(cfg: ModelConfig, params: Dict, tokens: jax.Array) -> jax.Array:
 def _logits(cfg: ModelConfig, params: Dict, x: jax.Array) -> jax.Array:
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = (x @ head).astype(jnp.float32)
     if (cfg.act_sharding is not None and logits.ndim == 3
             and cfg.act_sharding[1] == "model"):
         # Megatron-SP exit: gather sequence, keep vocab sharded on model.

@@ -16,14 +16,16 @@ zero-cost until :func:`configure` turns it on:
 gauges / bounded histograms; Prometheus text exposition); ``obs.trace``
 is the module-level :class:`Tracer` (``with obs.trace.span("prefill",
 slot=3): ...``). Instrumented call sites bind handles once and hold
-them forever; enabling/disabling flips live behavior in place.
+them forever; enabling/disabling flips live behavior in place. Spans
+also reach a running JAX profiler session, configured or not, as
+``repro.<track>.<name>`` annotations on the profiler's clock.
 """
 from __future__ import annotations
 
 from typing import Any, Optional
 
 from repro.obs.export import (chrome_trace, validate_chrome_trace,
-                              write_chrome_trace, write_jsonl)
+                              write_chrome_trace)
 from repro.obs.registry import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,
                                 MetricsRegistry, Reservoir)
 from repro.obs.trace import Span, Tracer
@@ -65,15 +67,9 @@ def export_chrome_trace(path: str, process_name: str = "repro") -> int:
     return write_chrome_trace(trace, path, process_name)
 
 
-def export_jsonl(path: str) -> int:
-    return write_jsonl(trace, path)
-
-
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "Reservoir",
     "Span", "Tracer", "DEFAULT_BUCKETS",
-    "chrome_trace", "write_chrome_trace", "write_jsonl",
-    "validate_chrome_trace",
-    "metrics", "trace", "configure", "enabled",
-    "export_chrome_trace", "export_jsonl",
+    "chrome_trace", "write_chrome_trace", "validate_chrome_trace",
+    "metrics", "trace", "configure", "enabled", "export_chrome_trace",
 ]

@@ -1,4 +1,4 @@
-"""Trace/metrics exporters: Chrome-trace (Perfetto) JSON and JSONL.
+"""Trace exporter: Chrome-trace (Perfetto) JSON.
 
 The tracer records events with float-second timestamps and logical
 *track* names; export maps tracks onto Chrome-trace ``tid`` integers
@@ -42,8 +42,6 @@ def chrome_trace(tracer: Tracer, process_name: str = "repro"
             ce["id"] = ev["id"]
         if "cat" in ev:
             ce["cat"] = ev["cat"]
-        if ev["ph"] == "i":
-            ce["s"] = "t"                # instant scope: thread
         if ev.get("args"):
             ce["args"] = {k: v for k, v in ev["args"].items()}
         out.append(ce)
@@ -64,16 +62,6 @@ def write_chrome_trace(tracer: Tracer, path: str,
     with open(path, "w") as f:
         json.dump(obj, f)
     return sum(1 for e in obj["traceEvents"] if e["ph"] != "M")
-
-
-def write_jsonl(tracer: Tracer, path: str) -> int:
-    """One JSON object per line, raw tracer vocabulary (float seconds,
-    track names) — the grep/pandas-friendly event log."""
-    events = tracer.events()
-    with open(path, "w") as f:
-        for ev in events:
-            f.write(json.dumps(ev) + "\n")
-    return len(events)
 
 
 def validate_chrome_trace(path: str) -> int:

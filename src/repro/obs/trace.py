@@ -1,11 +1,22 @@
 """Span tracer: one timeline vocabulary for live and simulated runs.
 
-``tracer.span("prefill", slot=3)`` opens a duration span on the current
-*track* (a logical timeline — "learner", "sampler-0", or the OS thread
-name by default); spans nest per track through a thread-local stack, and
-a span that raises still closes and records its duration plus the
-exception type. Events accumulate in a bounded ring buffer and export as
-Chrome-trace/Perfetto JSON or a JSONL event log (``repro.obs.export``).
+``tracer.span("prefill", track="engine", slot=3)`` opens a duration span
+on a *track* (a logical timeline — "learner", "sampler-0", or the
+thread's pinned track, else the OS thread name, by default); a span that
+raises still closes and records its duration plus the exception type.
+Events accumulate in a bounded ring buffer and export as
+Chrome-trace/Perfetto JSON (``repro.obs.export``).
+
+Two sinks, one call site. While a JAX profiler session is active
+(``jax.profiler.start_trace`` or ``jax.profiler.trace``), every span
+also enters a ``jax.profiler.TraceAnnotation`` named
+``repro.<track>.<name>`` (``repro.<name>`` when no track is passed), its
+args riding along as TraceMe metadata, so the span lands on the
+profiler's clock beside the device's events. That needs no
+``configure()``: the profiler session is the switch. The ring buffer
+records only while the tracer is enabled, on its own clock.
+``complete()`` and the async flows stay in the ring buffer alone: their
+times may be a simulator's, not wall time.
 
 The clock is pluggable: ``time.perf_counter`` for real runs, or any
 zero-arg callable — ``use_sim(sim)`` points it at an
@@ -15,8 +26,9 @@ discrete-event hetero run emits the *same* trace format as a live one
 work whose duration is known to the simulator rather than measured,
 ``complete(name, start_s, end_s)`` records an explicitly-timed span.
 
-Zero-cost contract: a disabled tracer's ``span()`` returns a shared
-no-op singleton — no allocation, no clock read; mutators check
+Zero-cost contract: with the tracer disabled and no profiler session,
+``span()`` returns a shared no-op singleton — no allocation, no clock
+read, one ``TraceAnnotation.is_enabled()`` check; mutators check
 ``enabled`` first. The ring buffer (``deque(maxlen=...)``) bounds memory
 on long-lived servers; the oldest events fall off.
 """
@@ -27,7 +39,18 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 DEFAULT_MAX_EVENTS = 200_000
+PROFILER_PREFIX = "repro."
+# true while a profiler session records host events
+_profiling = TraceAnnotation.is_enabled
+
+
+def profiler_name(name: str, track: Optional[str] = None) -> str:
+    """The name a span carries on the profiler's timeline."""
+    return (f"{PROFILER_PREFIX}{track}.{name}" if track
+            else PROFILER_PREFIX + name)
 
 
 class _NoopSpan:
@@ -46,25 +69,38 @@ _NOOP_SPAN = _NoopSpan()
 
 
 class Span:
-    """Open duration span; records a complete ("X") event on exit —
-    including the exceptional exit, which additionally tags the event
-    with the exception type so failed phases are visible in the trace."""
+    """Open duration span. Under a profiler session it holds a
+    ``TraceAnnotation`` for its extent; with the tracer enabled it
+    records a complete ("X") event on exit — including the exceptional
+    exit, which additionally tags the event with the exception type so
+    failed phases are visible in the trace."""
 
-    __slots__ = ("_tracer", "name", "args", "track", "t0")
+    __slots__ = ("_tracer", "name", "args", "track", "t0", "_record",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, track: Optional[str],
-                 args: Dict[str, Any]) -> None:
+                 args: Dict[str, Any], profiled: bool) -> None:
         self._tracer = tracer
         self.name = name
         self.track = track
         self.args = args
         self.t0 = 0.0
+        self._record = tracer.enabled
+        self._annotation = (TraceAnnotation(profiler_name(name, track),
+                                            **args) if profiled else None)
 
     def __enter__(self) -> "Span":
-        self.t0 = self._tracer.now()
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        if self._record:
+            self.t0 = self._tracer.now()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        if not self._record:
+            return False
         tr = self._tracer
         t1 = tr.now()
         args = self.args
@@ -75,23 +111,6 @@ class Span:
                   "dur": max(t1 - self.t0, 0.0),
                   "track": self.track or tr.current_track(), "args": args})
         return False                      # never swallow the exception
-
-
-class _TrackCtx:
-    __slots__ = ("_tracer", "_name")
-
-    def __init__(self, tracer: "Tracer", name: str) -> None:
-        self._tracer = tracer
-        self._name = name
-
-    def __enter__(self) -> None:
-        self._tracer._track_stack().append(self._name)
-
-    def __exit__(self, *exc) -> bool:
-        stack = self._tracer._track_stack()
-        if stack:
-            stack.pop()
-        return False
 
 
 class Tracer:
@@ -124,42 +143,26 @@ class Tracer:
         (anything with a float ``now`` attribute)."""
         self.clock = lambda: sim.now
 
-    # -- track (logical timeline) context ------------------------------
-    def _track_stack(self) -> List[str]:
-        stack = getattr(self._tls, "stack", None)
-        if stack is None:
-            stack = []
-            self._tls.stack = stack
-        return stack
-
+    # -- track (logical timeline) --------------------------------------
     def current_track(self) -> str:
-        stack = self._track_stack()
-        return stack[-1] if stack else threading.current_thread().name
-
-    def track(self, name: str) -> _TrackCtx:
-        """Context manager: spans opened inside land on track ``name``."""
-        return _TrackCtx(self, name)
+        return (getattr(self._tls, "track", None)
+                or threading.current_thread().name)
 
     def set_track(self, name: str) -> None:
         """Pin the current thread's default track (worker-loop entry)."""
-        self._tls.stack = [name]
+        self._tls.track = name
 
     # -- emitters --------------------------------------------------------
     def _emit(self, ev: Dict[str, Any]) -> None:
         self._events.append(ev)
 
     def span(self, name: str, track: Optional[str] = None, **args):
-        """Open a duration span (context manager). No-op when disabled."""
-        if not self.enabled:
+        """Open a duration span (context manager). No-op when the tracer
+        is disabled and no profiler session is active."""
+        profiled = _profiling()
+        if not (self.enabled or profiled):
             return _NOOP_SPAN
-        return Span(self, name, track, args)
-
-    def instant(self, name: str, track: Optional[str] = None,
-                **args) -> None:
-        if not self.enabled:
-            return
-        self._emit({"ph": "i", "name": name, "ts": self.now(),
-                    "track": track or self.current_track(), "args": args})
+        return Span(self, name, track, args, profiled)
 
     def complete(self, name: str, start_s: float, end_s: float,
                  track: Optional[str] = None, **args) -> None:

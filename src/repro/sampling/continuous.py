@@ -347,8 +347,8 @@ class ContinuousEngine:
         self._req_keys = np.zeros((num_slots, 2), np.uint32)  # threefry data
         self._results: Dict[int, GenerationResult] = {}
         # unified observability (repro.obs): handles bound once — each
-        # use is one enabled-check when the registry is off (the
-        # zero-cost contract obs_bench enforces on this hot path)
+        # use is one enabled-check when the registry is off, and a span
+        # one more check for a profiler session
         m = obs.metrics
         self._tr = obs.trace
         self._m_prefill_chunks = m.counter(
@@ -492,9 +492,30 @@ class ContinuousEngine:
     def step(self, now_s: Optional[float] = None) -> List[TokenEvent]:
         """One scheduler round: admit → one prefill chunk per prefilling
         slot → one decode chunk. Returns this round's token events
-        (streaming order: per request, in-completion order)."""
+        (streaming order: per request, in-completion order).
+
+        Each phase is a span on track ``engine`` (``repro.engine.*`` on
+        a profiler's timeline): ``step`` holds ``admit``, one
+        ``prefill`` per chunk, ``decode`` or ``verify`` (the chunk's
+        inputs and dispatch), ``sync`` (the host waiting for the chunk's
+        results) and ``commit`` (the per-slot token loop)."""
         now = time.perf_counter() if now_s is None else now_s
         events: List[TokenEvent] = []
+        with self._tr.span("step", track="engine"):
+            with self._tr.span("admit", track="engine"):
+                self._admit(now, events)
+            self._prefill_chunks()
+            dec = self.sched.decoding()
+            if not dec:
+                self._publish_gauges()
+            elif self.spec_k > 0:
+                self._spec_round(dec, now, events)
+            else:
+                self._decode_chunk(dec, now, events)
+        return events
+
+    def _admit(self, now: float, events: List[TokenEvent]) -> None:
+        """Admission, deadline expiry and shared-prefix copy-on-write."""
         sched = self.sched
         newly = sched.admit(now)
         for r in sched.drain_expired():
@@ -515,29 +536,31 @@ class ContinuousEngine:
                 f"({self.num_pages} pages) cannot fit the head request "
                 "even after prefix-cache eviction")
 
-        # chunked prefill: every prefilling slot advances one chunk per
-        # step, interleaved with the decode chunk below
+    def _prefill_chunks(self) -> None:
+        """Chunked prefill: every prefilling slot advances one chunk per
+        step, interleaved with the decode chunk."""
+        sched = self.sched
         for pref in [r for r in sched.slots
                      if r is not None and r.state == PREFILL]:
             c0 = pref.prefill_pos
             remaining = pref.prompt_len - c0
             cw = clamp_prefill_chunk(self.prefill_chunk,
                                      remaining) or remaining
-            chunk = pref.prompt[c0:c0 + cw]
-            if chunk.shape[0] < cw:                 # pad to fixed shape
-                chunk = np.concatenate(
-                    [chunk, np.full(cw - chunk.shape[0], PAD, np.int32)])
             # only pages reachable from this chunk's max position — the
             # gather inside the paged prefill branch scales with c0 + C,
             # not pool capacity. Padded-tail writes past the narrowed
             # width hit the same OOB-drop path as past the full width.
             width = _live_width(pages_for(c0 + cw, self.page_size),
                                 self.pages_per_slot)
-            page_row = jnp.asarray(
-                sched.block_table[pref.slot:pref.slot + 1, :width])
             with self._tr.span("prefill", track="engine", rid=pref.rid,
                                slot=pref.slot, start=c0, chunk=cw,
                                width=width):
+                chunk = pref.prompt[c0:c0 + cw]
+                if chunk.shape[0] < cw:             # pad to fixed shape
+                    chunk = np.concatenate(
+                        [chunk, np.full(cw - chunk.shape[0], PAD, np.int32)])
+                page_row = jnp.asarray(
+                    sched.block_table[pref.slot:pref.slot + 1, :width])
                 logits_c, self.pool = _prefill_chunk_jit(
                     self.cfg, self.params, self.pool, page_row,
                     jnp.asarray(chunk[None]), jnp.int32(c0), plan=self.plan)
@@ -561,14 +584,10 @@ class ContinuousEngine:
                         pref.pages[:pages_for(pref.prompt_len,
                                               self.page_size)])
 
-        dec = sched.decoding()
-        if not dec:
-            self._publish_gauges()
-            return events
-        if self.spec_k > 0:
-            self._spec_round(dec, now, events)
-            self._publish_gauges()
-            return events
+    def _decode_chunk(self, dec: List[GenRequest], now: float,
+                      events: List[TokenEvent]) -> None:
+        """``sync_every`` decode steps over every slot."""
+        sched = self.sched
         # non-decoding slots (empty, or mid-prefill) must scatter their
         # dead PAD writes into the scratch page — NOT position 0 of pages
         # a prefilling request has already filled. The table is narrowed
@@ -579,11 +598,11 @@ class ContinuousEngine:
             pages_for(int(self._pos[self._active].max()) + self.sync_every,
                       self.page_size),
             self.pages_per_slot)
-        bt = sched.block_table[:, :width].copy()
-        bt[~self._active] = SCRATCH_PAGE
         with self._tr.span("decode", track="engine",
                            slots=len(dec), chunk=self.sync_every,
                            width=width):
+            bt = sched.block_table[:, :width].copy()
+            bt[~self._active] = SCRATCH_PAGE
             toks, lps, self._last, self.pool = _decode_chunk_jit(
                 self.cfg, self.rl, self.params, self.pool, jnp.asarray(bt),
                 self._last, jnp.asarray(self._pos),
@@ -596,38 +615,55 @@ class ContinuousEngine:
         # deliberate sync point: the scheduler needs this chunk's tokens
         # on host for EOS recycling/admission — one sync per sync_every
         # decode steps, the amortization RA003 exists to protect
-        tok_np, lp_np = np.asarray(toks), np.asarray(lps)  # noqa: RA003
-        for r in dec:
-            for i in range(self.sync_every):
-                if r.gen_count >= r.max_new:
-                    break
-                t = int(tok_np[i, r.slot])
-                r.tokens.append(t)
-                r.logps.append(float(lp_np[i, r.slot]))
-                sched.stats["decode_slot_steps"] += 1
-                if r.gen_count == 1:
-                    r.t_first_token = now
-                events.append(TokenEvent(rid=r.rid, token=t,
-                                         logp=r.logps[-1],
-                                         index=r.gen_count - 1))
-                if t == EOS:
-                    break
-            self._pos[r.slot] = r.next_pos
-            self._gen[r.slot] = r.gen_count
-            reason = ""
-            if r.tokens and r.tokens[-1] == EOS:
-                reason = "eos"
-            elif r.gen_count >= r.max_new:
-                reason = "length"
-            if reason:
-                self._active[r.slot] = False
-                sched.finish(r, reason, now)
-                self._finish_result(r)
-                events.append(TokenEvent(rid=r.rid, token=-1, logp=0.0,
-                                         index=r.gen_count, finished=True,
-                                         finish_reason=reason))
-        self._publish_gauges()
-        return events
+        with self._tr.span("sync", track="engine"):
+            tok_np, lp_np = np.asarray(toks), np.asarray(lps)  # noqa: RA003
+        self._commit_chunk(dec, tok_np, lp_np, now, events)
+
+    def _commit_chunk(self, dec: List[GenRequest], tok_np: np.ndarray,
+                      lp_np: np.ndarray, now: float,
+                      events: List[TokenEvent]) -> None:
+        """Commit a multi-step chunk's tokens and log-probs
+        (``(sync_every, num_slots)``) to each decoding request, up to its
+        EOS or token budget."""
+        sched = self.sched
+        with self._tr.span("commit", track="engine"):
+            for r in dec:
+                for i in range(self.sync_every):
+                    if r.gen_count >= r.max_new:
+                        break
+                    t = int(tok_np[i, r.slot])
+                    r.tokens.append(t)
+                    r.logps.append(float(lp_np[i, r.slot]))
+                    sched.stats["decode_slot_steps"] += 1
+                    if r.gen_count == 1:
+                        r.t_first_token = now
+                    events.append(TokenEvent(rid=r.rid, token=t,
+                                             logp=r.logps[-1],
+                                             index=r.gen_count - 1))
+                    if t == EOS:
+                        break
+                self._settle(r, now, events)
+            self._publish_gauges()
+
+    def _settle(self, r: GenRequest, now: float,
+                events: List[TokenEvent]) -> None:
+        """After a commit: the slot's position and draw counter, and the
+        request's finish on EOS or its token budget."""
+        self._pos[r.slot] = r.next_pos
+        self._gen[r.slot] = r.gen_count
+        reason = ""
+        if r.tokens and r.tokens[-1] == EOS:
+            reason = "eos"
+        elif r.gen_count >= r.max_new:
+            reason = "length"
+        if reason:
+            self._active[r.slot] = False
+            self._spec_ema.pop(r.rid, None)
+            self.sched.finish(r, reason, now)
+            self._finish_result(r)
+            events.append(TokenEvent(rid=r.rid, token=-1, logp=0.0,
+                                     index=r.gen_count, finished=True,
+                                     finish_reason=reason))
 
     # ------------------------------------------------------------------
     def _dev(self, name: str, arr: np.ndarray) -> jax.Array:
@@ -715,10 +751,10 @@ class ContinuousEngine:
         width = _live_width(
             pages_for(int(packed[:, w + 2].max()) + w, self.page_size),
             self.pages_per_slot)
-        bt = sched.block_table[:, :width].copy()
-        bt[~self._active] = SCRATCH_PAGE
         with self._tr.span("verify", track="engine", slots=len(dec),
                            window=w, width=width):
+            bt = sched.block_table[:, :width].copy()
+            bt[~self._active] = SCRATCH_PAGE
             iout, fout, self.pool = _verify_chunk_jit(
                 self.cfg, self.rl, self.params, self.pool,
                 self._dev("bt.verify", bt), jnp.asarray(packed),
@@ -730,8 +766,20 @@ class ContinuousEngine:
         self._m_decode_steps.inc(1)
         # two deliberate syncs per verify round (packed int/f32 results),
         # the decode chunk's twin
-        io = np.asarray(iout)                              # noqa: RA003
-        fo = np.asarray(fout)                              # noqa: RA003
+        with self._tr.span("sync", track="engine"):
+            io = np.asarray(iout)                          # noqa: RA003
+            fo = np.asarray(fout)                          # noqa: RA003
+        with self._tr.span("commit", track="engine"):
+            self._commit_verify(dec, per_slot, io, fo, w, now, events)
+            self._publish_gauges()
+
+    def _commit_verify(self, dec: List[GenRequest],
+                       per_slot: Dict[int, tuple], io: np.ndarray,
+                       fo: np.ndarray, w: int, now: float,
+                       events: List[TokenEvent]) -> None:
+        """Commit a verify round's emitted tokens per slot and update the
+        drafting gate and the acceptance counters."""
+        sched = self.sched
         tok_np, ne, na = io[:, :w], io[:, w], io[:, w + 1]
         lp_np = fo[:, :w]
         if self.spec_rescore:
@@ -759,21 +807,7 @@ class ContinuousEngine:
                 events.append(TokenEvent(rid=r.rid, token=t,
                                          logp=r.logps[-1],
                                          index=r.gen_count - 1))
-            self._pos[s] = r.next_pos
-            self._gen[s] = r.gen_count
-            reason = ""
-            if r.tokens and r.tokens[-1] == EOS:
-                reason = "eos"
-            elif r.gen_count >= r.max_new:
-                reason = "length"
-            if reason:
-                self._active[s] = False
-                self._spec_ema.pop(r.rid, None)
-                sched.finish(r, reason, now)
-                self._finish_result(r)
-                events.append(TokenEvent(rid=r.rid, token=-1, logp=0.0,
-                                         index=r.gen_count, finished=True,
-                                         finish_reason=reason))
+            self._settle(r, now, events)
         sched.stats["drafted_tokens_total"] += drafted
         sched.stats["accepted_tokens_total"] += accepted
         sched.stats["draft_hits"] += hits
@@ -810,10 +844,10 @@ class ContinuousEngine:
         width = _live_width(
             pages_for(int(pos0.max()) + self.sync_every, self.page_size),
             self.pages_per_slot)
-        bt = sched.block_table[:, :width].copy()
-        bt[~self._active] = SCRATCH_PAGE
         with self._tr.span("decode", track="engine", slots=len(dec),
                            chunk=self.sync_every, width=width):
+            bt = sched.block_table[:, :width].copy()
+            bt[~self._active] = SCRATCH_PAGE
             toks, lps, self.pool = _spec_decode_chunk_jit(
                 self.cfg, self.rl, self.params, self.pool,
                 self._dev("bt.fallback", bt), jnp.asarray(pending),
@@ -825,37 +859,9 @@ class ContinuousEngine:
         sched.stats["spec_fallback_chunks"] += 1
         self._m_decode_steps.inc(self.sync_every)
         # one deliberate sync per chunk (the decode path's amortization)
-        tok_np, lp_np = np.asarray(toks), np.asarray(lps)  # noqa: RA003
-        for r in dec:
-            for i in range(self.sync_every):
-                if r.gen_count >= r.max_new:
-                    break
-                t = int(tok_np[i, r.slot])
-                r.tokens.append(t)
-                r.logps.append(float(lp_np[i, r.slot]))
-                sched.stats["decode_slot_steps"] += 1
-                if r.gen_count == 1:
-                    r.t_first_token = now
-                events.append(TokenEvent(rid=r.rid, token=t,
-                                         logp=r.logps[-1],
-                                         index=r.gen_count - 1))
-                if t == EOS:
-                    break
-            self._pos[r.slot] = r.next_pos
-            self._gen[r.slot] = r.gen_count
-            reason = ""
-            if r.tokens and r.tokens[-1] == EOS:
-                reason = "eos"
-            elif r.gen_count >= r.max_new:
-                reason = "length"
-            if reason:
-                self._active[r.slot] = False
-                self._spec_ema.pop(r.rid, None)
-                sched.finish(r, reason, now)
-                self._finish_result(r)
-                events.append(TokenEvent(rid=r.rid, token=-1, logp=0.0,
-                                         index=r.gen_count, finished=True,
-                                         finish_reason=reason))
+        with self._tr.span("sync", track="engine"):
+            tok_np, lp_np = np.asarray(toks), np.asarray(lps)  # noqa: RA003
+        self._commit_chunk(dec, tok_np, lp_np, now, events)
 
     # ------------------------------------------------------------------
     def generate(self, requests: Sequence[Request],

@@ -11,8 +11,7 @@ import numpy as np
 import pytest
 
 from repro.obs import (MetricsRegistry, Reservoir, Tracer, chrome_trace,
-                       validate_chrome_trace, write_chrome_trace,
-                       write_jsonl)
+                       validate_chrome_trace, write_chrome_trace)
 from repro.obs.registry import MAX_CHILDREN_PER_FAMILY
 from repro.serving.api import GenerationResult
 from repro.serving.telemetry import ServeTelemetry, percentile
@@ -223,7 +222,6 @@ class TestTracer:
         with s1:
             pass
         assert len(tr) == 0
-        tr.instant("i")
         tr.complete("c", 0.0, 1.0)
         tr.async_begin("f", 1)
         assert len(tr) == 0
@@ -239,10 +237,9 @@ class TestTracer:
 
     def test_span_nesting_orders_child_first(self):
         tr = Tracer(enabled=True)
-        with tr.track("learner"):
-            with tr.span("outer"):
-                with tr.span("inner"):
-                    pass
+        with tr.span("outer", track="learner"):
+            with tr.span("inner", track="learner"):
+                pass
         inner, outer = tr.events()
         assert inner["name"] == "inner" and outer["name"] == "outer"
         assert inner["track"] == outer["track"] == "learner"
@@ -257,13 +254,6 @@ class TestTracer:
         (ev,) = tr.events()
         assert ev["args"]["error"] == "RuntimeError"
         assert ev["dur"] >= 0.0             # still closed with a duration
-
-    def test_track_stack_pops_on_exit(self):
-        tr = Tracer(enabled=True)
-        with tr.track("a"):
-            with tr.track("b"):
-                assert tr.current_track() == "b"
-            assert tr.current_track() == "a"
 
     def test_sim_clock_drives_timestamps(self):
         tr = Tracer(enabled=True)
@@ -296,9 +286,103 @@ class TestTracer:
     def test_ring_buffer_bounds_memory(self):
         tr = Tracer(enabled=True, max_events=8)
         for i in range(100):
-            tr.instant(f"i{i}")
+            tr.complete(f"i{i}", 0.0, 1.0)
         assert len(tr) == 8
         assert tr.events()[0]["name"] == "i92"   # oldest fell off
+
+
+# ---------------------------------------------------------------------------
+def _profiled_host_events(log_dir):
+    """(name, stats) of every host event in the newest profile under
+    ``log_dir``."""
+    import glob
+    import os
+
+    import jax
+    f = sorted(glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)[-1]
+    pd = jax.profiler.ProfileData.from_file(f)
+    return [(e.name, dict(e.stats)) for p in pd.planes
+            if p.name.startswith("/host:") for line in p.lines
+            for e in line.events]
+
+
+class TestProfilerSink:
+    """While a JAX profiler session runs, spans also land on its
+    timeline as ``repro.<track>.<name>`` annotations."""
+
+    def test_profiler_gets_span_with_obs_off(self, tmp_path):
+        import jax
+        tr = Tracer(enabled=False)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            span = tr.span("decode", track="engine", slots=3, width=8)
+            with span:
+                pass
+            with tr.span("bare"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        assert span is not tr.span("decode")   # a real span while profiling
+        assert len(tr) == 0                      # the ring buffer stays off
+        ev = dict(_profiled_host_events(tmp_path))
+        assert ev["repro.engine.decode"] == {"slots": 3, "width": 8}
+        assert "repro.bare" in ev
+
+    def test_both_sinks_with_obs_on(self, tmp_path):
+        import jax
+        tr = Tracer(enabled=True)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tr.span("step", track="engine"):
+                with tr.span("sync", track="engine"):
+                    pass
+        finally:
+            jax.profiler.stop_trace()
+        assert [e["name"] for e in tr.events()] == ["sync", "step"]
+        names = [n for n, _ in _profiled_host_events(tmp_path)]
+        assert "repro.engine.step" in names and "repro.engine.sync" in names
+
+    def test_noop_once_profiler_stops(self, tmp_path):
+        import jax
+        tr = Tracer(enabled=False)
+        jax.profiler.start_trace(str(tmp_path))
+        jax.profiler.stop_trace()
+        assert tr.span("a") is tr.span("b", track="engine", slot=1)
+
+    def test_span_error_still_closes_annotation(self, tmp_path):
+        import jax
+        tr = Tracer(enabled=False)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with pytest.raises(RuntimeError):
+                with tr.span("commit", track="engine"):
+                    raise RuntimeError("boom")
+        finally:
+            jax.profiler.stop_trace()
+        assert "repro.engine.commit" in dict(_profiled_host_events(tmp_path))
+
+    def test_profiler_names(self):
+        from repro.obs.trace import profiler_name
+        assert profiler_name("step", "engine") == "repro.engine.step"
+        assert profiler_name("learner_step") == "repro.learner_step"
+
+    def test_pinned_track_names_ring_events(self):
+        tr = Tracer(enabled=True)
+        out = {}
+
+        def worker():
+            tr.set_track("sampler-1")
+            with tr.span("sampler_generate"):
+                pass
+            out["track"] = tr.current_track()
+
+        t = threading.Thread(target=worker, name="t0")
+        t.start()
+        t.join()
+        assert out["track"] == "sampler-1"
+        assert tr.events()[0]["track"] == "sampler-1"
+        assert tr.current_track() == threading.current_thread().name
 
 
 # ---------------------------------------------------------------------------
@@ -309,12 +393,10 @@ class TestExport:
             s = _FakeSim()
             tr.use_sim(s)
             s.now = 1.0
-        with tr.track("learner"):
-            with tr.span("learner_step", step=1):
-                pass
-        with tr.track("sampler-0"):
-            with tr.span("sampler_generate"):
-                pass
+        with tr.span("learner_step", track="learner", step=1):
+            pass
+        with tr.span("sampler_generate", track="sampler-0"):
+            pass
         fid = tr.next_flow_id()
         tr.async_begin("chunk_transfer", fid, ts=0.1)
         tr.async_end("chunk_transfer", fid, ts=0.2)
@@ -358,14 +440,6 @@ class TestExport:
                 {"name": "a", "ph": "b", "ts": 0.0}]}, f)
         with pytest.raises(ValueError):
             validate_chrome_trace(p)        # async event missing id
-
-    def test_jsonl_export(self, tmp_path):
-        p = str(tmp_path / "events.jsonl")
-        n = write_jsonl(self._traced(), p)
-        with open(p) as f:
-            lines = [json.loads(ln) for ln in f]
-        assert len(lines) == n == 4
-        assert lines[0]["name"] == "learner_step"
 
 
 # ---------------------------------------------------------------------------
