@@ -68,7 +68,9 @@ def paged_decode(q, kp, vp, page_table, lengths, *, kind: str = "causal",
     q (B, 1, Hq, D) one query token per slot (``decode_attention``'s
     layout); kp/vp (num_pages, Hkv, page_size, D) page pools; page_table
     (B, npages); lengths (B,) valid tokens per slot (current token's k/v
-    already scattered). Returns (B, 1, Hq, D).
+    already scattered). Returns (B, 1, Hq, D). From the model's block
+    scan, kp/vp are every layer's stacked pools folded to (L·P, ...) and
+    page_table points into the layer's slab (``layer_slab``).
 
     ``impl`` selects the backend (``ModelConfig.paged_attn_impl``):
       - "gather" (the ModelConfig default): materialize the logical
@@ -123,7 +125,8 @@ def paged_prefill(q, kp, vp, page_table, positions, *, kind: str = "causal",
 
     q (B, C, Hq, D) one C-token query chunk per slot; kp/vp
     (num_pages, Hkv, page_size, D) page pools (the chunk's k/v already
-    scattered in); page_table (B, npages); positions (B, C) absolute
+    scattered in; from the block scan, the folded stacked pools of
+    ``layer_slab``); page_table (B, npages); positions (B, C) absolute
     query positions, ``starts[slot] + arange(C)`` — contiguous per slot.
     Returns (B, C, Hq, D).
 
@@ -171,6 +174,24 @@ def paged_prefill(q, kp, vp, page_table, positions, *, kind: str = "causal",
                           interpret=interp)
 
 
+def layer_slab(kp, vp, page_table, layer):
+    """Address layer ``layer`` of stacked pools in place.
+
+    kp/vp (L, P, Hkv, page, D) fold to (L·P, Hkv, page, D), a reshape
+    that copies nothing, and the table's physical pages offset by
+    layer·P so they index the layer's slab. Every paged backend then
+    reads exactly the pages it would read from that layer's own pool.
+    ``layer`` is an int32 scalar, or an array that broadcasts against
+    ``page_table``. Returns (kp, vp, table).
+    """
+    lyr, pool_pages = kp.shape[:2]
+
+    def fold(pool):
+        return pool.reshape((lyr * pool_pages,) + pool.shape[2:])
+    return (fold(kp), fold(vp),
+            page_table.astype(jnp.int32) + layer * pool_pages)
+
+
 def _fold_layers(q, kp, vp, page_table, lengths):
     """Fold a leading layer axis into the slot axis so ONE kernel launch
     serves every layer's pools.
@@ -178,17 +199,15 @@ def _fold_layers(q, kp, vp, page_table, lengths):
     q (L, B, ...), kp/vp (L, P, Hkv, page, D), page_table (B, W),
     lengths (B,) → per-layer operands stacked along slots: the pools
     concatenate to (L·P, ...), and layer l's table rows offset by l·P so
-    they index the l-th pool slab. Slots never mix across grid steps, so
-    the folded launch is bit-exact vs L per-layer launches — it just
-    amortizes one grid setup and one scalar-prefetch DMA over all
-    layers instead of paying them L times.
+    they index the l-th pool slab (``layer_slab``). Slots never mix
+    across grid steps, so the folded launch is bit-exact vs L per-layer
+    launches — it just amortizes one grid setup and one scalar-prefetch
+    DMA over all layers instead of paying them L times.
     """
-    lyr, pool_pages = q.shape[0], kp.shape[1]
-    b = q.shape[1]
-    kpf = kp.reshape((lyr * pool_pages,) + kp.shape[2:])
-    vpf = vp.reshape((lyr * pool_pages,) + vp.shape[2:])
-    offs = (jnp.arange(lyr, dtype=jnp.int32) * pool_pages)[:, None, None]
-    tablef = (page_table.astype(jnp.int32)[None] + offs).reshape(lyr * b, -1)
+    lyr, b = q.shape[0], q.shape[1]
+    layers = jnp.arange(lyr, dtype=jnp.int32)[:, None, None]
+    kpf, vpf, tablef = layer_slab(kp, vp, page_table, layers)
+    tablef = tablef.reshape(lyr * b, -1)
     lengthsf = jnp.broadcast_to(lengths, (lyr,) + lengths.shape
                                 ).reshape(lyr * b)
     qf = q.reshape((lyr * b,) + q.shape[2:])

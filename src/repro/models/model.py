@@ -43,14 +43,16 @@ def _project_qkv(cfg: ModelConfig, p: Dict, xq: jax.Array,
 def _self_attn(cfg: ModelConfig, p: Dict, x: jax.Array, *, kind: str,
                positions: jax.Array, cache: Optional[Dict], pos,
                bidir: bool = False, page_table: Optional[jax.Array] = None,
-               record: bool = False):
+               block=None, record: bool = False):
     """Self-attention sub-layer body (input already normed).
 
     Returns (out, new_cache). In decode mode (pos is not None) x is
     (B,1,d) and the cache k/v are updated in place at ``pos``. When the
     cache is *paged* (holds "kp"/"vp" page pools and ``page_table`` maps
     (slot, logical_page) -> physical page), both chunked prefill and
-    decode go through the paged scatter/gather path instead.
+    decode go through the paged scatter/gather path instead: the pools
+    are the whole stacked (num_blocks, P, Hkv, page, D) pools the block
+    scan carries, and this layer owns slab ``block`` of them.
 
     ``record=True`` (paged chunked-prefill path only — the speculative
     verification forward) returns a third element: the post-rope queries
@@ -76,7 +78,7 @@ def _self_attn(cfg: ModelConfig, p: Dict, x: jax.Array, *, kind: str,
     if cache is not None and "kp" in cache:               # paged KV cache
         b, sq = x.shape[0], x.shape[1]
         kp, vp = cache["kp"], cache["vp"]
-        page_size = kp.shape[2]                           # (P, Hkv, page, D)
+        page_size = kp.shape[3]                       # (nb, P, Hkv, page, D)
         page = positions // page_size                     # (B, Sq) logical
         off = positions % page_size
         # logical pages past the block-table width (only padded prefill
@@ -84,15 +86,18 @@ def _self_attn(cfg: ModelConfig, p: Dict, x: jax.Array, *, kind: str,
         # below drops the write instead of clamping onto a live page
         phys = jnp.take_along_axis(page_table, page, axis=1, mode="fill",
                                    fill_value=jnp.iinfo(jnp.int32).min)
-        kp = kp.at[phys, :, off].set(k.astype(kp.dtype))
-        vp = vp.at[phys, :, off].set(v.astype(vp.dtype))
+        kp = kp.at[block, phys, :, off].set(k.astype(kp.dtype))
+        vp = vp.at[block, phys, :, off].set(v.astype(vp.dtype))
+        # every backend attends the layer's slab in place, through the
+        # folded pools and a table offset by block·P
+        from repro.kernels.ops import layer_slab, paged_decode, paged_prefill
+        kpl, vpl, table = layer_slab(kp, vp, page_table, block)
         if sq == 1:                                       # decode
             # hot loop: attend the pools in place (or via the bit-exact
             # gather fallback) — repro.kernels.ops.paged_decode. The
             # engine narrows page_table to the live high-water mark, so
             # every impl scales with context, not pool capacity.
-            from repro.kernels.ops import paged_decode
-            o = paged_decode(q, kp, vp, page_table, positions[:, 0] + 1,
+            o = paged_decode(q, kpl, vpl, table, positions[:, 0] + 1,
                              kind=mask_kind, window=cfg.sliding_window,
                              softcap=cfg.attn_softcap,
                              impl=cfg.paged_attn_impl)
@@ -103,8 +108,7 @@ def _self_attn(cfg: ModelConfig, p: Dict, x: jax.Array, *, kind: str,
             # page_table to pages_for(c0 + C), so the gather view is
             # bounded by the chunk's pow2 width bucket; the kernel/ref
             # paths never materialize it at all.
-            from repro.kernels.ops import paged_prefill
-            o = paged_prefill(q, kp, vp, page_table, positions,
+            o = paged_prefill(q, kpl, vpl, table, positions,
                               kind=mask_kind, window=cfg.sliding_window,
                               softcap=cfg.attn_softcap,
                               impl=cfg.paged_attn_impl,
@@ -191,7 +195,8 @@ def _ffn(cfg: ModelConfig, kind: str, p: Dict, x: jax.Array,
 
 def _apply_layer(cfg: ModelConfig, idx_in_block: int, p: Dict, x: jax.Array,
                  *, positions, memory, cache, pos, aux,
-                 encoder: bool = False, page_table=None, record: bool = False):
+                 encoder: bool = False, page_table=None, block=None,
+                 record: bool = False):
     kind = ATTN if encoder else cfg.block_pattern[idx_in_block]
     ffn_kind = MLP if encoder else cfg.ffn_kind(idx_in_block)
     new_cache: Dict[str, Any] = {}
@@ -202,7 +207,7 @@ def _apply_layer(cfg: ModelConfig, idx_in_block: int, p: Dict, x: jax.Array,
         res = _self_attn(cfg, p["attn"], h, kind=kind, positions=positions,
                          cache=None if cache is None else cache.get("self"),
                          pos=pos, bidir=encoder, page_table=page_table,
-                         record=record)
+                         block=block, record=record)
         o, c = res[0], res[1]
         if record:
             tape = res[2]
@@ -261,44 +266,62 @@ def _aux_init(cfg: ModelConfig) -> Dict[str, jax.Array]:
     return {}
 
 
+def _is_paged(cache: Optional[Dict]) -> bool:
+    """A paged pool (``repro.sampling.paged_cache``) holds "kp"/"vp"
+    page pools where a dense cache holds per-row "k"/"v"."""
+    return cache is not None and any(
+        "kp" in lc.get("self", {}) for lc in cache.values())
+
+
 def _run_blocks(cfg: ModelConfig, blocks: Dict, x: jax.Array, *,
                 positions, memory, cache, pos, encoder=False,
                 page_table=None, record=False):
     """Scan super-blocks. cache (if given) is a pytree stacked on axis 0
-    matching ``blocks``; returns (x, new_cache, aux). With ``record``
-    (paged-prefill path only) aux additionally carries ``q_tape`` /
-    ``o_tape`` — per-layer post-rope queries and attention outputs,
-    (L, B, S, Hq, Dh) with L enumerated block-major (the same order
-    ``kernels.ops._fold_layers`` folds pool leaves)."""
+    matching ``blocks``; returns (x, new_cache, aux).
+
+    A dense cache rides the scan as ``xs``/``ys``: each block reads and
+    returns its own slice. A paged cache rides it whole in the carry and
+    ``xs`` gives the block index: each layer scatters its K/V into, and
+    attends, its slab of the stacked (nb, P, Hkv, page, D) pools in
+    place, so no block copies a whole layer's pool in or out.
+
+    With ``record`` (paged-prefill path only) aux additionally carries
+    ``q_tape`` / ``o_tape`` — per-layer post-rope queries and attention
+    outputs, (L, B, S, Hq, Dh) with L enumerated block-major (the same
+    order ``kernels.ops._fold_layers`` folds pool leaves)."""
     aux0 = {} if encoder else _aux_init(cfg)
-    n_layers = cfg.encoder_layers if encoder else len(cfg.block_pattern)
+    paged = _is_paged(cache)
 
     def body(carry, xs):
-        x, aux = carry
+        x, aux, pools = carry
         x = _constrain(cfg, x)
-        bp, bc = xs
+        bp, bx = xs
+        bc, block = (pools, bx) if paged else (bx, None)
         new_bc = {}
         tapes = []
-        for i in range(n_layers if encoder else len(cfg.block_pattern)):
-            key = f"layer_{i}" if not encoder else "layer"
-            lp = bp[key] if not encoder else bp
-            lc = None if bc is None else bc.get(f"layer_{i}")
-            out = _apply_layer(cfg, i, lp, x, positions=positions,
+        for i in range(len(cfg.block_pattern)):
+            key = f"layer_{i}"
+            lc = None if bc is None else bc.get(key)
+            out = _apply_layer(cfg, i, bp[key], x, positions=positions,
                                memory=memory, cache=lc, pos=pos,
-                               aux=aux, encoder=encoder,
-                               page_table=page_table, record=record)
+                               aux=aux, page_table=page_table, block=block,
+                               record=record)
             x, nc, aux = out[0], out[1], out[2]
             if record:
                 tapes.append(out[3])
             if bc is not None:
-                new_bc[f"layer_{i}"] = nc
-        ys = new_bc if bc is not None else 0
+                new_bc[key] = nc
+        ys = 0
+        if paged:
+            pools = new_bc
+        elif bc is not None:
+            ys = new_bc
         if record:
             # stack the period's layers -> (P, B, S, Hq, Dh); the scan
             # stacks blocks in front -> (nb, P, ...)
             ys = (ys, {k: jnp.stack([t[k] for t in tapes])
                        for k in ("q", "o")})
-        return (x, aux), ys
+        return (x, aux, pools), ys
 
     if encoder:
         # encoder blocks are a single stacked layer dict
@@ -313,15 +336,20 @@ def _run_blocks(cfg: ModelConfig, blocks: Dict, x: jax.Array, *,
         return x, None, aux
 
     fn = jax.checkpoint(body) if cfg.remat else body
-    (x, aux), ys = jax.lax.scan(fn, (x, aux0), (blocks, cache))
+    if paged:
+        carry = (x, aux0, cache)
+        xs = (blocks, jnp.arange(cfg.num_blocks, dtype=jnp.int32))
+    else:
+        carry, xs = (x, aux0, None), (blocks, cache)
+    (x, aux, pools), ys = jax.lax.scan(fn, carry, xs)
     if record:
-        new_cache, tape = ys
+        ys, tape = ys
         for k, name in (("q", "q_tape"), ("o", "o_tape")):
             t = tape[k]                      # (nb, P, B, S, Hq, Dh)
             aux[name] = t.reshape((-1,) + t.shape[2:])
-    else:
-        new_cache = ys
-    return x, (new_cache if cache is not None else None), aux
+    if cache is None:
+        return x, None, aux
+    return x, (pools if paged else ys), aux
 
 
 # --------------------------------------------------------------------------

@@ -6,9 +6,10 @@ the whole generation. Here the sequence axis is instead carved into
 fixed-size *pages* owned by a global pool:
 
 - **page pools** — per attention layer, ``kp``/``vp`` of shape
-  ``(num_blocks, num_pages, Hkv, page_size, head_dim)`` (stacked on the
-  scanned super-block axis exactly like the dense cache, so the model's
-  block scan is unchanged);
+  ``(num_blocks, num_pages, Hkv, page_size, head_dim)``, stacked on the
+  scanned super-block axis like the dense cache. The model's block scan
+  carries them whole and each block scatters into and attends its own
+  slab in place (``models/model.py::_run_blocks``);
 - **block table** — ``(num_slots, pages_per_slot)`` int32 mapping a decode
   slot's logical page to a physical page. Logical position ``p`` of slot
   ``s`` lives at ``pool[table[s, p // page_size], p % page_size]``;

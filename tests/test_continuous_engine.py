@@ -3,15 +3,19 @@ slot/page recycling, allocator invariants, and architecture fallback."""
 import warnings
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _hlo import whole_pool_slices
 from repro.config import ModelConfig, RLConfig, ATTN, LOCAL, MAMBA, MLP, NONE
 from repro.sampling import (ContinuousScheduler, GenRequest, PageAllocator,
                             generate, generate_continuous, pages_for)
+from repro.sampling import continuous as cont
+from repro.sampling.paged_cache import init_paged_pool
 from repro.sampling.scheduler import DONE
 from repro.data.tasks import EOS
-from repro.models import init_params
+from repro.models import abstract_params, init_params
 
 TINY = ModelConfig(name="tiny", family="dense", num_layers=2, d_model=64,
                    num_heads=4, num_kv_heads=2, d_ff=128, vocab_size=32,
@@ -215,3 +219,36 @@ class TestFallback:
         with pytest.raises(TypeError, match="num_slots"):
             generate(TINY, rl, init_params(TINY, rng),
                      np.full((2, 5), 3), rng, num_slots=4)
+
+
+class TestCompiledPrograms:
+    """The block scan carries the stacked page pools: no compiled engine
+    program slices a whole layer's pool out of them or writes one back."""
+
+    PAGES, PAGE, SLOTS, WIDTH = 37, 4, 3, 8
+
+    def _lower(self, program):
+        cfg = GQA_LOCAL                       # 2 blocks of (ATTN, LOCAL)
+        params = abstract_params(cfg)
+        pool = jax.eval_shape(lambda: init_paged_pool(cfg, self.PAGES,
+                                                      self.PAGE))
+        sds = jax.ShapeDtypeStruct
+        n = self.SLOTS
+        if program == "decode":
+            return cont._decode_chunk_jit.lower(
+                cfg, RLConfig(), params, pool, sds((n, self.WIDTH), jnp.int32),
+                sds((n, cfg.padded_vocab), jnp.float32), sds((n,), jnp.int32),
+                sds((n,), jnp.bool_), sds((n, 2), jnp.uint32),
+                sds((n,), jnp.int32), sds((n,), jnp.int32),
+                vocab_limit=20, sync_every=2)
+        return cont._prefill_chunk_jit.lower(
+            cfg, params, pool, sds((1, self.WIDTH), jnp.int32),
+            sds((1, 6), jnp.int32), sds((), jnp.int32))
+
+    @pytest.mark.parametrize("program", ["decode", "prefill"])
+    def test_no_whole_layer_pool_slices(self, program):
+        hlo = self._lower(program).compile().as_text()
+        layer_pool = (self.PAGES, GQA_LOCAL.num_kv_heads, self.PAGE,
+                      GQA_LOCAL.head_dim)
+        assert f"[{GQA_LOCAL.num_blocks},{self.PAGES}," in hlo
+        assert whole_pool_slices(hlo, layer_pool) == []

@@ -461,6 +461,77 @@ class TestEngineBackends:
         np.testing.assert_array_equal(np.asarray(cached["completions"]),
                                       np.asarray(plain["completions"]))
 
+    @pytest.mark.parametrize("program", ["prefill", "decode"])
+    @pytest.mark.parametrize("impl", ["gather", "ref", "pallas"])
+    def test_carried_pool_scan_bit_exact(self, impl, program):
+        """The block scan carries the stacked pools and each block works
+        on its slab in place. Against a scan that slices each block's
+        slab in and writes it back out (the layout it replaced), every
+        backend gives the same logits and the same pools, bit for bit.
+        The pools hold random values, so reading another block's slab
+        would show; the last slot's positions run past the table, so its
+        writes drop."""
+        from repro.models import decode_step, forward
+        from repro.models.model import _apply_layer, _embed, _logits
+        cfg = dataclasses.replace(GQA_LOCAL, num_layers=6,
+                                  paged_attn_impl=impl)      # 3 blocks
+        params = init_params(cfg, jax.random.PRNGKey(3))
+        nb, npool, page, width = cfg.num_blocks, 13, 4, 4
+        ks = jax.random.split(jax.random.PRNGKey(4), 2 * len(
+            cfg.block_pattern))
+        pool = {f"layer_{i}": {"self": {
+            name: jax.random.normal(
+                ks[2 * i + j], (nb, npool, cfg.num_kv_heads, page,
+                                cfg.head_dim))
+            for j, name in enumerate(("kp", "vp"))}}
+            for i in range(len(cfg.block_pattern))}
+        table = jnp.asarray(np.random.default_rng(5).permutation(
+            np.arange(1, npool))[:3 * width].reshape(3, width), jnp.int32)
+        tokens = jnp.asarray([[5, 7, 9, 11], [4, 6, 8, 10], [3, 12, 13, 14]])
+        if program == "prefill":
+            positions = jnp.asarray([0, 6, 14])[:, None] + jnp.arange(4)
+        else:
+            tokens, positions = tokens[:, :1], jnp.asarray([[3], [9], [16]])
+        pos = positions[:, 0] if program == "decode" else None
+
+        @jax.jit
+        def carried(pool):
+            if program == "decode":
+                return decode_step(cfg, params, pool, tokens[:, 0], pos,
+                                   page_table=table)
+            logits, pool, _ = forward(cfg, params, tokens,
+                                      positions=positions, cache=pool,
+                                      page_table=table)
+            return logits, pool
+
+        @jax.jit
+        def sliced(pool):
+            def body(x, xs):
+                bp, bc = xs
+                new = {}
+                for i in range(len(cfg.block_pattern)):
+                    key = f"layer_{i}"
+                    slab = jax.tree_util.tree_map(lambda a: a[None], bc[key])
+                    x, nc, _ = _apply_layer(
+                        cfg, i, bp[key], x, positions=positions,
+                        memory=None, cache=slab, pos=pos, aux={},
+                        page_table=table, block=0)
+                    new[key] = jax.tree_util.tree_map(lambda a: a[0], nc)
+                return x, new
+
+            x, pool = jax.lax.scan(body, _embed(cfg, params, tokens),
+                                   (params["blocks"], pool))
+            logits = _logits(cfg, params, x)
+            return (logits[:, 0] if program == "decode" else logits), pool
+
+        got_logits, got_pool = carried(pool)
+        want_logits, want_pool = sliced(pool)
+        np.testing.assert_array_equal(np.asarray(got_logits),
+                                      np.asarray(want_logits))
+        for got, want in zip(jax.tree_util.tree_leaves(got_pool),
+                             jax.tree_util.tree_leaves(want_pool)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
     def test_gqa_local_window_ref_backend(self, rng):
         cfg = dataclasses.replace(GQA_LOCAL, paged_attn_impl="ref")
         params = init_params(cfg, rng)

@@ -3,13 +3,15 @@
 Each case compiles (and runs nothing) for one chip of a described
 ``v5e:2x2`` topology with the TPU compiler, so a BlockSpec or layout the
 chip refuses fails here instead of on the chip. Interpret-mode parity
-lives in the kernels' own test files.
+lives in the kernels' own test files. One more case compiles the
+engine's decode-chunk program at qwen3 widths and reads its structure.
 
 The topology is described inside a module fixture: only the worker that
 runs this file loads the TPU library. Keep these cases in this one file.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 
 import jax
@@ -17,8 +19,13 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from _hlo import whole_pool_slices
+from repro.config import RLConfig
 from repro.configs import get_config
 from repro.kernels import ops
+from repro.models import abstract_params
+from repro.sampling import continuous as cont
+from repro.sampling.paged_cache import init_paged_pool
 
 VOCAB = 152064                   # qwen3 vocab, padded to a multiple of 256
 HQ, HKV, HEAD_DIM, PAGE = 16, 8, 128, 16
@@ -120,3 +127,30 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     fn, avals = CASES[name]
     text = _compiled_text(fn, one_chip, *avals())
     assert "tpu_custom_call" in text, f"{name}: no Mosaic kernel compiled"
+
+
+def test_decode_chunk_keeps_pools_in_place_for_v5e(one_chip):
+    """The rollout cell's decode chunk (64 slots, table width 64, 2049
+    pages of 16, bf16, plain sampling) at qwen3-1.7b widths, cut to 2
+    layers: the layer scan carries the stacked pools, so the compiled
+    program holds no dynamic-slice or dynamic-update-slice of one
+    layer's whole pool."""
+    cfg = dataclasses.replace(get_config("qwen3-1.7b"), num_layers=2)
+    n, width, pages = 64, 64, 2049
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, pool = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        (abstract_params(cfg),
+         jax.eval_shape(lambda: init_paged_pool(cfg, pages, PAGE))))
+    rl = RLConfig(temperature=1.0, top_k=0, top_p=1.0)
+    hlo = cont._decode_chunk_jit.lower(
+        cfg, rl, params, pool, sds((n, width), jnp.int32),
+        sds((n, cfg.padded_vocab), jnp.float32), sds((n,), jnp.int32),
+        sds((n,), jnp.bool_), sds((n, 2), jnp.uint32), sds((n,), jnp.int32),
+        sds((n,), jnp.int32), vocab_limit=cfg.vocab_size,
+        sync_every=8).compile().as_text()
+    assert f"bf16[{cfg.num_blocks},{pages},{HKV},{PAGE},{HEAD_DIM}]" in hlo
+    assert whole_pool_slices(hlo, (pages, HKV, PAGE, HEAD_DIM)) == []
