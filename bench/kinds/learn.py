@@ -20,9 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.lib import program, reference, traffic, trace
+from bench.lib import placement, program, reference, spec, traffic, trace
 from bench.lib import weights as W
-from bench.lib.work import dims
 
 
 def settings(cell, n_rows: int):
@@ -35,12 +34,13 @@ def settings(cell, n_rows: int):
     return rl, tc
 
 
-def _adafactor_grad_norms(state, decay: float = 0.999) -> Dict[str, float]:
+def _adafactor_grad_norms(fam, state, decay: float = 0.999
+                          ) -> Dict[str, float]:
     """Each leaf's gradient norm as the optimizer got it at step 1, worked
     out from the Adafactor state: after one step the row statistic is
     (1 - decay) * mean over the last axis of g^2."""
-    vr = program.program_leaf_names(state.opt.vr)
-    shapes = program.program_leaf_names(state.params)
+    vr = fam.program_leaf_names(state.opt.vr)
+    shapes = fam.program_leaf_names(state.params)
     out = {}
     for n, r in vr.items():
         cols = shapes[n].shape[-1] if shapes[n].ndim >= 2 else 1
@@ -50,12 +50,14 @@ def _adafactor_grad_norms(state, decay: float = 0.999) -> Dict[str, float]:
 
 def _change_norms(c, seed: int, v_pad: int, leaves: Dict[str, Any]
                   ) -> Dict[str, float]:
-    """Norm of each leaf's change from the seed's initial weights."""
+    """Norm of each leaf's change from the seed's initial weights, each
+    initial leaf drawn again where the leaf lives."""
     @jax.jit
     def norm(now, init):
         return jnp.sqrt(jnp.sum(jnp.square(now.astype(jnp.float32)
                                            - init.astype(jnp.float32))))
-    return {n: float(norm(a, W.make_leaf(c, seed, v_pad, n)))
+    return {n: float(norm(a, W.make_leaf(c, seed, v_pad, n,
+                                         placement.spread(a))))
             for n, a in leaves.items()}
 
 
@@ -69,13 +71,15 @@ def _gap(prog: Dict[str, float], ref: Dict[str, float],
 
 
 def reference_readings(c, t, seed: int, v_pad: int, tc, mm: str = "f32",
-                       half: bool = False, log=lambda s: None
+                       half: bool = False, mesh=None, log=lambda s: None
                        ) -> Dict[str, Any]:
     """The reference's readings over the first ``check_steps`` batches:
     each step's loss, each leaf's gradient norm as the optimizer got it at
     step 1, and each leaf's change after the last step. ``mm="fp8"`` is
     the control; ``half`` is the fault of half the batch left out, the
-    mean taken over the rest (its first half: whole groups)."""
+    mean taken over the rest (its first half: whole groups). ``mesh``
+    (``bench.lib.placement``) splits the reference's state over the
+    cell's chips."""
     batches = [traffic.learn_batch(t, c["vocab_size"], seed, i)
                for i in range(t["check_steps"])]
     micro = c["learner"]["micro_batch_rows"]
@@ -83,9 +87,10 @@ def reference_readings(c, t, seed: int, v_pad: int, tc, mm: str = "f32",
         n = len(batches[0]["rewards"]) // 2
         batches = [{k: a[:n] for k, a in b.items()} for b in batches]
         micro = min(micro, n)
-    ref = reference.learn_steps(c, t["rl"], W.make(c, seed, v_pad), batches,
-                                lr=tc.learning_rate, clip=tc.grad_clip,
-                                micro_rows=micro, mm=mm, log=log)
+    ref = reference.learn_steps(c, t["rl"], W.make(c, seed, v_pad, mesh),
+                                batches, lr=tc.learning_rate,
+                                clip=tc.grad_clip, micro_rows=micro, mm=mm,
+                                mesh=mesh, log=log)
     ref["change_norms"] = _change_norms(c, seed, v_pad, ref.pop("weights"))
     return ref
 
@@ -123,6 +128,7 @@ def run(cell, args, env) -> Dict[str, Any]:
     log = env.log
     cfg = program.model_config(c)
     program.check_layout(cfg, c)
+    fam = spec.family(c)
     v = c["vocab_size"]
     n_rows = t["prompts"] * t["group_size"]
     rl, tc = settings(cell, n_rows)
@@ -155,11 +161,11 @@ def run(cell, args, env) -> Dict[str, Any]:
         state, m = step(state, db)
         prog_losses.append(float(m["loss"]))
         if i == 0:
-            grad_prog = _adafactor_grad_norms(state)
+            grad_prog = _adafactor_grad_norms(fam, state)
         log(f"learn: setup step {i + 1} loss {prog_losses[-1]!r} "
             f"grad_norm {float(m['grad_norm'])!r}")
     change_prog = _change_norms(c, seed, cfg.padded_vocab,
-                                program.program_leaf_names(state.params))
+                                fam.program_leaf_names(state.params))
     env.setup_done()
 
     # --- the window ----------------------------------------------------
@@ -188,13 +194,13 @@ def run(cell, args, env) -> Dict[str, Any]:
 
     # --- the reference ---------------------------------------------------
     t_ref = time.perf_counter()
-    ref = reference_readings(c, t, seed, cfg.padded_vocab, tc, log=log)
+    ref = reference_readings(c, t, seed, cfg.padded_vocab, tc,
+                             mesh=placement.mesh(cell.chips), log=log)
     log(f"learn: reference took {time.perf_counter() - t_ref!r} s")
     prog = {"losses": prog_losses, "grad_norms": grad_prog,
             "change_norms": change_prog}
     checks = compare(prog, ref, log)
     failed = sum(1 for x in losses if not math.isfinite(x))
-    k = dims(c)
     return {
         "checks": checks,
         "attempted": len(losses), "failed": failed,
@@ -205,6 +211,5 @@ def run(cell, args, env) -> Dict[str, Any]:
                    "grad_accum": tc.grad_accum,
                    "logit_tokens": c["learner"]["micro_batch_rows"]
                    * (t["width"] - 1),
-                   "padded_vocab": cfg.padded_vocab,
-                   "layers": k["L"]},
+                   "padded_vocab": cfg.padded_vocab},
     }

@@ -20,7 +20,7 @@ from typing import Any, Dict, List, Tuple
 import jax
 import numpy as np
 
-from bench.lib import program, reference, traffic, trace
+from bench.lib import placement, program, reference, traffic, trace
 from bench.lib import weights as W
 
 
@@ -57,21 +57,24 @@ def check_rows(c: Dict[str, Any], served: List[Dict[str, Any]], width: int
 
 
 def reference_readings(c: Dict[str, Any], seed: int, v_pad: int,
-                       rows: Dict[str, np.ndarray], mm: str = "f32"
-                       ) -> Dict[str, np.ndarray]:
+                       rows: Dict[str, np.ndarray], mm: str = "f32",
+                       mesh=None) -> Dict[str, np.ndarray]:
     """Per position of the check rows, under the reference computed in
     ``mm``: the served token's log-prob, the reference's best perturbed
     token, and the served token's gap below it. With ``mm="fp8"`` (the
     control) the f32 reference also reads the gap of the token the
-    control puts first."""
-    wts = W.make(c, seed, v_pad)
+    control puts first. ``mesh`` (``bench.lib.placement``) splits the
+    weights over the cell's chips."""
+    wts = W.make(c, seed, v_pad, mesh)
     served = rows["tokens"][:, 1:]
     lp, best, gap = reference.served_readings(
-        c, wts, rows["tokens"], rows["keys"], served, rows["valid"], mm=mm)
+        c, wts, rows["tokens"], rows["keys"], served, rows["valid"], mm=mm,
+        mesh=mesh)
     out = {"logp": lp, "best": best, "gap": gap}
     if mm != "f32":
         _, _, out["gap_of_best"] = reference.served_readings(
-            c, wts, rows["tokens"], rows["keys"], best, rows["valid"])
+            c, wts, rows["tokens"], rows["keys"], best, rows["valid"],
+            mesh=mesh)
     return out
 
 
@@ -178,7 +181,8 @@ def run(cell, args, env) -> Dict[str, Any]:
     t_ref = time.perf_counter()
     served = sample_served(results, t["check_requests"], seed)
     rows = check_rows(c, served, serve.max_total_tokens)
-    ref = reference_readings(c, seed, cfg.padded_vocab, rows)
+    ref = reference_readings(c, seed, cfg.padded_vocab, rows,
+                             mesh=placement.mesh(cell.chips))
     checks = compare(rows, ref)
     log(f"rollout: reference over {int(rows['valid'].sum())} served tokens "
         f"of {len(served)} requests took {time.perf_counter() - t_ref!r} s")

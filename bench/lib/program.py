@@ -1,7 +1,8 @@
 """The benchmark's side of the interface to the program under test: the
 program's model configuration as the configuration file states it, and
-the benchmark's weights handed over in the program's parameter layout.
-The program's own code is imported here and in ``bench/kinds`` only.
+the benchmark's weights handed over in the program's parameter layout
+(the model family's ``to_program``). The program's own code is imported
+here and in ``bench/kinds`` only.
 """
 from __future__ import annotations
 
@@ -10,6 +11,7 @@ from typing import Any, Dict
 
 import jax
 
+from bench.lib import spec
 from bench.lib import weights as W
 
 
@@ -31,40 +33,12 @@ def model_config(c: Dict[str, Any]):
     return cfg
 
 
-def to_program(w: Dict[str, Any]) -> Dict[str, Any]:
-    """Benchmark layout -> the program's parameter tree (a renaming; the
-    arrays are the same)."""
-    tree = {
-        "embed": w["embed"], "final_norm": w["final_norm"],
-        "blocks": {"layer_0": {
-            "norm": w["attn_norm"],
-            "attn": {k: w[k] for k in ("wq", "wk", "wv", "wo")},
-            "ffn_norm": w["mlp_norm"],
-            "mlp": {k: w[k] for k in ("w_gate", "w_up", "w_down")},
-        }},
-    }
-    if "lm_head" in w:
-        tree["lm_head"] = w["lm_head"]
-    return tree
-
-
-def program_leaf_names(tree: Dict[str, Any]) -> Dict[str, Any]:
-    """Benchmark leaf name -> the same leaf of a program-layout tree."""
-    b = tree["blocks"]["layer_0"]
-    out = {"embed": tree["embed"], "final_norm": tree["final_norm"],
-           "attn_norm": b["norm"], "mlp_norm": b["ffn_norm"],
-           **b["attn"], **b["mlp"]}
-    if "lm_head" in tree:
-        out["lm_head"] = tree["lm_head"]
-    return out
-
-
 def check_layout(cfg, c: Dict[str, Any]) -> None:
     """The program's parameter tree has exactly the benchmark's leaves,
     shapes and dtype."""
     from repro.models import abstract_params
     want = abstract_params(cfg)
-    have = to_program(jax.eval_shape(
+    have = spec.family(c).to_program(jax.eval_shape(
         lambda: W.make(c, 0, cfg.padded_vocab)))
     ws = jax.tree_util.tree_map(lambda a: (a.shape, str(a.dtype)), want)
     hs = jax.tree_util.tree_map(lambda a: (a.shape, str(a.dtype)), have)
@@ -78,6 +52,7 @@ def make_program_weights(cfg, c: Dict[str, Any], seed: int, plan=None):
     program's layout (sharded on ``plan`` when given)."""
     v_pad = cfg.padded_vocab
     key = W.base_key(seed)
+    to_program = spec.family(c).to_program
 
     def make(k):
         return to_program(W.tree(k, c, v_pad,

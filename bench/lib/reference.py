@@ -3,18 +3,23 @@ its Adafactor step. It imports nothing of the program and takes nothing
 the program made: weights come from ``bench.lib.weights`` (the seed),
 batches from ``bench.lib.traffic``.
 
-The model is the published Qwen3 decoder as the configuration file
-states it, with the departures that file lists (no QK-norm). Every
-matmul runs at ``Precision.HIGHEST``; ``mm="fp8"`` is the control: each
-linear layer's weights (per output column) and inputs (per row) rounded
-to float8_e4m3fn with an absmax scale, the precision below the
-configured bfloat16.
+The layers are those of the configuration's model family
+(``bench/models/<model_type>.py``, its ``layer``), as the configuration
+file states them, with the departures that file lists; the embedding,
+the final norm, the LM head, the loss, the optimizer and the readings
+of served tokens are here. Every matmul runs at ``Precision.HIGHEST``;
+``mm="fp8"`` is the control: each linear layer's weights (per output
+column) and inputs (per row) rounded to float8_e4m3fn with an absmax
+scale, the precision below the configured bfloat16.
 
-Everything runs in blocks so that it fits next to nothing else on one
-chip: the learner reference keeps the weights in bfloat16 (their stored
-type; each layer is widened to float32 as it is used), accumulates an
-f32 gradient layer by layer through ``jax.vjp`` of one layer at a time,
-and reads the LM head in chunks of positions.
+Everything runs in blocks so that it fits next to nothing else on the
+cell's chips: the learner reference keeps the weights in bfloat16 (their
+stored type; each layer is widened to float32 as it is used),
+accumulates an f32 gradient layer by layer through ``jax.vjp`` of one
+layer at a time, and reads the LM head in chunks of positions. On more
+than one chip the weights, the gradient and the optimizer's statistics
+are split over a mesh of them (``bench.lib.placement``); on one, the
+programs are the one-device programs.
 """
 from __future__ import annotations
 
@@ -25,7 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.lib.weights import STACKED
+from bench.lib import placement, spec
+from bench.lib.weights import config_items
 
 HI = jax.lax.Precision.HIGHEST
 F8 = jnp.float8_e4m3fn
@@ -70,33 +76,6 @@ def rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def layer(c: Dict[str, Any], lw: Dict[str, jax.Array], x: jax.Array,
-          mm: str) -> jax.Array:
-    """One decoder layer on x (R, W, d) f32 at positions 0..W-1."""
-    r, w, _ = x.shape
-    nq, nkv, hd = (c["num_attention_heads"], c["num_key_value_heads"],
-                   c["head_dim"])
-    eps = c["rms_norm_eps"]
-    pos = jnp.broadcast_to(jnp.arange(w), (r, w))
-    h = rmsnorm(x, lw["attn_norm"], eps)
-    q = rope(matmul(h, lw["wq"], mm).reshape(r, w, nq, hd), pos,
-             c["rope_theta"])
-    k = rope(matmul(h, lw["wk"], mm).reshape(r, w, nkv, hd), pos,
-             c["rope_theta"])
-    v = matmul(h, lw["wv"], mm).reshape(r, w, nkv, hd)
-    q = q.reshape(r, w, nkv, nq // nkv, hd)
-    s = jnp.einsum("bqgrd,bkgd->bgrqk", q, k, precision=HI) * hd ** -0.5
-    causal = jnp.tril(jnp.ones((w, w), bool))
-    s = jnp.where(causal, s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bgrqk,bkgd->bqgrd", p, v, precision=HI)
-    x = x + matmul(o.reshape(r, w, nq * hd), lw["wo"], mm)
-    h = rmsnorm(x, lw["mlp_norm"], eps)
-    g = matmul(h, lw["w_gate"], mm)
-    u = matmul(h, lw["w_up"], mm)
-    return x + matmul(jax.nn.silu(g) * u, lw["w_down"], mm)
-
-
 def head_matrix(wts: Dict[str, jax.Array], vocab: int) -> jax.Array:
     """(d, V) LM head over the published vocabulary."""
     if "lm_head" in wts:
@@ -104,19 +83,16 @@ def head_matrix(wts: Dict[str, jax.Array], vocab: int) -> jax.Array:
     return wts["embed"][:vocab].T
 
 
-def _stacked(wts):
-    return {n: wts[n] for n in STACKED}
-
-
 def hidden(c, wts, tokens, mm, keep: bool = False):
     """Embed and run every layer. Returns the last hidden state and, with
     ``keep``, every layer's input (L, R, W, d)."""
+    fam = spec.family(c)
     x = wts["embed"][tokens].astype(jnp.float32)
 
     def body(x, lw):
-        return layer(c, lw, x, mm), (x if keep else None)
+        return fam.layer(c, lw, x, mm), (x if keep else None)
 
-    x, xs = jax.lax.scan(body, x, _stacked(wts))
+    x, xs = jax.lax.scan(body, x, {n: wts[n] for n in fam.LAYER_LEAVES})
     return x, xs
 
 
@@ -206,10 +182,10 @@ def _head_chunks(h, targets, n):
     return hs, ts
 
 
-@functools.partial(jax.jit, static_argnames=("citems", "rl_items", "mm"),
-                   donate_argnums=(1,))
+@functools.partial(jax.jit, static_argnames=("citems", "rl_items", "mm",
+                                             "mesh"), donate_argnums=(1,))
 def _block_grad(wts, acc, tokens, slp, mask, adv, log_den, pol_w, kl_w,
-                citems, rl_items, mm):
+                citems, rl_items, mm, mesh):
     """Add one block of rows' share of the loss gradient to ``acc``.
 
     The LM head's backward is written out by chunks of positions, so
@@ -261,26 +237,28 @@ def _block_grad(wts, acc, tokens, slp, mask, adv, log_den, pol_w, kl_w,
     acc["final_norm"] = acc["final_norm"] + dfn
 
     n_layers = c["num_hidden_layers"]
+    fam = spec.family(c)
+    stacked = fam.LAYER_LEAVES
 
     def back(i, carry):
         st, dx = carry
         li = n_layers - 1 - i
         lw = {k: jax.lax.dynamic_index_in_dim(wts[k], li, keepdims=False
                                               ).astype(jnp.float32)
-              for k in STACKED}
+              for k in stacked}
         x_in = jax.lax.dynamic_index_in_dim(xs, li, keepdims=False)
-        _, vjp = jax.vjp(lambda lw, x: layer(c, lw, x, mm), lw, x_in)
+        _, vjp = jax.vjp(lambda lw, x: fam.layer(c, lw, x, mm), lw, x_in)
         dlw, dx = vjp(dx)
         st = {k: jax.lax.dynamic_update_index_in_dim(
             st[k], jax.lax.dynamic_index_in_dim(st[k], li, keepdims=False)
-            + dlw[k], li, 0) for k in STACKED}
+            + dlw[k], li, 0) for k in stacked}
         return st, dx
 
     st, dx = jax.lax.fori_loop(0, n_layers, back,
-                               ({k: acc[k] for k in STACKED}, dx))
+                               ({k: acc[k] for k in stacked}, dx))
     acc.update(st)
     acc["embed"] = acc["embed"].at[inp].add(dx)
-    return acc, loss
+    return placement.constrain(acc, mesh), loss
 
 
 @functools.partial(jax.jit, static_argnames=("citems", "mm"))
@@ -311,9 +289,9 @@ def _blocks(lengths: Sequence[int], rows: int, width: int
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("lr", "clip", "decay"),
+@functools.partial(jax.jit, static_argnames=("lr", "clip", "decay", "mesh"),
                    donate_argnums=(0, 2, 3))
-def _adafactor(wts, grads, vr, vc, lr, clip, decay=0.999):
+def _adafactor(wts, grads, vr, vc, lr, clip, mesh, decay=0.999):
     """The configured optimizer step: global-norm clipping, then the
     factored second moment, update clipping to RMS 1 over each leaf, and
     the new weights rounded to their stored bfloat16."""
@@ -340,56 +318,59 @@ def _adafactor(wts, grads, vr, vc, lr, clip, decay=0.999):
         new_w[n] = (wts[n].astype(jnp.float32) - lr * delta).astype(
             wts[n].dtype)
         new_r[n], new_c[n] = r, cc
+    new_w, new_r, new_c = placement.constrain((new_w, new_r, new_c), mesh)
     return new_w, new_r, new_c, norms, gnorm
 
 
-def _zeros_like_f32(wts):
-    return {n: jnp.zeros(w.shape, jnp.float32) for n, w in wts.items()}
+def _zeros_like_f32(wts, mesh=None):
+    return {n: placement.zeros(n, w.shape, mesh) for n, w in wts.items()}
 
 
-def adafactor_state(wts):
-    vr = {n: jnp.zeros(w.shape[:-1] if w.ndim >= 2 else w.shape,
-                       jnp.float32) for n, w in wts.items()}
-    vc = {n: jnp.zeros(w.shape[:-2] + w.shape[-1:] if w.ndim >= 2 else (1,),
-                       jnp.float32) for n, w in wts.items()}
+def adafactor_state(wts, mesh=None):
+    vr = {n: placement.zeros(n, w.shape[:-1] if w.ndim >= 2 else w.shape,
+                             mesh) for n, w in wts.items()}
+    vc = {n: placement.zeros(n, w.shape[:-2] + w.shape[-1:] if w.ndim >= 2
+                             else (1,), mesh) for n, w in wts.items()}
     return vr, vc
 
 
 def learn_steps(c: Dict[str, Any], rl: Dict[str, Any], wts: Dict[str, Any],
                 batches: Sequence[Dict[str, np.ndarray]], *, lr: float,
                 clip: float, micro_rows: int, mm: str = "f32",
-                block_rows: int = 4,
+                block_rows: int = 4, mesh=None,
                 log: Callable[[str], None] = lambda s: None
                 ) -> Dict[str, Any]:
-    """Follow the learner through ``batches`` from ``wts`` (consumed).
+    """Follow the learner through ``batches`` from ``wts`` (consumed;
+    split over ``mesh`` where one is given, as ``weights.make`` draws
+    them).
 
     Returns the loss of each step (before its update), every leaf's
     gradient norm as the optimizer got it at step 1 (after clipping),
     and the weights after the last step."""
-    citems = tuple(sorted((k, v) for k, v in c.items()
-                          if isinstance(v, (int, float, bool, str))))
+    citems = config_items(c)
     rl_items = tuple(sorted(rl.items()))
-    vr, vc = adafactor_state(wts)
+    put = functools.partial(placement.host, m=mesh)
+    vr, vc = adafactor_state(wts, mesh)
     losses, grad_norms, gnorms = [], None, []
     for step, batch in enumerate(batches):
         k = group_constants(rl, batch, micro_rows)
         tokens = np.asarray(batch["tokens"])
         width = tokens.shape[1] - 1
         lengths = [int(x) for x in np.asarray(batch["lengths"])]
-        acc = _zeros_like_f32(wts)
+        acc = _zeros_like_f32(wts, mesh)
         loss = 0.0
         for idx, w in _blocks(lengths, block_rows, width):
             acc, part = _block_grad(
-                wts, acc, jnp.asarray(tokens[idx, :w + 1]),
-                jnp.asarray(batch["sampler_lp"][idx, :w]),
-                jnp.asarray(batch["mask"][idx, :w]),
-                jnp.asarray(k["adv"][idx]), jnp.asarray(k["log_den"][idx]),
-                jnp.asarray(k["pol_w"][idx]), jnp.asarray(k["kl_w"][idx]),
-                citems=citems, rl_items=rl_items, mm=mm)
+                wts, acc, put(tokens[idx, :w + 1]),
+                put(batch["sampler_lp"][idx, :w]),
+                put(batch["mask"][idx, :w]),
+                put(k["adv"][idx]), put(k["log_den"][idx]),
+                put(k["pol_w"][idx]), put(k["kl_w"][idx]),
+                citems=citems, rl_items=rl_items, mm=mm, mesh=mesh)
             loss += float(part)
         losses.append(loss)
         wts, vr, vc, norms, gnorm = _adafactor(wts, acc, vr, vc, lr=lr,
-                                               clip=clip)
+                                               clip=clip, mesh=mesh)
         del acc
         gnorms.append(float(gnorm))
         if grad_norms is None:
@@ -445,15 +426,16 @@ def _served_logits_readings(wts, tokens, keys, query, valid, citems, mm):
 
 def served_readings(c: Dict[str, Any], wts: Dict[str, Any],
                     tokens: np.ndarray, keys: np.ndarray, query: np.ndarray,
-                    valid: np.ndarray, mm: str = "f32", block_rows: int = 4):
-    """``_served_logits_readings`` over blocks of rows, as numpy."""
-    citems = tuple(sorted((k, v) for k, v in c.items()
-                          if isinstance(v, (int, float, bool, str))))
+                    valid: np.ndarray, mm: str = "f32", block_rows: int = 4,
+                    mesh=None):
+    """``_served_logits_readings`` over blocks of rows, as numpy; the
+    rows whole on every chip of ``mesh`` where one is given."""
+    citems = config_items(c)
+    put = functools.partial(placement.host, m=mesh)
     outs = []
     for i in range(0, tokens.shape[0], block_rows):
         sl = slice(i, i + block_rows)
         outs.append([np.asarray(a) for a in _served_logits_readings(
-            wts, jnp.asarray(tokens[sl]), jnp.asarray(keys[sl]),
-            jnp.asarray(query[sl]), jnp.asarray(valid[sl]),
-            citems=citems, mm=mm)])
+            wts, put(tokens[sl]), put(keys[sl]), put(query[sl]),
+            put(valid[sl]), citems=citems, mm=mm)])
     return tuple(np.concatenate([o[j] for o in outs]) for j in range(3))

@@ -7,14 +7,18 @@ piece sits in a file of its own, found by that name:
 - a traffic mix: ``bench/traffic/<traffic>.json``, whose ``kind`` names
   the runner ``bench/kinds/<kind>.py``;
 - a cell's correctness limits: ``bench/limits/<workload>.json``;
-- a per-layer metric's reader: ``bench/metrics/<metric>.py``.
+- a per-layer metric's reader: ``bench/metrics/<metric>.py``;
+- a configuration's model family (its leaves, the program's tree for
+  them, its reference layer and its work counts):
+  ``bench/models/<model_type>.py``, by the file's ``model_type``.
 
-Adding a cell, a configuration, a mix or a metric adds files; nothing
-here changes.
+Adding a cell, a configuration, a mix, a metric or a model family adds
+files; nothing here changes.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -88,3 +92,17 @@ def kind_module(kind: str, bench_dir: Path = BENCH) -> ModuleType:
 
 def metric_reader(name: str, bench_dir: Path = BENCH) -> ModuleType:
     return load_module(bench_dir / "metrics" / f"{name}.py")
+
+
+def family(c: Dict[str, Any], bench_dir: Path = BENCH) -> ModuleType:
+    """The model family of configuration ``c``, by its ``model_type``."""
+    return _family(c["model_type"], bench_dir)
+
+
+@functools.lru_cache(maxsize=None)
+def _family(model_type: str, bench_dir: Path) -> ModuleType:
+    path = bench_dir / "models" / f"{model_type}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no model family {model_type!r}: "
+                                f"{path} does not exist")
+    return load_module(path)

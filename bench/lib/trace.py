@@ -28,22 +28,47 @@ OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 # control-flow ops whose events enclose the events of their bodies
 CONTAINERS = ("while", "conditional", "call")
+# collectives by opcode; an asynchronous one's ``-start`` and ``-done``
+# halves count as it, and a fusion that calls one (the TPU's
+# ``kind=kCustom, calls=%all-reduce-scatter.4``) is named by what it calls
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+               "all-reduce-scatter", "collective-permute", "all-to-all")
 _OPCODE = re.compile(r"\s([a-z][\w.-]*)\(")
+_CALLS = re.compile(r"calls=%?([a-z][a-z-]*)")
 
 
 @functools.lru_cache(maxsize=None)
 def short_name(name: str) -> str:
     """``%fusion.12 fusion`` for an XLA op event named by its whole HLO
-    text (``%fusion.12 = bf16[...]{...} fusion(...), ...``)."""
+    text (``%fusion.12 = bf16[...]{...} fusion(...), ...``);
+    ``%fusion.7 all-reduce-scatter`` for a fusion that calls a
+    collective."""
     if " = " not in name:
         return name
     lhs, rhs = name.split(" = ", 1)
     m = _OPCODE.search(" " + rhs)
-    return f"{lhs} {m.group(1)}" if m else lhs
+    if not m:
+        return lhs
+    called = _CALLS.search(rhs)
+    if called and called.group(1) in COLLECTIVES:
+        return f"{lhs} {called.group(1)}"
+    return f"{lhs} {m.group(1)}"
 
 
 def is_container(name: str) -> bool:
     return name.rsplit(" ", 1)[-1] in CONTAINERS
+
+
+def is_collective(name: str) -> bool:
+    op = name.rsplit(" ", 1)[-1]
+    return op.removesuffix("-start").removesuffix("-done") in COLLECTIVES
+
+
+def _intervals(ops: List[Tuple[str, float, float]]) -> np.ndarray:
+    if not ops:
+        return np.zeros((0, 2))
+    a = np.array([(s, s + d) for _, s, d in ops], float)
+    return a[np.argsort(a[:, 0], kind="stable")]
 
 
 @dataclass
@@ -52,10 +77,7 @@ class Device:
     modules: List[Tuple[str, float, float]] = field(default_factory=list)
 
     def intervals(self) -> np.ndarray:
-        if not self.ops:
-            return np.zeros((0, 2))
-        a = np.array([(s, s + d) for _, s, d in self.ops], float)
-        return a[np.argsort(a[:, 0], kind="stable")]
+        return _intervals(self.ops)
 
 
 @dataclass
@@ -170,6 +192,45 @@ def busy_share(trace: Trace) -> float:
                           for d in trace.devices.values()]))
 
 
+def _overlap(a: List[Tuple[float, float]], b: List[Tuple[float, float]]
+             ) -> float:
+    """Length of the intersection of two sorted lists of disjoint
+    intervals."""
+    i = j = 0
+    total = 0.0
+    while i < len(a) and j < len(b):
+        total += max(0.0, min(a[i][1], b[j][1]) - max(a[i][0], b[j][0]))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
+def collective_exposed_ns(dev: Device, lo: float, hi: float) -> float:
+    """Time in [lo, hi] inside collective ops that no other op on the
+    device covers (control-flow ops, which enclose everything in their
+    bodies, cover nothing)."""
+    coll = _union(_intervals([o for o in dev.ops if is_collective(o[0])]),
+                  lo, hi)
+    rest = _union(_intervals([o for o in dev.ops
+                              if not is_collective(o[0])
+                              and not is_container(o[0])]), lo, hi)
+    return sum(e - s for s, e in coll) - _overlap(coll, rest)
+
+
+def collective_exposed_share(trace: Trace) -> Optional[float]:
+    """``collective_exposed_ns`` over the window, averaged over the
+    devices; None when no device ran a collective in the window."""
+    lo, hi = trace.window()
+    if hi <= lo or not any(is_collective(n) and s < hi and s + d > lo
+                           for dev in trace.devices.values()
+                           for n, s, d in dev.ops):
+        return None
+    return float(np.mean([collective_exposed_ns(d, lo, hi) / (hi - lo)
+                          for d in trace.devices.values()]))
+
+
 def idle_gaps(dev: Device, lo: float, hi: float
               ) -> List[Tuple[float, float]]:
     gaps, t = [], lo
@@ -253,6 +314,9 @@ def summary(trace: Trace) -> str:
             lines.append(f"  module {n!r}: {s!r} s")
     for n, s in top_ops(trace, 12):
         lines.append(f"  op {n!r}: {s!r} s")
+    coll = {n: s for n, s in op_seconds(trace).items() if is_collective(n)}
+    lines.append(f"collectives: {len(coll)} ops, {sum(coll.values())!r} s "
+                 f"a device; busiest {sorted(coll, key=coll.get)[-3:]}")
     names: Dict[str, int] = {}
     for n, _, _ in trace.host:
         names[n] = names.get(n, 0) + 1

@@ -1,43 +1,38 @@
 """Operations and bytes that the algorithm needs, from shapes.
 
-Every count is of the published model (``configs/<name>.json``): matmul
-FLOPs are 2 per multiply-add, attention is counted causally at each
-token's own position, and nothing that an implementation recomputes
+Every count is of the published model (``configs/<name>.json``), as its
+model family (``bench/models/<model_type>.py``) counts it: matmul FLOPs
+are 2 per multiply-add, attention is counted causally at each token's
+own position, and nothing that an implementation recomputes
 (rematerialization, padding, dead decode slots) is counted.
 """
 from __future__ import annotations
 
 from typing import Any, Dict, Iterable, Sequence
 
+from bench.lib import spec
+
 Config = Dict[str, Any]
 
 
-def dims(c: Config) -> Dict[str, int]:
-    return {"d": c["hidden_size"], "f": c["intermediate_size"],
-            "L": c["num_hidden_layers"], "nq": c["num_attention_heads"],
-            "nkv": c["num_key_value_heads"], "hd": c["head_dim"],
-            "V": c["vocab_size"]}
-
-
-def layer_matmul_params(c: Config) -> int:
-    k = dims(c)
-    attn = k["d"] * (k["nq"] + 2 * k["nkv"]) * k["hd"] + k["nq"] * k["hd"] * k["d"]
-    return attn + 3 * k["d"] * k["f"]
-
-
 def matmul_params(c: Config) -> int:
-    """Weights every token multiplies by: the layers and the LM head (the
-    embedding matrix itself where the head is tied). The input embedding
-    is a lookup, not a matmul."""
-    k = dims(c)
-    return k["L"] * layer_matmul_params(c) + k["V"] * k["d"]
+    """Weights every token multiplies by."""
+    return spec.family(c).matmul_params(c)
 
 
 def attn_flops(c: Config, ctx: float) -> float:
-    """Forward attention FLOPs of one token that attends ``ctx`` keys:
-    scores and the weighted sum, every layer."""
-    k = dims(c)
-    return 4.0 * k["L"] * k["nq"] * k["hd"] * ctx
+    """Forward attention FLOPs of one token that attends ``ctx`` keys."""
+    return spec.family(c).attn_flops(c, ctx)
+
+
+def kv_bytes_per_token(c: Config, dtype_bytes: int = 2) -> int:
+    return spec.family(c).kv_bytes_per_token(c, dtype_bytes)
+
+
+def weight_bytes(c: Config, tokens: int, dtype_bytes: int = 2) -> int:
+    """The least bytes of weights one decode step reads when it serves
+    ``tokens`` tokens."""
+    return spec.family(c).decode_weight_bytes(c, tokens, dtype_bytes)
 
 
 def train_flops(c: Config, lengths: Iterable[int]) -> float:
@@ -51,28 +46,15 @@ def train_flops(c: Config, lengths: Iterable[int]) -> float:
     return total
 
 
-def kv_bytes_per_token(c: Config, dtype_bytes: int = 2) -> int:
-    k = dims(c)
-    return k["L"] * 2 * k["nkv"] * k["hd"] * dtype_bytes
-
-
-def weight_bytes(c: Config, dtype_bytes: int = 2) -> int:
-    """Bytes of the weights one decode step must read: every matmul
-    weight (the head included) and the RMSNorm scales."""
-    k = dims(c)
-    norms = (2 * k["L"] + 1) * k["d"]
-    return (matmul_params(c) + norms) * dtype_bytes
-
-
 def decode_least_seconds(c: Config, prompt_lens: Sequence[int],
                          gen_lens: Sequence[int], peak_flops: float,
                          peak_bw: float) -> float:
     """Least device time of a closed batch's decode steps: step ``s``
     serves every request with more than ``s`` generated tokens, reads the
-    weights once and each such request's cached K/V (its prompt and the
-    ``s`` tokens before), and does their matmul and attention FLOPs.
-    Each step costs the larger of its bytes and its FLOPs over the peaks."""
-    w = weight_bytes(c)
+    weights it needs once and each such request's cached K/V (its prompt
+    and the ``s`` tokens before), and does their matmul and attention
+    FLOPs. Each step costs the larger of its bytes and its FLOPs over the
+    peaks."""
     kvb = kv_bytes_per_token(c)
     n = matmul_params(c)
     steps = max(gen_lens, default=0)
@@ -81,7 +63,7 @@ def decode_least_seconds(c: Config, prompt_lens: Sequence[int],
         ctx = [p + s + 1 for p, g in zip(prompt_lens, gen_lens) if g > s]
         if not ctx:
             continue
-        byts = w + kvb * sum(ctx)
+        byts = weight_bytes(c, len(ctx)) + kvb * sum(ctx)
         flops = 2.0 * n * len(ctx) + attn_flops(c, sum(ctx))
         total += max(byts / peak_bw, flops / peak_flops)
     return total

@@ -5,7 +5,8 @@ cell's own size; not part of a benchmark run.
   python3 bench/readings.py --workload <name> --seeds 11,12,13
 
 For each seed it prints one JSON line with the numbers that the cell
-compares, as read by:
+compares, and the fullest chip's peak memory so far (the reference's own,
+for a ``learn`` cell), as read by:
 
 - ``control``: the reference computed in float8 (e4m3, absmax-scaled
   weights and inputs of every linear layer) put in the program's place,
@@ -37,16 +38,18 @@ def log(msg: str) -> None:
 
 def learn_readings(cell, seed: int) -> dict:
     from bench.kinds import learn
-    from bench.lib import program
+    from bench.lib import placement, program
     c, t = cell.config, cell.traffic
     cfg = program.model_config(c)
     _, tc = learn.settings(cell, t["prompts"] * t["group_size"])
-    ref = learn.reference_readings(c, t, seed, cfg.padded_vocab, tc, log=log)
+    mesh = placement.mesh(cell.chips)
+    ref = learn.reference_readings(c, t, seed, cfg.padded_vocab, tc,
+                                   mesh=mesh, log=log)
     out = {}
     for name, kw in (("control", {"mm": "fp8"}), ("half_batch", {"half": True})):
         t0 = time.perf_counter()
         other = learn.reference_readings(c, t, seed, cfg.padded_vocab, tc,
-                                         log=log, **kw)
+                                         mesh=mesh, log=log, **kw)
         out[name] = learn.compare(other, ref, log)
         log(f"{name} seed {seed}: {out[name]} ({time.perf_counter() - t0:.1f} s)")
         gc.collect()
@@ -57,7 +60,7 @@ def rollout_readings(cell, seed: int) -> dict:
     import numpy as np
 
     from bench.kinds import rollout
-    from bench.lib import program, traffic
+    from bench.lib import placement, program, traffic
     from repro.config import RLConfig, ServeConfig
     from repro.sampling import build_engine
     from repro.serving.api import Request, SamplingParams
@@ -86,9 +89,11 @@ def rollout_readings(cell, seed: int) -> dict:
     gc.collect()
     served = rollout.sample_served(results, t["check_requests"], seed)
     rows = rollout.check_rows(c, served, serve.max_total_tokens)
-    ref = rollout.reference_readings(c, seed, cfg.padded_vocab, rows)
+    mesh = placement.mesh(cell.chips)
+    ref = rollout.reference_readings(c, seed, cfg.padded_vocab, rows,
+                                     mesh=mesh)
     ctl = rollout.reference_readings(c, seed, cfg.padded_vocab, rows,
-                                     mm="fp8")
+                                     mm="fp8", mesh=mesh)
     vm = rows["valid"]
     return {"program": rollout.compare(rows, ref),
             "control": {
@@ -106,6 +111,7 @@ def main(argv=None) -> int:
     import jax
 
     from bench.lib import spec
+    from bench.run import Env
     from repro.compile_cache import enable_compile_cache
     if jax.devices()[0].platform != "tpu":
         log("needs a TPU")
@@ -114,10 +120,13 @@ def main(argv=None) -> int:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     cell = spec.load_cell(args.workload)
     fn = learn_readings if cell.kind == "learn" else rollout_readings
+    env = Env(None)
+    env.chips = cell.chips
     for s in args.seeds.split(","):
         out = fn(cell, int(s))
-        print(json.dumps({"workload": cell.name, "seed": int(s), **out}),
-              flush=True)
+        env.read_memory()
+        print(json.dumps({"workload": cell.name, "seed": int(s), **out,
+                          "memory_peak_bytes": env.memory_peak}), flush=True)
     return 0
 
 

@@ -6,7 +6,9 @@ attached, and print what the compiler says they need.
 
 ``learn`` cells: the program's GEPO train step at the cell's batch, once
 per micro-batch size given (default: the configuration's), with its
-``memory_analysis()`` (arguments, outputs, temporaries). ``rollout``
+``memory_analysis()`` (arguments, outputs, temporaries), then the plain
+reference's gradient block at the widest block and its optimizer step,
+split over the cell's chips as a run splits them. ``rollout``
 cells: the engine's decode-chunk program at its widest block table and
 the widest prefill. A compile that passes is not a chip run: it gives
 bytes, never a time. The topology is ``v5e:2x2``; one-chip cells use its
@@ -108,6 +110,46 @@ def rehearse_learn(cell, topo, micros) -> None:
               f"{text.count('reduce-scatter')}", flush=True)
 
 
+def rehearse_reference(cell, topo) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from bench.lib import placement, program, reference, spec
+    from bench.lib import weights as W
+
+    c, t = cell.config, cell.traffic
+    cfg = program.model_config(c)
+    m = placement.mesh(cell.chips, topo.devices)
+    whole = jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+    def sds(name, shape, dt):
+        sh = whole if m is None else placement.leaf_sharding(m, name, shape)
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+
+    shapes = spec.family(c).leaf_shapes(c, cfg.padded_vocab)
+    dt = jnp.dtype(c["torch_dtype"])
+    wts = {n: sds(n, s, dt) for n, s in shapes.items()}
+    acc = {n: sds(n, s, jnp.float32) for n, s in shapes.items()}
+    vr, vc = ({n: sds(n, f(s), jnp.float32) for n, s in shapes.items()}
+              for f in (lambda s: s[:-1] if len(s) >= 2 else s,
+                        lambda s: s[:-2] + s[-1:] if len(s) >= 2 else (1,)))
+    rows, width = 4, reference._width_bucket(t["width"] - 1)
+    rep = whole if m is None else jax.sharding.NamedSharding(
+        m, jax.sharding.PartitionSpec())
+    row = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)
+    compiled = reference._block_grad.lower(
+        wts, acc, row((rows, width + 1), jnp.int32),
+        row((rows, width), jnp.float32), row((rows, width), jnp.float32),
+        *(row((rows,), jnp.float32) for _ in range(4)),
+        citems=W.config_items(c), rl_items=tuple(sorted(t["rl"].items())),
+        mm="f32", mesh=m).compile()
+    report(f"{cell.name} reference gradient block ({rows} rows x {width}, "
+           f"{cell.chips} chips)", compiled)
+    compiled = reference._adafactor.lower(wts, acc, vr, vc, lr=1e-3,
+                                          clip=1.0, mesh=m).compile()
+    report(f"{cell.name} reference Adafactor step", compiled)
+
+
 def rehearse_rollout(cell, topo) -> None:
     import jax
     import jax.numpy as jnp
@@ -177,6 +219,7 @@ def main(argv=None) -> int:
         micros = ([int(x) for x in args.micro.split(",")] if args.micro
                   else [cell.config["learner"]["micro_batch_rows"]])
         rehearse_learn(cell, topo, micros)
+        rehearse_reference(cell, topo)
     else:
         rehearse_rollout(cell, topo)
     return 0

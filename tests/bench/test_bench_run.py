@@ -59,3 +59,21 @@ def test_memory_peak_counts_reserved_bytes(monkeypatch):
                         property(lambda self: [Dev(4, 9), Dev(7, None)]))
     env.read_memory()
     assert env.memory_peak == 13
+
+
+def test_judge_compares_only_numbers_with_limits():
+    """A number the cell's limits leave out is not compared; compiles in
+    the window always are, with the limit 0; a number that is not finite
+    fails."""
+    sys.path.insert(0, str(ROOT))
+    from bench import run as harness
+    checks, limits, ok = harness.judge(
+        {"update_gap": 0.001, "loss_gap": 9.0}, {"update_gap": 0.005}, 0)
+    assert checks == {"update_gap": 0.001, "window_compiles": 0.0}
+    assert limits == {"update_gap": 0.005, "window_compiles": 0.0} and ok
+    assert not harness.judge({"update_gap": 0.001}, {"update_gap": 0.005},
+                             1)[2]
+    assert not harness.judge({"update_gap": float("nan")},
+                             {"update_gap": 0.005}, 0)[2]
+    assert not harness.judge({"update_gap": 0.006}, {"update_gap": 0.005},
+                             0)[2]

@@ -79,6 +79,38 @@ def test_time_by_name_and_scope():
     assert T.module_seconds(tr, "jit_step") == (pytest.approx(60e-9), 1)
 
 
+def test_collective_exposed_share():
+    """Window 0..100 ns. Device 0: an all-reduce [10, 50) under a fusion
+    [0, 30) and a while loop [0, 100) that covers nothing: 20 ns exposed;
+    an all-gather's halves [60, 62) and [80, 90) under a copy [85, 95):
+    7 ns. Device 1: an all-reduce-scatter fusion [40, 120), cut at the
+    window's end, none of it covered: 60 ns. Mean (27 + 60) / 2 / 100."""
+    dev0 = T.Device(ops=[("%while.1 while", 0, 100),
+                         ("%fusion.2 fusion", 0, 30),
+                         ("%all-reduce.3 all-reduce", 10, 40),
+                         ("%all-gather-start.4 all-gather-start", 60, 2),
+                         ("%all-gather-done.5 all-gather-done", 80, 10),
+                         ("%copy.6 copy", 85, 10)])
+    dev1 = T.Device(ops=[("%fusion.7 fusion", 0, 40),
+                         (T.short_name("%fusion.8 = bf16[8]{0} fusion(bf16[8]"
+                                       " %a), kind=kCustom, calls="
+                                       "%all-reduce-scatter.2"), 40, 80)])
+    tr = T.Trace(devices={"/device:TPU:0": dev0, "/device:TPU:1": dev1},
+                 host=[("bench.window", 0, 100)])
+    assert T.collective_exposed_ns(dev0, 0, 100) == 27
+    assert T.collective_exposed_ns(dev1, 0, 100) == 60
+    assert T.collective_exposed_share(tr) == pytest.approx(0.435)
+    from bench.lib import spec
+    reader = spec.metric_reader("collective_exposed_share")
+    assert reader.read({"kind": "learn", "trace": tr}) == pytest.approx(43.5)
+    # the other hand-made trace: device 1's all-reduce [0, 50), bare
+    assert reader.read({"kind": "learn", "trace": hand_made()}) == 25.0
+    # no collective in the window: nothing to read
+    quiet = T.Trace(devices={"/device:TPU:0": T.Device(
+        ops=[("%fusion.1 fusion", 0, 50)])}, host=[("bench.window", 0, 100)])
+    assert reader.read({"kind": "learn", "trace": quiet}) is None
+
+
 def test_json_round_trip(tmp_path):
     tr = hand_made()
     p = tmp_path / "t.json"

@@ -4,18 +4,20 @@ import json
 import pytest
 from tiny import ROOT
 
-from bench.lib import work
+from bench.lib import spec, work
 
 
 def config(name):
-    return json.loads((ROOT / "bench" / "configs" / f"{name}.json").read_text())
+    """The published configuration: the file with any cut undone."""
+    c = json.loads((ROOT / "bench" / "configs" / f"{name}.json").read_text())
+    return dict(c, **c.get("published", {}))
 
 
 def test_qwen3_1_7b_flops_per_token():
     c = config("qwen3-1.7b")
     # per layer: q,k,v 2048 x (16+8+8) x 128, o 2048 x 2048, MLP 3 x 2048 x 6144
     layer = 2048 * 32 * 128 + 2048 * 2048 + 3 * 2048 * 6144
-    assert work.layer_matmul_params(c) == layer == 50_331_648
+    assert spec.family(c).layer_matmul_params(c) == layer == 50_331_648
     # 28 layers and the tied head over the published 151,936 ids
     assert work.matmul_params(c) == 28 * layer + 151_936 * 2048 == 1_720_451_072
     per_token = work.train_flops(c, [1]) - 3 * work.attn_flops(c, 1)
@@ -47,11 +49,11 @@ def test_decode_least_seconds_by_hand():
     peak_f, bw = 197e12, 819e9
     # two requests: prompt 10 with 2 tokens, prompt 20 with 1 token
     got = work.decode_least_seconds(c, [10, 20], [2, 1], peak_f, bw)
-    w, kv, n = (work.weight_bytes(c), work.kv_bytes_per_token(c),
-                work.matmul_params(c))
-    step0 = max((w + kv * (11 + 21)) / bw,
+    kv, n = work.kv_bytes_per_token(c), work.matmul_params(c)
+    step0 = max((work.weight_bytes(c, 2) + kv * (11 + 21)) / bw,
                 (2 * n * 2 + work.attn_flops(c, 32)) / peak_f)
-    step1 = max((w + kv * 12) / bw, (2 * n + work.attn_flops(c, 12)) / peak_f)
+    step1 = max((work.weight_bytes(c, 1) + kv * 12) / bw,
+                (2 * n + work.attn_flops(c, 12)) / peak_f)
     assert got == pytest.approx(step0 + step1)
     # weights dominate: about 3.44 GB a step at 819 GB/s
     assert step1 == pytest.approx(3.44e9 / bw, rel=0.01)
