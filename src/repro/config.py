@@ -51,10 +51,13 @@ class ModelConfig:
     rope_theta: float = 1_000_000.0
 
     # MoE options ---------------------------------------------------------
-    num_experts: int = 0
+    num_experts: int = 0            # the router's width
     experts_per_token: int = 0
     shared_expert: bool = False
-    capacity_factor: float = 1.25
+    # The experts this layer holds, (first expert id, how many): one
+    # chip's block of an expert-parallel deployment, which computes only
+    # its own experts' part of the result. None holds all of them.
+    expert_block: Optional[Tuple[int, int]] = None
 
     # SSM (Mamba2 / SSD) options -----------------------------------------
     ssm_state: int = 0              # N, state dimension
@@ -111,6 +114,14 @@ class ModelConfig:
         assert self.num_layers % period == 0, (
             f"{self.name}: num_layers={self.num_layers} not divisible by "
             f"pattern period {period}")
+        if self.expert_block is not None:
+            first, count = map(int, self.expert_block)   # JSON gives a list
+            if not (count >= 1 and 0 <= first
+                    and first + count <= self.num_experts):
+                raise ValueError(
+                    f"{self.name}: expert_block {self.expert_block} is not "
+                    f"within the {self.num_experts} experts")
+            object.__setattr__(self, "expert_block", (first, count))
 
     # derived -------------------------------------------------------------
     @property
@@ -135,6 +146,14 @@ class ModelConfig:
     @property
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_headdim if self.ssm_state else 0
+
+    @property
+    def first_held_expert(self) -> int:
+        return self.expert_block[0] if self.expert_block else 0
+
+    @property
+    def num_held_experts(self) -> int:
+        return self.expert_block[1] if self.expert_block else self.num_experts
 
     def ffn_kind(self, layer_in_block: int) -> str:
         return self.ffn_pattern[layer_in_block % len(self.ffn_pattern)]
@@ -170,7 +189,7 @@ class ModelConfig:
                 total += 3 * d * self.d_ff
             elif fk == MOE:
                 n_e = (self.experts_per_token if active_only
-                       else self.num_experts)
+                       else self.num_held_experts)
                 total += 3 * d * self.d_ff * n_e
                 total += d * self.num_experts                        # router
                 if self.shared_expert:

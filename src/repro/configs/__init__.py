@@ -16,11 +16,12 @@ from repro.configs.whisper_small import CONFIG as _whisper
 from repro.configs.internlm2_1_8b import CONFIG as _internlm2
 from repro.configs.mamba2_1_3b import CONFIG as _mamba2
 from repro.configs.qwen2_7b import CONFIG as _qwen2
+from repro.configs.qwen3_30b_a3b import CONFIG as _qwen3_moe
 from repro.configs.qwen3_paper import CONFIG as _qwen3, CONFIG_8B as _qwen3_8b
 
 ARCHS = {c.name: c for c in (
     _qwen15_32b, _llama32v, _jamba, _scout, _gemma2, _maverick,
-    _whisper, _internlm2, _mamba2, _qwen2,
+    _whisper, _internlm2, _mamba2, _qwen2, _qwen3_moe,
 )}
 # The paper's own training targets (Qwen3-1.7B/8B proxies).
 PAPER_ARCHS = {c.name: c for c in (_qwen3, _qwen3_8b)}

@@ -261,8 +261,7 @@ def _constrain(cfg: ModelConfig, x: jax.Array) -> jax.Array:
 
 def _aux_init(cfg: ModelConfig) -> Dict[str, jax.Array]:
     if MOE in cfg.ffn_pattern:
-        return {"moe_load_balance": jnp.zeros(()), "moe_z_loss": jnp.zeros(()),
-                "moe_drop_frac": jnp.zeros(())}
+        return {"moe_load_balance": jnp.zeros(()), "moe_z_loss": jnp.zeros(())}
     return {}
 
 

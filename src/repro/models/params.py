@@ -50,10 +50,11 @@ def _mlp_templates(cfg: ModelConfig) -> Dict[str, ParamTemplate]:
 
 
 def _moe_templates(cfg: ModelConfig) -> Dict[str, Any]:
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.num_experts
+    """The router over every expert; weights of the held experts only."""
+    d, f, e = cfg.d_model, cfg.d_ff, cfg.num_held_experts
     out_scale = 0.02 / np.sqrt(2 * cfg.num_layers)
     t = {
-        "router": ParamTemplate((d, e), ("embed", None)),
+        "router": ParamTemplate((d, cfg.num_experts), ("embed", None)),
         "w_gate": ParamTemplate((e, d, f), ("experts", "embed", "expert_ffn")),
         "w_up": ParamTemplate((e, d, f), ("experts", "embed", "expert_ffn")),
         "w_down": ParamTemplate((e, f, d), ("experts", "expert_ffn", "embed"),

@@ -29,7 +29,6 @@ SCRIPT = textwrap.dedent("""
                       vocab_size=256, block_pattern=(ATTN,),
                       ffn_pattern=(MOE,), num_experts=4,
                       experts_per_token={k}, dtype="float32",
-                      capacity_factor=8.0,       # no drops on either path
                       attn_impl="naive", remat=False)
     key = jax.random.PRNGKey(0)
     params = init_params(cfg, key)["blocks"]["layer_0"]["moe"]
