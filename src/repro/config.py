@@ -81,13 +81,15 @@ class ModelConfig:
     # implementation knobs (not architecture) -----------------------------
     attn_impl: str = "chunked"      # naive | chunked | pallas
     attn_chunk: int = 512           # query/kv block for chunked attention
-    # Paged-decode backend for the continuous engine's hot loop
-    # (repro.kernels.ops.paged_decode): "gather" materializes the logical
-    # KV view and stays bit-identical to the dense decode path (the
-    # static ≡ continuous parity contract — hence the default); "auto"
-    # picks the in-place Pallas kernel on TPU / its jnp ref elsewhere;
-    # "pallas" | "ref" force a backend.
-    paged_attn_impl: str = "gather"
+    # Paged-attention backend for the continuous engine
+    # (repro.kernels.ops.paged_decode / paged_prefill): "auto" decodes
+    # with the in-place Pallas kernel on a TPU and gathers the logical KV
+    # view elsewhere (bit-identical to the dense decode path: the static
+    # ≡ continuous parity contract), and prefills through the gather
+    # view everywhere; an engine on a multi-device plan resolves it to
+    # "gather" (a pallas_call has no GSPMD rule). "gather" | "ref" |
+    # "pallas" force a backend.
+    paged_attn_impl: str = "auto"
     remat: bool = True              # activation checkpointing per block
     # residual-stream sharding constraint between blocks (set by the
     # launcher; nested tuples of mesh axis names / None). E.g. Megatron-SP

@@ -73,19 +73,21 @@ def paged_decode(q, kp, vp, page_table, lengths, *, kind: str = "causal",
     page_table points into the layer's slab (``layer_slab``).
 
     ``impl`` selects the backend (``ModelConfig.paged_attn_impl``):
-      - "gather" (the ModelConfig default): materialize the logical
-        (B, npages·page_size, Hkv, D) view and run ``decode_attention``
-        over it — bit-identical to the pre-kernel path (the static ≡
-        continuous engine parity contract), O(npages) bytes/token. The
-        engine narrows ``page_table`` to the live high-water mark before
-        calling, so even this path stops touching the whole pool.
+      - "gather": materialize the logical (B, npages·page_size, Hkv, D)
+        view and run ``decode_attention`` over it — bit-identical to the
+        dense decode path (the static ≡ continuous engine parity
+        contract), O(slots · npages) bytes/token. The engine narrows
+        ``page_table`` to the live high-water mark before calling.
       - "ref": ``paged_decode_ref`` — per-page online softmax, no
         materialized view, GSPMD-native (kv-heads shard over 'model').
-      - "pallas": the Mosaic kernel, pages DMA'd in place. pallas_call
-        has no GSPMD partitioning rules: on a multi-device mesh call it
-        under shard_map with kv-heads (and the grouped q heads) split
-        over 'model' — same caveat as ``fused_token_logprob``.
-      - None / "auto": pallas on TPU, ref elsewhere.
+      - "pallas": the Mosaic kernel: each slot's live pages DMA'd in
+        place, nothing read for a dead slot (length 0, or past the
+        table's reach). pallas_call has no GSPMD partitioning rules: on
+        a multi-device mesh call it under shard_map with kv-heads (and
+        the grouped q heads) split over 'model' — same caveat as
+        ``fused_token_logprob``; the engine keeps "auto" off it there.
+      - None / "auto" (the ModelConfig default): pallas on a TPU,
+        gather elsewhere.
 
     ``kind``/``window`` follow ``decode_attention``: the sliding-window
     band applies only when kind == "local".
@@ -93,7 +95,7 @@ def paged_decode(q, kp, vp, page_table, lengths, *, kind: str = "causal",
     if impl not in PAGED_IMPLS + (None,):
         raise ValueError(f"unknown paged-attention impl {impl!r}")
     if impl in (None, "auto"):
-        impl = "pallas" if on_tpu() else "ref"
+        impl = "pallas" if on_tpu() else "gather"
     if kind not in ("causal", "local"):
         raise ValueError(f"paged decode is causal-only, got kind={kind!r}")
     eff_window = window if kind == "local" else None
@@ -131,24 +133,25 @@ def paged_prefill(q, kp, vp, page_table, positions, *, kind: str = "causal",
     Returns (B, C, Hq, D).
 
     ``impl`` selects the backend (``ModelConfig.paged_attn_impl``):
-      - "gather" (the ModelConfig default): materialize the logical
-        (B, npages·page_size, Hkv, D) view and run dense ``attention``
-        over it — bit-identical to the pre-kernel chunked-prefill branch
-        of ``models/model.py`` (the static ≡ continuous parity
-        contract), O(table width) bytes/chunk. ``attn_impl``/``chunk``
-        feed through to that dense attention (the flash kernel assumes
-        pos_q = arange(Sq), so "pallas" downgrades to "chunked").
+      - "gather": materialize the logical (B, npages·page_size, Hkv, D)
+        view and run dense ``attention`` over it — bit-identical to the
+        pre-kernel chunked-prefill branch of ``models/model.py`` (the
+        static ≡ continuous parity contract), O(table width)
+        bytes/chunk. ``attn_impl``/``chunk`` feed through to that dense
+        attention (the flash kernel assumes pos_q = arange(Sq), so
+        "pallas" downgrades to "chunked").
       - "ref": ``paged_prefill_ref`` — per-page online softmax, no dense
         view, bytes scale with the batch-max live page count.
       - "pallas": the Mosaic kernel; unreachable pages re-point in the
         index map, so bytes scale with ``pages_for(starts + C)``. Like
         ``paged_decode``, wrap in shard_map to split kv heads on a mesh.
-      - None / "auto": pallas on TPU, ref elsewhere.
+      - None / "auto": gather on every backend (the kernel is untimed on
+        the chip; the view is bounded by the chunk's width bucket).
     """
     if impl not in PAGED_IMPLS + (None,):
         raise ValueError(f"unknown paged-attention impl {impl!r}")
     if impl in (None, "auto"):
-        impl = "pallas" if on_tpu() else "ref"
+        impl = "gather"
     if kind not in ("causal", "local"):
         raise ValueError(f"paged prefill is causal-only, got kind={kind!r}")
     eff_window = window if kind == "local" else None

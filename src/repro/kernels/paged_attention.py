@@ -6,33 +6,35 @@ token per slot against that slot's KV pages *in place* — the pools from
 ``(B, pages_per_slot·page_size, Hkv, D)`` logical view (the legacy path's
 O(pool) HBM traffic per token; see ``repro.kernels.ops.paged_decode``).
 
-Pool layout: ``(num_pages, Hkv, page_size, D)``. One kv head of one page
-is then a contiguous ``(page_size, D)`` tile, the block the kernels DMA —
-tile-aligned on TPU, where a ``(page_size, 1, D)`` slice of a
-``(page_size, Hkv, D)`` page is not.
+Pool layout: ``(num_pages, Hkv, page_size, D)``. One page of all kv
+heads is then one contiguous block (the decode kernel's DMA), and one kv
+head of one page a contiguous ``(page_size, D)`` tile (the prefill
+kernel's block) — tile-aligned on TPU, where a ``(page_size, 1, D)``
+slice of a ``(page_size, Hkv, D)`` page is not.
 
-Kernel layout:
+Decode kernel layout (``paged_attention``):
 
-- grid ``(slot, kv_head, logical_page)`` with the page axis innermost so
-  the online-softmax accumulators (m, l, acc) live in VMEM scratch across
-  page iterations — the flash-attention recurrence over pages;
-- the block table and per-slot ``lengths`` ride in as scalar-prefetch
-  operands (``pltpu.PrefetchScalarGridSpec``), so the kv BlockSpec index
-  map resolves ``table[slot, j]`` to a physical page id before each grid
-  step issues its DMA;
-- pages at or past ``ceil(lengths[slot]/page_size)`` are *dead*: their
-  index map re-points at the slot's last live page (same block index ⇒
-  Pallas skips the copy — no DMA, and ``pl.when`` skips the compute), so
-  bytes and FLOPs scale with the slot's true context length, not the
-  allocator's ``pages_per_slot`` capacity;
-- GQA is resolved in the index maps: all ``rep = Hq // Hkv`` query heads
-  of one kv head run in a single kernel instance against one page fetch;
+- grid ``(slot,)``, run in order: one instance handles all Hkv heads and
+  their ``rep`` grouped query heads against one copy of each page;
+- the pools stay in HBM (``memory_space=pl.ANY``). A slot's pages are
+  copied ``PAGES_PER_BLOCK`` at a time, one page of all kv heads per
+  DMA, into a double-buffered VMEM scratch: block i + 1 is in flight
+  while block i is computed, and a slot's last block starts the next
+  live slot's first;
+- the flattened block table, per-slot live lengths and each slot's
+  next live slot ride in as scalar-prefetch operands
+  (``pltpu.PrefetchScalarGridSpec``); the block loop runs over the
+  slot's live blocks only, so bytes and FLOPs scale with the slot's
+  true context length, not the table width. A dead slot (length 0, or
+  past the table's reach, where the engine parks a finished slot)
+  costs one empty grid step and returns zeros;
 - masking matches ``repro.models.attention.decode_attention``: key
   positions ``idx <= pos`` (with ``pos = lengths - 1``), plus the
-  sliding-window band and attention-logit softcap. Masked positions are
-  zeroed in ``v`` (not just NEG_INF'd in the scores) so garbage in dead
-  page tails — scratch-page contents included, even NaNs — can never
-  reach a live slot's output.
+  sliding-window band (blocks wholly before it are skipped) and
+  attention-logit softcap. In the block holding the slot's last token
+  masked positions are also zeroed in ``v`` (not just NEG_INF'd in the
+  scores), so garbage in dead page tails or stale scratch rows — even
+  NaNs — can never reach a live slot's output.
 
 ``paged_decode_ref`` is the jnp twin (``lax.fori_loop`` over live pages
 with running (m, l, acc)): the CPU oracle and the lowering path, the same
@@ -63,72 +65,149 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# pages the decode kernel copies per block: 128 tokens of every kv head
+PAGES_PER_BLOCK = 8
 
 
-def _mask_scores_and_values(s, v, j, page_size, length, window):
-    """Apply the decode validity band to one page block.
-
-    s (R, page) scores, v (page, D) values; returns masked (s, v) where
-    invalid key positions are NEG_INF in s and *zero* in v — the zeroing
-    is what keeps NaN/garbage in unwritten page tails out of ``p @ v``.
-    """
-    def band(col):
-        ok = col < length
-        if window is not None:
-            ok &= col > length - 1 - window
-        return ok
-
-    cols_s = j * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1)
-    cols_v = j * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (page_size, 1), 0)
-    s = jnp.where(band(cols_s), s, NEG_INF)
-    v = jnp.where(band(cols_v), v, 0.0)
-    return s, v
+def _live_slots(lengths, width: int, page_size: int):
+    """The decode kernel's per-slot operands: each slot's live length
+    (0 for the dead-slot marker, a length past the table's reach
+    ``width·page_size``) and ``nxt`` (B + 1,): ``nxt[0]`` is the first
+    slot with a live token and ``nxt[s + 1]`` the next one after slot
+    ``s`` (B when there is none)."""
+    b = lengths.shape[0]
+    live = jnp.where(lengths > width * page_size, 0, lengths)
+    has = jnp.where(live > 0, jnp.arange(b, dtype=jnp.int32), b)
+    after = jax.lax.cummin(has, reverse=True)            # min over s' >= s
+    return live, jnp.concatenate([after, jnp.full((1,), b, jnp.int32)])
 
 
-def _kernel(table_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
-            m_scr, l_scr, acc_scr, *, scale: float, window: Optional[int],
-            softcap: Optional[float], page_size: int, npages: int):
+def _decode_kernel(table_ref, lengths_ref, nxt_ref, q_ref, kp_ref, vp_ref,
+                   o_ref, kbuf, vbuf, sems, buf_ref,
+                   m_scr, l_scr, acc_scr, *, scale: float,
+                   window: Optional[int], softcap: Optional[float],
+                   page_size: int, width: int, ppb: int, num_slots: int):
     s_id = pl.program_id(0)
-    j = pl.program_id(2)
+    hkv = kbuf.shape[2]
+    tokens = ppb * page_size
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def first_block(slot):
+        """The first block a slot's band reaches (its window's floor)."""
+        if window is None:
+            return 0
+        return jnp.maximum(lengths_ref[slot] - window, 0) // tokens
 
+    def block_copies(slot, blk, buf):
+        """(live?, K copy, V copy) of each page of ``slot``'s block
+        ``blk`` into buffer ``buf``. Pages past the slot's length are not
+        copied: their buffer rows keep whatever they held, and the tail
+        block masks them."""
+        n_pages = (lengths_ref[slot] + page_size - 1) // page_size
+        out = []
+        for i in range(ppb):
+            j = blk * ppb + i
+            page = table_ref[slot * width + jnp.minimum(j, width - 1)]
+            out.append((j < n_pages,
+                        pltpu.make_async_copy(kp_ref.at[page], kbuf.at[buf, i],
+                                              sems.at[0, buf]),
+                        pltpu.make_async_copy(vp_ref.at[page], vbuf.at[buf, i],
+                                              sems.at[1, buf])))
+        return out
+
+    def start(slot, blk, buf):
+        for live, kc, vc in block_copies(slot, blk, buf):
+            @pl.when(live)
+            def _():
+                kc.start()
+                vc.start()
+
+    def wait(slot, blk, buf):
+        for live, kc, vc in block_copies(slot, blk, buf):
+            @pl.when(live)
+            def _():
+                kc.wait()
+                vc.wait()
+
+    # the first live slot's first block; every later slot's first block
+    # is started by the previous live slot's last block (below)
+    @pl.when(s_id == 0)
+    def _prologue():
+        buf_ref[0] = 0
+        first_slot = nxt_ref[0]
+
+        @pl.when(first_slot < num_slots)
+        def _():
+            start(first_slot, first_block(first_slot), 0)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
     length = lengths_ref[s_id]
-    live = j * page_size < length
+    n_blk = (length + tokens - 1) // tokens
+    nxt = nxt_ref[s_id + 1]
 
-    @pl.when(live)
-    def _body():
-        q = q_ref[...].astype(jnp.float32)                # (rep, D)
-        k = k_ref[...].astype(jnp.float32)                # (page, D)
-        v = v_ref[...].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * scale                                     # (rep, page)
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        s, v = _mask_scores_and_values(s, v, j, page_size, length, window)
+    def attend(blk, tail: bool):
+        """Online softmax over block ``blk`` for every kv head, with the
+        slot's next block (or, from its tail block, the next live slot's
+        first block) in flight meanwhile. Only the tail block reaches
+        positions >= length: there masked scores are NEG_INF and masked
+        values zero, so stale or garbage rows (even NaN) never reach the
+        output. A block before it needs a mask only for the window."""
+        buf = buf_ref[0]
+        if not tail:
+            start(s_id, blk + 1, 1 - buf)
+        else:
+            @pl.when(nxt < num_slots)
+            def _():
+                start(nxt, first_block(nxt), 1 - buf)
 
-        m_prev = m_scr[...]                               # (rep, 1)
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
-        acc_scr[...] = (acc_scr[...] * alpha
-                        + jax.lax.dot_general(
-                            p, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
-        m_scr[...] = m_new
+        wait(s_id, blk, buf)
+        masked = tail or window is not None
+        if masked:
+            cols = blk * tokens + jax.lax.broadcasted_iota(
+                jnp.int32, (1, tokens), 1)
+            ok = cols < length if tail else cols > length - 1 - window
+            if tail and window is not None:
+                ok &= cols > length - 1 - window
+        for h in range(hkv):
+            q = q_ref[h]                                  # (rep, D)
+            k = kbuf[buf, :, h].reshape(tokens, -1)       # (T, D)
+            v = vbuf[buf, :, h].reshape(tokens, -1)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * scale                                 # (rep, T)
+            if softcap is not None:
+                s = softcap * jnp.tanh(s / softcap)
+            if masked:
+                s = jnp.where(ok, s, NEG_INF)
+            if tail:
+                rows = blk * tokens + jax.lax.broadcasted_iota(
+                    jnp.int32, (tokens, 1), 0)
+                v = jnp.where(rows < length, v, jnp.zeros((), v.dtype))
+            m_prev = m_scr[h]                             # (rep, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_scr[h] = l_scr[h] * alpha + p.sum(axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
+        buf_ref[0] = 1 - buf
 
-    @pl.when(j == npages - 1)
-    def _finish():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
+    def body(blk, carry):
+        attend(blk, tail=False)
+        return carry
+
+    first = first_block(s_id)
+    jax.lax.fori_loop(first, jnp.maximum(n_blk - 1, first), body, 0)
+
+    @pl.when(n_blk > first)
+    def _tail():
+        attend(n_blk - 1, tail=True)
+
+    l = jnp.maximum(l_scr[...], 1e-30)
+    o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def paged_attention(q: jax.Array, kp: jax.Array, vp: jax.Array,
@@ -139,53 +218,60 @@ def paged_attention(q: jax.Array, kp: jax.Array, vp: jax.Array,
     """Decode-step attention against paged KV pools, in place.
 
     q (B, Hq, D) single query token per slot; kp/vp
-    (num_pages, Hkv, page_size, D) page pools; page_table (B, npages)
-    int32 slot→physical-page map; lengths (B,) int32 valid tokens per
-    slot (``pos + 1`` — the current token's k/v must already be
-    scattered into the pools). Returns (B, Hq, D) in q.dtype.
+    (num_pages, Hkv, page_size, D) page pools; page_table (B, W) int32
+    slot→physical-page map; lengths (B,) int32 valid tokens per slot
+    (``pos + 1`` — the current token's k/v must already be scattered
+    into the pools). A length past the table's reach (``> W·page_size``,
+    where the engine parks a finished slot) marks a dead slot: it reads
+    no page and returns zeros, as does a length of 0. Returns
+    (B, Hq, D) in q.dtype.
+
+    One grid step per slot handles all Hkv heads and their ``rep``
+    query heads. The pools stay in HBM (``memory_space=pl.ANY``); the
+    slot's live pages are copied ``PAGES_PER_BLOCK`` at a time into a
+    double-buffered VMEM scratch, block i + 1 in flight while block i is
+    computed, and the next live slot's first block in flight during
+    this slot's last. The block loop runs over the slot's live blocks
+    only, so a dead slot costs one empty grid step.
     """
     b, hq, d = q.shape
     num_pages, hkv, page_size, dk = kp.shape
     assert d == dk and hq % hkv == 0, (q.shape, kp.shape)
     rep = hq // hkv
-    npages = page_table.shape[1]
-    qr = q.reshape(b, hkv, rep, d)
-
-    def q_map(s, h, j, table_ref, lengths_ref):
-        del table_ref, lengths_ref, j
-        return (s, h, 0, 0)
-
-    def kv_map(s, h, j, table_ref, lengths_ref):
-        # dead pages re-point at the slot's last live page: identical
-        # consecutive block indices make Pallas skip the DMA, and the
-        # body's pl.when(live) skips the compute.
-        length = lengths_ref[s]
-        last_live = jnp.maximum(pl.cdiv(length, page_size) - 1, 0)
-        jj = jnp.minimum(j, last_live)
-        return (table_ref[s, jj], h, 0, 0)
-
-    q_block = pl.BlockSpec((None, None, rep, d), q_map)
-    kv_block = pl.BlockSpec((None, None, page_size, d), kv_map)
+    width = page_table.shape[1]
+    ppb = min(PAGES_PER_BLOCK, width)
+    live, nxt = _live_slots(lengths.astype(jnp.int32), width, page_size)
+    q_block = pl.BlockSpec((None, hkv, rep, d), lambda s, *_: (s, 0, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv, npages),
-        in_specs=[q_block, kv_block, kv_block],
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[q_block, pool, pool],
         out_specs=q_block,
         scratch_shapes=[
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, d), jnp.float32),
+            pltpu.VMEM((2, ppb, hkv, page_size, d), kp.dtype),
+            pltpu.VMEM((2, ppb, hkv, page_size, d), vp.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((hkv, rep, 1), jnp.float32),
+            pltpu.VMEM((hkv, rep, 1), jnp.float32),
+            pltpu.VMEM((hkv, rep, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=d ** -0.5, window=window,
-                          softcap=softcap, page_size=page_size,
-                          npages=npages),
+        functools.partial(_decode_kernel, scale=d ** -0.5, window=window,
+                          softcap=softcap, page_size=page_size, width=width,
+                          ppb=ppb, num_slots=b),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rep, d), q.dtype),
+        # a slot's last block starts the next slot's first: the grid
+        # runs in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode",
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), qr, kp, vp)
+    )(page_table.astype(jnp.int32).reshape(-1), live, nxt,
+      q.reshape(b, hkv, rep, d).astype(kp.dtype), kp, vp)
     return out.reshape(b, hq, d)
 
 
@@ -196,8 +282,9 @@ def paged_decode_ref(q: jax.Array, kp: jax.Array, vp: jax.Array,
     """Pure-JAX twin of ``paged_attention``: ``fori_loop`` over logical
     pages with running (m, l, acc), bounded by the batch-max live page
     count so work scales with occupancy. Same shapes/semantics as the
-    kernel; this is the CPU oracle and the GSPMD-native lowering path
-    (the per-page gather partitions cleanly with kv-heads on 'model')."""
+    kernel, dead-slot marker included (zeros); this is the CPU oracle
+    and the GSPMD-native lowering path (the per-page gather partitions
+    cleanly with kv-heads on 'model')."""
     b, hq, d = q.shape
     hkv, page_size = kp.shape[1], kp.shape[2]
     rep = hq // hkv
@@ -210,6 +297,8 @@ def paged_decode_ref(q: jax.Array, kp: jax.Array, vp: jax.Array,
     qg = q.reshape(b, hkv, rep, d).astype(kp.dtype)
     table = page_table.astype(jnp.int32)
     lengths = lengths.astype(jnp.int32)
+    # the kernel's dead-slot marker: a length past the table reads nothing
+    lengths = jnp.where(lengths > npages * page_size, 0, lengths)
 
     def body(j, carry):
         m_run, l_run, acc = carry

@@ -86,24 +86,32 @@ def _self_attn(cfg: ModelConfig, p: Dict, x: jax.Array, *, kind: str,
         # below drops the write instead of clamping onto a live page
         phys = jnp.take_along_axis(page_table, page, axis=1, mode="fill",
                                    fill_value=jnp.iinfo(jnp.int32).min)
-        kp = kp.at[block, phys, :, off].set(k.astype(kp.dtype))
-        vp = vp.at[block, phys, :, off].set(v.astype(vp.dtype))
+        # one (D,) row per (token, kv head): the written window is the
+        # pool's minor axis, so XLA keeps the pools row-major in the
+        # layer scan, the layout the Pallas kernels read. With a
+        # (Hkv, D) window XLA stores them (P, page, Hkv, D) there and
+        # relays both stacked pools around the scan and every kernel.
+        head = jnp.arange(kp.shape[2])
+        at = (block, phys[..., None], head, off[..., None])
+        kp = kp.at[at].set(k.astype(kp.dtype))
+        vp = vp.at[at].set(v.astype(vp.dtype))
         # every backend attends the layer's slab in place, through the
         # folded pools and a table offset by block·P
         from repro.kernels.ops import layer_slab, paged_decode, paged_prefill
         kpl, vpl, table = layer_slab(kp, vp, page_table, block)
         if sq == 1:                                       # decode
-            # hot loop: attend the pools in place (or via the bit-exact
-            # gather fallback) — repro.kernels.ops.paged_decode. The
-            # engine narrows page_table to the live high-water mark, so
-            # every impl scales with context, not pool capacity.
+            # hot loop: attend the pools in place (the Pallas kernel on
+            # a TPU) or via the bit-exact gather view —
+            # repro.kernels.ops.paged_decode. The engine narrows
+            # page_table to the live high-water mark, so every impl
+            # scales with context, not pool capacity.
             o = paged_decode(q, kpl, vpl, table, positions[:, 0] + 1,
                              kind=mask_kind, window=cfg.sliding_window,
                              softcap=cfg.attn_softcap,
                              impl=cfg.paged_attn_impl)
         else:                                             # chunked prefill
             # attend the pools in place (ref/pallas) or via the dense
-            # per-slot gather (the bit-exact ModelConfig default) —
+            # per-slot gather (what "auto" runs) —
             # repro.kernels.ops.paged_prefill. The engine narrows
             # page_table to pages_for(c0 + C), so the gather view is
             # bounded by the chunk's pow2 width bucket; the kernel/ref

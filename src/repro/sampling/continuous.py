@@ -23,9 +23,10 @@ cached pages hold exactly the K/V a cold prefill would write.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -309,6 +310,10 @@ class ContinuousEngine:
             raise ValueError(f"{cfg.name}: continuous engine needs an "
                              "attention-only decode cache (no enc-dec / "
                              "ring-KV / modality memory)")
+        if cfg.paged_attn_impl == "auto" and plan is not None \
+                and plan.num_devices > 1:
+            # a pallas_call has no GSPMD rule: sharded engines gather
+            cfg = dataclasses.replace(cfg, paged_attn_impl="gather")
         self.cfg, self.rl, self.params, self.plan = cfg, rl, params, plan
         self.vocab_limit = vocab_limit or cfg.padded_vocab
         self.num_slots = num_slots
@@ -598,9 +603,14 @@ class ContinuousEngine:
             pages_for(int(self._pos[self._active].max()) + self.sync_every,
                       self.page_size),
             self.pages_per_slot)
+        steps = np.where(self._active,
+                         np.clip(self._max_new - self._gen, 0,
+                                 self.sync_every), 0)
+        read, table = self._count_kv_pages(self._pos, steps, width)
         with self._tr.span("decode", track="engine",
                            slots=len(dec), chunk=self.sync_every,
-                           width=width):
+                           width=width, kv_pages_read=read,
+                           kv_pages_table=table):
             bt = sched.block_table[:, :width].copy()
             bt[~self._active] = SCRATCH_PAGE
             toks, lps, self._last, self.pool = _decode_chunk_jit(
@@ -618,6 +628,28 @@ class ContinuousEngine:
         with self._tr.span("sync", track="engine"):
             tok_np, lp_np = np.asarray(toks), np.asarray(lps)  # noqa: RA003
         self._commit_chunk(dec, tok_np, lp_np, now, events)
+
+    def _count_kv_pages(self, pos: np.ndarray, steps: np.ndarray,
+                        width: int) -> Tuple[int, int]:
+        """KV pages one decode chunk reads: (in place, through the
+        gather view), added to the ``decode_kv_pages_read`` /
+        ``decode_kv_pages_table`` counters and returned for the chunk's
+        ``decode`` span.
+
+        Slot ``s`` decodes ``steps[s]`` live steps (0 when it is not
+        decoding) from position ``pos[s]``; step i attends
+        ``pages_for(pos[s] + i + 1)`` pages, while the gather view reads
+        the whole ``width``-page table of every slot at every step.
+        Steps come from the token budgets known at dispatch, so a slot
+        that ends on EOS inside the chunk counts to the chunk's end."""
+        i = np.arange(self.sync_every)
+        lengths = pos[:, None] + 1 + i[None, :]
+        read = int(np.where(i[None, :] < steps[:, None],
+                            -(-lengths // self.page_size), 0).sum())
+        table = self.num_slots * width * self.sync_every
+        self.sched.stats["decode_kv_pages_read"] += read
+        self.sched.stats["decode_kv_pages_table"] += table
+        return read, table
 
     def _commit_chunk(self, dec: List[GenRequest], tok_np: np.ndarray,
                       lp_np: np.ndarray, now: float,
@@ -844,8 +876,13 @@ class ContinuousEngine:
         width = _live_width(
             pages_for(int(pos0.max()) + self.sync_every, self.page_size),
             self.pages_per_slot)
+        steps = np.where(self._active,
+                         np.clip(self._max_new - gen_base - 1, 0,
+                                 self.sync_every), 0)
+        read, table = self._count_kv_pages(pos0, steps, width)
         with self._tr.span("decode", track="engine", slots=len(dec),
-                           chunk=self.sync_every, width=width):
+                           chunk=self.sync_every, width=width,
+                           kv_pages_read=read, kv_pages_table=table):
             bt = sched.block_table[:, :width].copy()
             bt[~self._active] = SCRATCH_PAGE
             toks, lps, self.pool = _spec_decode_chunk_jit(

@@ -105,6 +105,9 @@ class ContinuousScheduler:
         self.stats: Dict[str, int] = {
             "submitted": 0, "admitted": 0, "completed": 0, "expired": 0,
             "max_active": 0, "decode_steps": 0, "decode_slot_steps": 0,
+            # KV pages the decode chunks read in place / the gather view
+            # reads (ContinuousEngine._count_kv_pages)
+            "decode_kv_pages_read": 0, "decode_kv_pages_table": 0,
             "prefill_chunks": 0, "prefill_tokens": 0,
             "prefix_hits": 0, "prefix_tokens_reused": 0, "cow_copies": 0,
             # speculative decode (zero when spec_k == 0)

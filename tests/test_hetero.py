@@ -188,7 +188,7 @@ class TestSamplerTelemetry:
                                task, tok, params, PolicyStore(), hcfg,
                                seed=0, **kw)
 
-        assert node(HeteroConfig()).cfg.paged_attn_impl == "gather"
+        assert node(HeteroConfig()).cfg.paged_attn_impl == "auto"
         s = node(HeteroConfig(paged_attn_impl="ref"))
         assert s.cfg.paged_attn_impl == "ref"
         s = node(HeteroConfig(paged_attn_impl="ref"),

@@ -41,24 +41,56 @@ def make_case(*, b=4, hkv=2, rep=4, d=32, page=8, npages=6, pool=None,
     return q, kp, vp, jnp.asarray(table), jnp.asarray(lengths)
 
 
+def edge_lengths(npages, page, ppb=8):
+    """Lengths at the kernel's edges, for a table of ``npages`` pages:
+    0, 1, one page, one past it, one page block of ``ppb`` pages, one
+    past it, the table's full reach, and one past that (the engine's
+    dead-slot marker)."""
+    block = min(ppb, npages) * page
+    full = npages * page
+    return np.asarray([0, 1, page, page + 1, block, block + 1, full,
+                       full + 1], np.int32)
+
+
+# (Hkv, rep, D, page): small shapes, then the rollout cells' heads —
+# qwen3-1.7b (8 kv heads of 2 queries) and qwen3-30b-a3b (4 of 8)
+SHAPES = {"g2r1p8": (2, 1, 32, 8), "g2r4p8": (2, 4, 32, 8),
+          "g2r1p16": (2, 1, 32, 16), "g2r4p16": (2, 4, 32, 16),
+          "qwen3-1.7b": (8, 2, 128, 16), "qwen3-30b-a3b": (4, 8, 128, 16)}
+
+
 class TestParity:
-    @pytest.mark.parametrize("page", [8, 16])
-    @pytest.mark.parametrize("rep", [1, 4])
+    @pytest.mark.parametrize("shape", sorted(SHAPES))
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    def test_sweep_vs_gather_oracle(self, page, rep, dtype):
-        q, kp, vp, table, lengths = make_case(page=page, rep=rep,
-                                              dtype=dtype, seed=page + rep)
-        oracle = paged_decode(q, kp, vp, table, lengths, impl="gather")
+    def test_sweep_vs_gather_oracle(self, shape, dtype):
+        """Every backend against the gather view at the kernel's edge
+        lengths, with more than one page block in the table. Lengths 0
+        and past the table's reach read nothing: the in-place backends
+        return zeros there."""
+        hkv, rep, d, page = SHAPES[shape]
+        npages = 10
+        lengths = edge_lengths(npages, page)
+        q, kp, vp, table, _ = make_case(b=lengths.size, hkv=hkv, rep=rep,
+                                        d=d, page=page, npages=npages,
+                                        dtype=dtype, seed=hkv + rep + page)
+        lengths = jnp.asarray(lengths)
+        live = np.asarray((lengths > 0) & (lengths <= npages * page))
+        oracle = np.asarray(paged_decode(q, kp, vp, table, lengths,
+                                         impl="gather"), np.float32)
         for impl in ("ref", "pallas"):
-            out = paged_decode(q, kp, vp, table, lengths, impl=impl,
-                               interpret=True)
-            np.testing.assert_allclose(
-                np.asarray(out, np.float32), np.asarray(oracle, np.float32),
-                err_msg=impl, **_tols(dtype))
+            out = np.asarray(paged_decode(q, kp, vp, table, lengths,
+                                          impl=impl, interpret=True),
+                             np.float32)
+            np.testing.assert_allclose(out[live], oracle[live],
+                                       err_msg=impl, **_tols(dtype))
+            np.testing.assert_array_equal(out[~live], 0.0, err_msg=impl)
 
     @pytest.mark.parametrize("window", [5, 16])
     def test_sliding_window_and_softcap(self, window):
-        q, kp, vp, table, lengths = make_case(seed=7)
+        # two page blocks of the kernel: long slots' bands start in the
+        # second, so the kernel skips the first
+        q, kp, vp, table, _ = make_case(b=6, npages=10, seed=7)
+        lengths = jnp.asarray([1, 9, 64, 65, 70, 80], jnp.int32)
         for cap in (None, 20.0):
             oracle = paged_decode(q, kp, vp, table, lengths, kind="local",
                                   window=window, softcap=cap, impl="gather")
@@ -103,20 +135,34 @@ class TestScratchPoisoning:
 
     @pytest.mark.parametrize("impl", ["ref", "pallas"])
     def test_nan_scratch_page_invisible(self, impl):
-        q, kp, vp, table, lengths = make_case(seed=3, max_len=3 * 8)
-        # dead tail of every slot parked on the scratch page, like the
-        # engine's block table for partially-filled slots
+        """NaN in every page a backend must not read: the scratch page,
+        the pages of each slot's table past its live pages, and the
+        whole row of a dead slot (length past the table's reach, the
+        engine's marker for a finished slot). Live outputs stay bit for
+        bit those of a clean pool; the dead slot reads zeros."""
+        q, kp, vp, table, lengths = make_case(b=5, seed=3, npages=12,
+                                              max_len=3 * 8)
+        page = kp.shape[2]
+        # dead tail of slots 0-1 parked on the scratch page, like the
+        # engine's block table for partially-filled slots; slots 2-3
+        # keep their own pages past their length
         tb = np.asarray(table).copy()
-        tb[:, 4:] = 0
-        clean = paged_decode(q, kp, vp, jnp.asarray(tb), lengths,
-                             impl=impl, interpret=True)
-        kp_bad = kp.at[0].set(jnp.nan)
-        vp_bad = vp.at[0].set(jnp.nan)
-        poisoned = paged_decode(q, kp_bad, vp_bad, jnp.asarray(tb), lengths,
+        tb[:2, 4:] = 0
+        ln = np.asarray(lengths).copy()
+        ln[4] = tb.shape[1] * page + 1
+        tb, ln = jnp.asarray(tb), jnp.asarray(ln)
+        clean = paged_decode(q, kp, vp, tb, ln, impl=impl, interpret=True)
+        unread = {0} | {int(tb[s, j]) for s in range(4)
+                        for j in range(-(-int(ln[s]) // page), tb.shape[1])}
+        unread |= {int(p) for p in np.asarray(tb[4])}
+        unread = jnp.asarray(sorted(unread))
+        poisoned = paged_decode(q, kp.at[unread].set(jnp.nan),
+                                vp.at[unread].set(jnp.nan), tb, ln,
                                 impl=impl, interpret=True)
         assert bool(jnp.isfinite(poisoned).all())
         np.testing.assert_array_equal(np.asarray(poisoned),
                                       np.asarray(clean))
+        np.testing.assert_array_equal(np.asarray(poisoned[4]), 0.0)
 
     @pytest.mark.parametrize("impl", ["ref", "pallas"])
     def test_dead_slot_yields_finite_output(self, impl):
@@ -144,10 +190,18 @@ class TestDispatcher:
             paged_decode(q, kp, vp, table, lengths, kind="bidir")
 
     def test_auto_matches_ref_off_tpu(self):
+        """Off a TPU "auto" (and None) decode through the gather view,
+        bit for bit, and so agree with the ref backend to rounding."""
         q, kp, vp, table, lengths = make_case(seed=9)
-        auto = paged_decode(q, kp, vp, table, lengths)
+        auto = paged_decode(q, kp, vp, table, lengths, impl="auto")
+        gather = paged_decode(q, kp, vp, table, lengths, impl="gather")
+        np.testing.assert_array_equal(np.asarray(auto), np.asarray(gather))
+        np.testing.assert_array_equal(
+            np.asarray(paged_decode(q, kp, vp, table, lengths)),
+            np.asarray(gather))
         ref = paged_decode(q, kp, vp, table, lengths, impl="ref")
-        np.testing.assert_array_equal(np.asarray(auto), np.asarray(ref))
+        np.testing.assert_allclose(np.asarray(auto), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
 
     def test_window_ignored_unless_local(self):
         q, kp, vp, table, lengths = make_case(seed=13)
@@ -298,10 +352,31 @@ class TestPrefillDispatcher:
             paged_prefill(q, kp, vp, table, positions, kind="bidir")
 
     def test_auto_matches_ref_off_tpu(self):
+        """"auto" (and None) prefill through the gather view on every
+        backend: bit for bit off a TPU, and within rounding of ref."""
         q, kp, vp, table, positions = make_prefill_case(seed=37)
-        auto = paged_prefill(q, kp, vp, table, positions)
+        auto = paged_prefill(q, kp, vp, table, positions, impl="auto")
+        gather = paged_prefill(q, kp, vp, table, positions, impl="gather")
+        np.testing.assert_array_equal(np.asarray(auto), np.asarray(gather))
+        np.testing.assert_array_equal(
+            np.asarray(paged_prefill(q, kp, vp, table, positions)),
+            np.asarray(gather))
         ref = paged_prefill(q, kp, vp, table, positions, impl="ref")
-        np.testing.assert_array_equal(np.asarray(auto), np.asarray(ref))
+        np.testing.assert_allclose(np.asarray(auto), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+
+    def test_auto_prefills_through_gather_on_tpu(self, monkeypatch):
+        """On a TPU "auto" still prefills through the gather view: the
+        prefill kernel is not the default there."""
+        import repro.kernels.ops as ops_mod
+        monkeypatch.setattr(ops_mod, "on_tpu", lambda: True)
+        q, kp, vp, table, positions = make_prefill_case(seed=39)
+        auto = paged_prefill.lower(q, kp, vp, table, positions,
+                                   impl="auto").as_text()
+        gather = paged_prefill.lower(q, kp, vp, table, positions,
+                                     impl="gather").as_text()
+        assert "tpu_custom_call" not in auto
+        assert auto.replace('"auto"', '"gather"') == gather
 
     def test_no_dense_view_in_ref_lowering(self):
         """The point of the kernel: the ref path's XLA temp footprint
@@ -546,6 +621,77 @@ class TestEngineBackends:
         np.testing.assert_allclose(np.asarray(r1["sampler_lp"]),
                                    np.asarray(r2["sampler_lp"]),
                                    rtol=1e-3, atol=1e-3)
+
+
+class TestEngineKvPages:
+    """The engine's decode page counters and its backend choice."""
+
+    def test_kv_page_counters(self, rng):
+        """Four requests of known prompt lengths and token budgets, all
+        admitted and prefilled in the first round: chunk k of
+        ``sync_every`` steps decodes each unfinished request from
+        position prompt + k·S for as many steps as its budget leaves,
+        and step i attends ceil((pos + i + 1) / page) pages; the gather
+        view reads slots x table width pages a step, the width being the
+        live pages of the chunk's furthest position rounded up to a
+        power of two."""
+        from repro.sampling.continuous import ContinuousEngine
+        from repro.serving.api import Request, SamplingParams
+        page, sync, slots, budget = 4, 3, 6, 40
+        rl = RLConfig(temperature=1.0, top_k=0, top_p=1.0)
+        eng = ContinuousEngine(TINY, init_params(TINY, rng), rl=rl,
+                               max_total_tokens=budget, num_slots=slots,
+                               page_size=page, sync_every=sync,
+                               vocab_limit=20)
+        prompts, max_new = [5, 9, 3, 12], [7, 2, 16, 10]
+        host = np.random.default_rng(0)
+        reqs = [Request(rid=r, prompt=host.integers(3, 20, n).astype(
+                    np.int32),
+                        params=SamplingParams(temperature=1.0, top_k=0,
+                                              top_p=1.0, max_new_tokens=m))
+                for r, (n, m) in enumerate(zip(prompts, max_new))]
+        got = [len(res.tokens) for res in eng.generate(reqs, key=rng)]
+        st = eng.stats()
+
+        def ceil(a, b):
+            return -(-a // b)
+
+        read = table = 0
+        for k in range(ceil(max(got), sync)):
+            live = [r for r in range(len(reqs)) if got[r] > k * sync]
+            far = max(prompts[r] + k * sync for r in live) + sync
+            width = 1
+            while width < ceil(far, page):
+                width *= 2
+            table += slots * min(width, ceil(budget, page)) * sync
+            for r in live:
+                steps = min(sync, max_new[r] - k * sync)
+                read += sum(ceil(prompts[r] + k * sync + i + 1, page)
+                            for i in range(steps))
+        assert st["decode_kv_pages_read"] == read
+        assert st["decode_kv_pages_table"] == table
+        assert 0 < read < table
+
+    @pytest.mark.parametrize("devices", [1, 2])
+    def test_multi_device_plan_keeps_gather(self, rng, devices):
+        """"auto" would run the Pallas kernel on a TPU, and a pallas_call
+        has no GSPMD rule: an engine on a plan over more than one device
+        decodes through the gather view instead. Explicit backends keep
+        their meaning."""
+        from types import SimpleNamespace
+
+        from repro.sampling.continuous import ContinuousEngine
+        params = init_params(TINY, rng)
+        plan = SimpleNamespace(num_devices=devices)
+        rl = RLConfig(temperature=1.0, top_k=0, top_p=1.0)
+        want = "gather" if devices > 1 else "auto"
+        for impl, expect in (("auto", want), ("pallas", "pallas"),
+                             ("ref", "ref")):
+            cfg = dataclasses.replace(TINY, paged_attn_impl=impl)
+            eng = ContinuousEngine(cfg, params, rl=rl, max_total_tokens=16,
+                                   num_slots=2, page_size=4, plan=plan)
+            assert eng.cfg.paged_attn_impl == expect, impl
+        assert ModelConfig.paged_attn_impl == "auto"
 
 
 @pytest.mark.skipif(len(jax.devices()) < 2,

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from _hlo import whole_pool_slices
+from _hlo import whole_pool_copies, whole_pool_slices
 from repro.config import RLConfig
 from repro.configs import get_config
 from repro.kernels import ops
@@ -75,6 +75,15 @@ LOGPROB_AVALS = [((1536, VOCAB), BF16), ((1536,), jnp.int32)]
 
 # 8 slots, 32 pages of 16 tokens each, pool of 1 scratch + 8·32 pages
 POOL = (1 + 8 * 32, HKV, PAGE, HEAD_DIM)
+# the rollout cells' decode: 64 slots at table width 64 over 2049 pages,
+# (Hkv, rep) of qwen3-1.7b and of qwen3-30b-a3b
+SLOTS, WIDTH, PAGES = 64, 64, 2049
+
+
+def _decode_avals(hkv, rep):
+    pool = ((PAGES, hkv, PAGE, HEAD_DIM), BF16)
+    return [((SLOTS, 1, hkv * rep, HEAD_DIM), BF16), pool, pool,
+            ((SLOTS, WIDTH), jnp.int32), ((SLOTS,), jnp.int32)]
 
 
 def _paged_decode(q, kp, vp, table, lengths):
@@ -109,9 +118,8 @@ def _ssd(x, dt, a, b, c):
 CASES = {
     "fused_logprob_fwd": (_fused_logprob_fwd, lambda: LOGPROB_AVALS),
     "fused_logprob_grad": (_fused_logprob_grad, lambda: LOGPROB_AVALS),
-    "paged_decode": (_paged_decode, lambda: [
-        ((8, 1, HQ, HEAD_DIM), BF16), (POOL, BF16), (POOL, BF16),
-        ((8, 32), jnp.int32), ((8,), jnp.int32)]),
+    "paged_decode": (_paged_decode, lambda: _decode_avals(8, 2)),
+    "paged_decode_hkv4_rep8": (_paged_decode, lambda: _decode_avals(4, 8)),
     "paged_prefill": (_paged_prefill, lambda: [
         ((8, 64, HQ, HEAD_DIM), BF16), (POOL, BF16), (POOL, BF16),
         ((8, 32), jnp.int32), ((8, 64), jnp.int32)]),
@@ -129,14 +137,20 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in text, f"{name}: no Mosaic kernel compiled"
 
 
-def test_decode_chunk_keeps_pools_in_place_for_v5e(one_chip):
+@pytest.mark.parametrize("impl", ["gather", "auto"])
+def test_decode_chunk_keeps_pools_in_place_for_v5e(one_chip, monkeypatch,
+                                                   impl):
     """The rollout cell's decode chunk (64 slots, table width 64, 2049
     pages of 16, bf16, plain sampling) at qwen3-1.7b widths, cut to 2
-    layers: the layer scan carries the stacked pools, so the compiled
-    program holds no dynamic-slice or dynamic-update-slice of one
-    layer's whole pool."""
-    cfg = dataclasses.replace(get_config("qwen3-1.7b"), num_layers=2)
-    n, width, pages = 64, 64, 2049
+    layers, through the gather view and through what "auto" runs on a
+    TPU (the Pallas kernel): the layer scan carries the stacked pools,
+    so the compiled program holds no dynamic-slice or
+    dynamic-update-slice of one layer's whole pool, and no copy of a
+    pool, stacked or one layer's, around the scan or the kernel."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("qwen3-1.7b"), num_layers=2,
+                              paged_attn_impl=impl)
+    n, width, pages = SLOTS, WIDTH, PAGES
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -152,5 +166,8 @@ def test_decode_chunk_keeps_pools_in_place_for_v5e(one_chip):
         sds((n,), jnp.bool_), sds((n, 2), jnp.uint32), sds((n,), jnp.int32),
         sds((n,), jnp.int32), vocab_limit=cfg.vocab_size,
         sync_every=8).compile().as_text()
-    assert f"bf16[{cfg.num_blocks},{pages},{HKV},{PAGE},{HEAD_DIM}]" in hlo
+    stacked = f"bf16[{cfg.num_blocks},{pages},{HKV},{PAGE},{HEAD_DIM}]"
+    assert stacked in hlo
     assert whole_pool_slices(hlo, (pages, HKV, PAGE, HEAD_DIM)) == []
+    assert whole_pool_copies(hlo, (pages, HKV, PAGE, HEAD_DIM)) == []
+    assert ('custom_call_target="tpu_custom_call"' in hlo) == (impl == "auto")
