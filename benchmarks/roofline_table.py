@@ -14,8 +14,7 @@ RESULTS = os.environ.get("DRYRUN_RESULTS", "results/dryrun")
 def paged_decode_rows() -> list:
     """Bytes-per-token model of the serving decode hot loop: the legacy
     gather reads (and re-materializes) every slot's full page allotment
-    each step, the in-place kernel reads only the live pages — see
-    ``benchmarks/decode_bench.py`` for the measured twin of this table."""
+    each step, the in-place kernel reads only the live pages."""
     from repro.config import DECODE_32K
     from repro.configs import get_config
 
@@ -43,8 +42,7 @@ def paged_prefill_rows() -> list:
     in-place kernel streams exactly the reachable pages. The gap is the
     pow2 rounding (≤2x) *plus* the materialization itself: gather pays
     its bytes twice (read pool, write view, read view), the kernel
-    once. Measured twin: the prefill sweep in
-    ``benchmarks/decode_bench.py``."""
+    once."""
     from repro.config import DECODE_32K
     from repro.configs import get_config
 

@@ -30,7 +30,6 @@ MODULES = [
     ("serve", "benchmarks.serve_throughput"),
     ("serve_lat", "benchmarks.serve_latency"),
     ("logprob", "benchmarks.logprob_bench"),
-    ("decode", "benchmarks.decode_bench"),
     ("scaling", "benchmarks.scaling_bench"),
     ("sync", "benchmarks.sync_bench"),
     ("sentinel", "benchmarks.recompile_bench"),
@@ -44,10 +43,6 @@ MODULES = [
 # its workers are subprocesses, so the forced device count never leaks;
 # "sync" asserts the chunked weight transport beats whole-blob sync and
 # stays byte-identical — its mesh part subprocesses when devices < 4;
-# "decode" A/Bs the paged-attention hot loops — decode steps and
-# chunked-prefill chunks, plus the fused multi-layer launch —
-# (gather-legacy vs in-place kernel/ref) on the temp-bytes proxy and
-# emits BENCH_decode.json);
 # "serve_lat" drives the admission-controlled front door under Poisson/
 # bursty/overload open-loop load and emits BENCH_serve.json;
 # "sentinel" asserts the engine's pow2-bucketed executable bound under
@@ -55,17 +50,13 @@ MODULES = [
 # "spec" A/Bs speculative decoding (prompt-lookup drafts + k-token paged
 # verification) against sequential decode and asserts the templated k=4
 # speedup/accept-rate bars (emits BENCH_spec.json)
-SMOKE_MODULES = ("fig2", "theory", "logprob", "decode", "scaling", "sync",
+SMOKE_MODULES = ("fig2", "theory", "logprob", "scaling", "sync",
                  "serve_lat", "sentinel", "spec")
 
 
 # One headline metric per legacy BENCH_*.json artifact (newer artifacts
 # carry an explicit "headline" block instead and need no entry here).
 _HEADLINE_PICKERS = {
-    "BENCH_decode.json": lambda d: {
-        "metric": "gather_over_ref_temp_max_ctx",
-        "value": d["gather_over_ref_temp"][
-            max(d["gather_over_ref_temp"], key=int)]},
     "BENCH_serve.json": lambda d: {
         "metric": "poisson_slo_tokens_per_s",
         "value": d["poisson"]["slo"]["tokens_per_s"]},

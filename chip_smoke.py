@@ -142,8 +142,8 @@ def serve_phase(arch: str = "qwen3-1.7b",
                                       tok, args.batch, sp)
     out = {}
     for impl in ("pallas", "ref"):
-        serve = dataclasses.replace(dep.serve, paged_attn_impl=impl)
-        engine = build_engine(dep.cfg, dep.params, serve, rl=dep.rl,
+        cfg = dataclasses.replace(dep.cfg, paged_attn_impl=impl)
+        engine = build_engine(cfg, dep.params, dep.serve, rl=dep.rl,
                               vocab_limit=tok.vocab_size, plan=dep.plan,
                               key=dep.key)
         c0, t0 = compile_seconds(), time.perf_counter()

@@ -137,7 +137,7 @@ def spec_verify_width_buckets(spec_k: int) -> int:
     (sampling/continuous.py): the window holds 1 pending token plus
     0..spec_k drafts, bucketed through the same pow2 rounding as
     `_live_width` with a floor of 2 (width-1 windows would route to the
-    decode kernel, which has no query-recording path). Cross-checked
+    decode path instead of the prefill-shaped one). Cross-checked
     against ``repro.sampling.spec.verify_width_buckets`` in tests.
     """
     widths = set()

@@ -96,9 +96,6 @@ class ModelConfig:
     # style ((("pod","data"),), "model", None) shards (B, S, d) as
     # batch->dp, seq->model.
     act_sharding: Optional[Tuple] = None
-    # §Perf H-A1 (REFUTED for dense-train: 3.3× more collective bytes —
-    # see EXPERIMENTS.md): force head-sharded full-S q/k/v before attention.
-    attn_gather_qkv: bool = False
     # §Perf H-B2/H-C3: shard_map expert-parallel MoE ("train"|"serve",
     # None = GSPMD baseline); ep_dp_axes = data axes of the mesh.
     moe_ep: Optional[str] = None
@@ -293,10 +290,6 @@ class HeteroConfig:
     # same conventions as TrainConfig.mesh. All sampler nodes share it —
     # HeteroRL's point is that it can differ from the learner's mesh.
     sampler_mesh: str = "1x1"
-    # Sampler-side paged-decode backend override (ModelConfig.
-    # paged_attn_impl vocabulary; None keeps the arch default). Lets the
-    # hetero sweeps A/B the in-place kernel against the gather path.
-    paged_attn_impl: Optional[str] = None
     # Sampler-side speculative decoding (continuous engine only): draft
     # cap per verification round; 0 = off. Distribution-preserving, so
     # table2-style runs can A/B it purely as a decode-latency lever.
@@ -331,16 +324,12 @@ class ServeConfig:
     prefix_cache: bool = True        # shared-prefix KV page reuse
     prefix_cache_entries: int = 64
     mesh: str = "1x1"                # serve mesh DxM (TrainConfig.mesh conv.)
-    paged_attn_impl: Optional[str] = None   # ModelConfig override (None=keep)
     # speculative decoding (continuous engine only): drafts per
     # verification round (0 = off). Acceptance preserves the sampled
     # distribution exactly; greedy stays bit-identical to spec off.
     spec_k: int = 0
     spec_ngram_max: int = 3          # prompt-lookup suffix n-gram (longest)
     spec_ngram_min: int = 1          # ... shortest suffix tried
-    # rescore acceptance through one fused paged_prefill_layers launch
-    # per round and export max |fused - in-forward| as a drift gauge
-    spec_rescore: bool = True
     # front door -----------------------------------------------------------
     host: str = "127.0.0.1"
     port: int = 8100

@@ -60,18 +60,10 @@ class SamplerNode:
                  hcfg: HeteroConfig, seed: int,
                  engine: Optional[str] = None,
                  logprob_impl: str = "fused",
-                 paged_attn_impl: Optional[str] = None,
                  plan: Optional[ExecutionPlan] = None,
                  serve: Optional[ServeConfig] = None,
                  spec_k: Optional[int] = None) -> None:
         self.sid = sid
-        # sampler-side paged-decode backend (explicit arg beats the
-        # HeteroConfig knob beats the arch default) — the A/B lever for
-        # hetero sweeps: a different impl is a different jit key, so the
-        # replaced config keeps executables per-backend.
-        pa = paged_attn_impl or hcfg.paged_attn_impl
-        if pa is not None:
-            cfg = dataclasses.replace(cfg, paged_attn_impl=pa)
         self.cfg, self.rl = cfg, rl
         self.pipeline, self.task, self.tok = pipeline, task, tok
         # serve-mode execution plan of this node (defaults to the

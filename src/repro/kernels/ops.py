@@ -184,8 +184,8 @@ def layer_slab(kp, vp, page_table, layer):
     that copies nothing, and the table's physical pages offset by
     layer·P so they index the layer's slab. Every paged backend then
     reads exactly the pages it would read from that layer's own pool.
-    ``layer`` is an int32 scalar, or an array that broadcasts against
-    ``page_table``. Returns (kp, vp, table).
+    ``layer`` is an int32 scalar, the block scan's block index. Returns
+    (kp, vp, table).
     """
     lyr, pool_pages = kp.shape[:2]
 
@@ -193,81 +193,6 @@ def layer_slab(kp, vp, page_table, layer):
         return pool.reshape((lyr * pool_pages,) + pool.shape[2:])
     return (fold(kp), fold(vp),
             page_table.astype(jnp.int32) + layer * pool_pages)
-
-
-def _fold_layers(q, kp, vp, page_table, lengths):
-    """Fold a leading layer axis into the slot axis so ONE kernel launch
-    serves every layer's pools.
-
-    q (L, B, ...), kp/vp (L, P, Hkv, page, D), page_table (B, W),
-    lengths (B,) → per-layer operands stacked along slots: the pools
-    concatenate to (L·P, ...), and layer l's table rows offset by l·P so
-    they index the l-th pool slab (``layer_slab``). Slots never mix
-    across grid steps, so the folded launch is bit-exact vs L per-layer
-    launches — it just amortizes one grid setup and one scalar-prefetch
-    DMA over all layers instead of paying them L times.
-    """
-    lyr, b = q.shape[0], q.shape[1]
-    layers = jnp.arange(lyr, dtype=jnp.int32)[:, None, None]
-    kpf, vpf, tablef = layer_slab(kp, vp, page_table, layers)
-    tablef = tablef.reshape(lyr * b, -1)
-    lengthsf = jnp.broadcast_to(lengths, (lyr,) + lengths.shape
-                                ).reshape(lyr * b)
-    qf = q.reshape((lyr * b,) + q.shape[2:])
-    return qf, kpf, vpf, tablef, lengthsf
-
-
-@functools.partial(jax.jit, static_argnames=("kind", "window", "softcap",
-                                             "impl", "interpret"))
-def paged_decode_layers(q, kp, vp, page_table, lengths, *,
-                        kind: str = "causal", window: Optional[int] = None,
-                        softcap: Optional[float] = None,
-                        impl: Optional[str] = None,
-                        interpret: Optional[bool] = None):
-    """``paged_decode`` over all layers' pools in ONE launch.
-
-    q (L, B, 1, Hq, D) per-layer queries; kp/vp (L, P, Hkv, page, D)
-    stacked pools (the scanned-block layout of ``init_paged_cache``);
-    page_table (B, W) and lengths (B,) shared by every layer. Returns
-    (L, B, 1, Hq, D), bit-exact vs L separate ``paged_decode`` calls.
-
-    Inside the model's forward pass layer l's *query* depends on layer
-    l-1's output, so the block scan cannot use this; it serves callers
-    that already hold all layers' queries (speculative scoring, KV-pool
-    maintenance sweeps) and pins the launch-count/bit-exactness claim
-    the benchmarks measure.
-    """
-    lyr, b = q.shape[0], q.shape[1]
-    qf, kpf, vpf, tablef, lengthsf = _fold_layers(q, kp, vp, page_table,
-                                                  lengths)
-    o = paged_decode(qf, kpf, vpf, tablef, lengthsf, kind=kind,
-                     window=window, softcap=softcap, impl=impl,
-                     interpret=interpret)
-    return o.reshape((lyr, b) + o.shape[1:])
-
-
-@functools.partial(jax.jit, static_argnames=("kind", "window", "softcap",
-                                             "impl", "attn_impl", "chunk",
-                                             "interpret"))
-def paged_prefill_layers(q, kp, vp, page_table, positions, *,
-                         kind: str = "causal", window: Optional[int] = None,
-                         softcap: Optional[float] = None,
-                         impl: Optional[str] = None,
-                         attn_impl: str = "chunked", chunk: int = 512,
-                         interpret: Optional[bool] = None):
-    """``paged_prefill`` over all layers' pools in ONE launch: q
-    (L, B, C, Hq, D), kp/vp (L, P, Hkv, page, D), positions (B, C)
-    shared across layers. Returns (L, B, C, Hq, D), bit-exact vs L
-    separate calls — same layer-folding as ``paged_decode_layers``."""
-    lyr, b = q.shape[0], q.shape[1]
-    lengths = (positions[:, -1] + 1).astype(jnp.int32)
-    qf, kpf, vpf, tablef, _ = _fold_layers(q, kp, vp, page_table, lengths)
-    posf = jnp.broadcast_to(positions, (lyr,) + positions.shape
-                            ).reshape((lyr * b,) + positions.shape[1:])
-    o = paged_prefill(qf, kpf, vpf, tablef, posf, kind=kind, window=window,
-                      softcap=softcap, impl=impl, attn_impl=attn_impl,
-                      chunk=chunk, interpret=interpret)
-    return o.reshape((lyr, b) + o.shape[1:])
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

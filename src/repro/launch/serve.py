@@ -52,11 +52,10 @@ def parse_serve_config(args: argparse.Namespace) -> ServeConfig:
         max_total_tokens=args.max_total_tokens
         or args.prompt_width + args.max_new,
         num_pages=args.num_pages, prefix_cache=not args.no_prefix_cache,
-        mesh=args.mesh, paged_attn_impl=args.paged_attn_impl,
+        mesh=args.mesh,
         host=args.host, port=args.port, max_queue=args.max_queue,
         default_deadline_s=args.deadline_s, seed=args.seed,
-        spec_k=args.spec_k, spec_ngram_max=args.spec_ngram,
-        spec_rescore=not args.no_spec_rescore)
+        spec_k=args.spec_k, spec_ngram_max=args.spec_ngram)
 
 
 class Deployment(NamedTuple):
@@ -99,17 +98,12 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap.add_argument("--mesh", default="1x1",
                     help="serve mesh DxM (batch over data × tensor "
                          "parallel over model)")
-    ap.add_argument("--paged-attn-impl", default=None,
-                    choices=("auto", "pallas", "ref", "gather"))
     ap.add_argument("--spec-k", type=int, default=0,
                     help="speculative decoding: drafts per verification "
                          "round (0 = off; continuous engine only)")
     ap.add_argument("--spec-ngram", type=int, default=3,
                     help="longest suffix n-gram the prompt-lookup "
                          "drafter matches")
-    ap.add_argument("--no-spec-rescore", action="store_true",
-                    help="skip the fused-layers acceptance rescore "
-                         "(drops the drift gauge, saves one launch/round)")
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8100)
     ap.add_argument("--max-queue", type=int, default=256)

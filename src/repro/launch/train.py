@@ -113,11 +113,6 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap.add_argument("--sampler-mesh", default="1x1",
                     help="sampler-node mesh DxM (serve-mode tensor "
                          "parallel)")
-    ap.add_argument("--paged-attn-impl", default=None,
-                    choices=["auto", "pallas", "ref", "gather"],
-                    help="sampler paged-decode backend for hetero A/B "
-                         "sweeps (HeteroConfig.paged_attn_impl; default "
-                         "keeps the arch's ModelConfig knob)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--eval-every", type=int, default=10)
     ap.add_argument("--out", default=None)
@@ -174,8 +169,7 @@ def train(args: argparse.Namespace):
                             max_delay_steps=args.max_delay,
                             delay_distribution=args.delay_dist,
                             delay_median_s=300.0, seed=args.seed,
-                            sampler_mesh=args.sampler_mesh,
-                            paged_attn_impl=args.paged_attn_impl)
+                            sampler_mesh=args.sampler_mesh)
         rt = HeteroRuntime(cfg, rl, tc, hcfg, task, tok, state,
                            prompts_per_batch=args.prompts,
                            eval_fn=eval_fn, eval_every=args.eval_every)

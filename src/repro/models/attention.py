@@ -274,69 +274,6 @@ def chunked_attention(q, k, v, *, pos_q, pos_k, kind="causal", window=4096,
                   min(q_chunk, q.shape[1]), min(kv_chunk, k.shape[1]))
 
 
-def _chunked_attention_legacy(q, k, v, *, pos_q, pos_k, kind="causal",
-                              window=4096, softcap=None, q_chunk=512,
-                              kv_chunk=512):
-    """Flash-style attention: outer scan over query blocks, inner scan over
-    kv blocks with online-softmax accumulators. Peak live memory is
-    O(q_chunk * kv_chunk) scores instead of O(Sq * Sk)."""
-    b, sq, hq, d = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
-    g, r = hkv, hq // hkv
-    q_chunk = min(q_chunk, sq)
-    kv_chunk = min(kv_chunk, sk)
-    sq_orig = sq
-    pad_q = (-sq) % q_chunk
-    pad_k = (-sk) % kv_chunk
-    if pad_q:
-        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
-        pos_q = jnp.pad(pos_q, ((0, 0), (0, pad_q)))
-        sq += pad_q
-    if pad_k:
-        k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-        pos_k = jnp.pad(pos_k, ((0, 0), (0, pad_k)),
-                        constant_values=PAD_POS)
-        sk += pad_k
-    nq, nk = sq // q_chunk, sk // kv_chunk
-
-    qb = q.reshape(b, nq, q_chunk, g, r, d).transpose(1, 0, 2, 3, 4, 5)
-    pqb = pos_q.reshape(b, nq, q_chunk).transpose(1, 0, 2)
-    kb = k.reshape(b, nk, kv_chunk, g, d).transpose(1, 0, 2, 3, 4)
-    vb = v.reshape(b, nk, kv_chunk, g, d).transpose(1, 0, 2, 3, 4)
-    pkb = pos_k.reshape(b, nk, kv_chunk).transpose(1, 0, 2)
-
-    def q_block(qi_pq):
-        qi, pq = qi_pq                                    # (B,qc,G,R,D), (B,qc)
-
-        def kv_block(carry, kv):
-            m_run, l_run, acc = carry
-            ki, vi, pk = kv
-            s = _scores(qi, ki, softcap)                  # (B,G,R,qc,kc)
-            msk = _mask(pq, pk, kind, window)[:, None, None]
-            s = jnp.where(msk, s, NEG_INF)
-            m_new = jnp.maximum(m_run, s.max(axis=-1))
-            scale = jnp.exp(m_run - m_new)
-            p = jnp.exp(s - m_new[..., None])
-            l_new = l_run * scale + p.sum(axis=-1)
-            acc = acc * scale[..., None] + jnp.einsum(
-                "bgrqk,bkgd->bgrqd", p, vi.astype(jnp.float32))
-            return (m_new, l_new, acc), None
-
-        qc = qi.shape[1]
-        m0 = jnp.full((b, g, r, qc), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((b, g, r, qc), jnp.float32)
-        a0 = jnp.zeros((b, g, r, qc, d), jnp.float32)
-        (m_f, l_f, acc), _ = jax.lax.scan(kv_block, (m0, l0, a0),
-                                          (kb, vb, pkb))
-        o = acc / jnp.maximum(l_f, 1e-30)[..., None]      # (B,G,R,qc,D)
-        return o.transpose(0, 3, 1, 2, 4)                 # (B,qc,G,R,D)
-
-    o = jax.lax.map(q_block, (qb, pqb))                   # (nq,B,qc,G,R,D)
-    o = o.transpose(1, 0, 2, 3, 4, 5).reshape(b, sq, hq, d)
-    return o[:, :sq_orig].astype(q.dtype)
-
-
 def decode_attention(q, k_cache, v_cache, *, pos, kind="causal",
                      window=4096, softcap=None, length=None):
     """Single-token attention against a (B, Smax, Hkv, D) cache.

@@ -43,7 +43,7 @@ def _project_qkv(cfg: ModelConfig, p: Dict, xq: jax.Array,
 def _self_attn(cfg: ModelConfig, p: Dict, x: jax.Array, *, kind: str,
                positions: jax.Array, cache: Optional[Dict], pos,
                bidir: bool = False, page_table: Optional[jax.Array] = None,
-               block=None, record: bool = False):
+               block=None):
     """Self-attention sub-layer body (input already normed).
 
     Returns (out, new_cache). In decode mode (pos is not None) x is
@@ -53,25 +53,10 @@ def _self_attn(cfg: ModelConfig, p: Dict, x: jax.Array, *, kind: str,
     decode go through the paged scatter/gather path instead: the pools
     are the whole stacked (num_blocks, P, Hkv, page, D) pools the block
     scan carries, and this layer owns slab ``block`` of them.
-
-    ``record=True`` (paged chunked-prefill path only — the speculative
-    verification forward) returns a third element: the post-rope queries
-    and the per-layer attention output, both (B, Sq, Hq, Dh), so the
-    caller can replay all layers' attention through one fused
-    ``paged_prefill_layers`` launch.
     """
     q, k, v = _project_qkv(cfg, p, x, x)
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
-    if cfg.attn_gather_qkv and cfg.act_sharding is not None and pos is None:
-        # §Perf H-A1 (kept for the record; REFUTED — GSPMD's own layout
-        # beat it 3.3× on collective bytes): gather the sequence here and
-        # run attention head-sharded.
-        dp = cfg.act_sharding[0]
-        spec = jax.sharding.PartitionSpec(dp, None, "model", None)
-        q = jax.lax.with_sharding_constraint(q, spec)
-        k = jax.lax.with_sharding_constraint(k, spec)
-        v = jax.lax.with_sharding_constraint(v, spec)
     mask_kind = ("bidir" if bidir else
                  "local" if kind == LOCAL else "causal")
 
@@ -121,9 +106,6 @@ def _self_attn(cfg: ModelConfig, p: Dict, x: jax.Array, *, kind: str,
                               softcap=cfg.attn_softcap,
                               impl=cfg.paged_attn_impl,
                               attn_impl=cfg.attn_impl, chunk=cfg.attn_chunk)
-            if record:
-                return (o.reshape(b, sq, -1) @ p["wo"], {"kp": kp, "vp": vp},
-                        {"q": q, "o": o})
         return o.reshape(b, sq, -1) @ p["wo"], {"kp": kp, "vp": vp}
 
     ring = (cfg.local_ring_kv and kind == LOCAL)
@@ -203,22 +185,17 @@ def _ffn(cfg: ModelConfig, kind: str, p: Dict, x: jax.Array,
 
 def _apply_layer(cfg: ModelConfig, idx_in_block: int, p: Dict, x: jax.Array,
                  *, positions, memory, cache, pos, aux,
-                 encoder: bool = False, page_table=None, block=None,
-                 record: bool = False):
+                 encoder: bool = False, page_table=None, block=None):
     kind = ATTN if encoder else cfg.block_pattern[idx_in_block]
     ffn_kind = MLP if encoder else cfg.ffn_kind(idx_in_block)
     new_cache: Dict[str, Any] = {}
-    tape = None
 
     h = rmsnorm(x, p["norm"], cfg.norm_eps)
     if kind in (ATTN, LOCAL):
-        res = _self_attn(cfg, p["attn"], h, kind=kind, positions=positions,
-                         cache=None if cache is None else cache.get("self"),
-                         pos=pos, bidir=encoder, page_table=page_table,
-                         block=block, record=record)
-        o, c = res[0], res[1]
-        if record:
-            tape = res[2]
+        o, c = _self_attn(cfg, p["attn"], h, kind=kind, positions=positions,
+                          cache=None if cache is None else cache.get("self"),
+                          pos=pos, bidir=encoder, page_table=page_table,
+                          block=block)
         x = x + o
         if c is not None:
             new_cache["self"] = c
@@ -244,12 +221,6 @@ def _apply_layer(cfg: ModelConfig, idx_in_block: int, p: Dict, x: jax.Array,
         raise ValueError(kind)
 
     x, aux = _ffn(cfg, ffn_kind, p, x, aux)
-    if record:
-        if tape is None:
-            raise ValueError(
-                f"record_queries needs every layer on the paged attention "
-                f"path; layer kind {kind!r} is not")
-        return x, new_cache, aux, tape
     return x, new_cache, aux
 
 
@@ -282,7 +253,7 @@ def _is_paged(cache: Optional[Dict]) -> bool:
 
 def _run_blocks(cfg: ModelConfig, blocks: Dict, x: jax.Array, *,
                 positions, memory, cache, pos, encoder=False,
-                page_table=None, record=False):
+                page_table=None):
     """Scan super-blocks. cache (if given) is a pytree stacked on axis 0
     matching ``blocks``; returns (x, new_cache, aux).
 
@@ -290,12 +261,7 @@ def _run_blocks(cfg: ModelConfig, blocks: Dict, x: jax.Array, *,
     returns its own slice. A paged cache rides it whole in the carry and
     ``xs`` gives the block index: each layer scatters its K/V into, and
     attends, its slab of the stacked (nb, P, Hkv, page, D) pools in
-    place, so no block copies a whole layer's pool in or out.
-
-    With ``record`` (paged-prefill path only) aux additionally carries
-    ``q_tape`` / ``o_tape`` — per-layer post-rope queries and attention
-    outputs, (L, B, S, Hq, Dh) with L enumerated block-major (the same
-    order ``kernels.ops._fold_layers`` folds pool leaves)."""
+    place, so no block copies a whole layer's pool in or out."""
     aux0 = {} if encoder else _aux_init(cfg)
     paged = _is_paged(cache)
 
@@ -305,17 +271,13 @@ def _run_blocks(cfg: ModelConfig, blocks: Dict, x: jax.Array, *,
         bp, bx = xs
         bc, block = (pools, bx) if paged else (bx, None)
         new_bc = {}
-        tapes = []
         for i in range(len(cfg.block_pattern)):
             key = f"layer_{i}"
             lc = None if bc is None else bc.get(key)
-            out = _apply_layer(cfg, i, bp[key], x, positions=positions,
-                               memory=memory, cache=lc, pos=pos,
-                               aux=aux, page_table=page_table, block=block,
-                               record=record)
-            x, nc, aux = out[0], out[1], out[2]
-            if record:
-                tapes.append(out[3])
+            x, nc, aux = _apply_layer(cfg, i, bp[key], x, positions=positions,
+                                      memory=memory, cache=lc, pos=pos,
+                                      aux=aux, page_table=page_table,
+                                      block=block)
             if bc is not None:
                 new_bc[key] = nc
         ys = 0
@@ -323,11 +285,6 @@ def _run_blocks(cfg: ModelConfig, blocks: Dict, x: jax.Array, *,
             pools = new_bc
         elif bc is not None:
             ys = new_bc
-        if record:
-            # stack the period's layers -> (P, B, S, Hq, Dh); the scan
-            # stacks blocks in front -> (nb, P, ...)
-            ys = (ys, {k: jnp.stack([t[k] for t in tapes])
-                       for k in ("q", "o")})
         return (x, aux, pools), ys
 
     if encoder:
@@ -349,11 +306,6 @@ def _run_blocks(cfg: ModelConfig, blocks: Dict, x: jax.Array, *,
     else:
         carry, xs = (x, aux0, None), (blocks, cache)
     (x, aux, pools), ys = jax.lax.scan(fn, carry, xs)
-    if record:
-        ys, tape = ys
-        for k, name in (("q", "q_tape"), ("o", "o_tape")):
-            t = tape[k]                      # (nb, P, B, S, Hq, Dh)
-            aux[name] = t.reshape((-1,) + t.shape[2:])
     if cache is None:
         return x, None, aux
     return x, (pools if paged else ys), aux
@@ -402,7 +354,6 @@ def forward(cfg: ModelConfig, params: Dict, tokens: jax.Array, *,
             positions: Optional[jax.Array] = None,
             cache: Optional[Dict] = None,
             page_table: Optional[jax.Array] = None,
-            record_queries: bool = False,
             ) -> Tuple[jax.Array, Optional[Dict], Dict]:
     """Full-sequence forward (training / prefill).
 
@@ -411,12 +362,6 @@ def forward(cfg: ModelConfig, params: Dict, tokens: jax.Array, *,
     cache (page pools from ``repro.sampling.paged_cache``) additionally
     needs ``page_table`` (B, pages_per_slot) and explicit ``positions``
     for chunked prefill at an offset.
-
-    ``record_queries`` (paged-cache forwards only) adds ``q_tape`` /
-    ``o_tape`` — per-layer post-rope queries and per-layer attention
-    outputs, (L, B, S, Hq, Dh) — to the returned aux dict, so a
-    speculative verifier can rescore acceptance through one
-    ``paged_prefill_layers`` launch instead of L.
     """
     b, s = tokens.shape
     if positions is None:
@@ -425,8 +370,7 @@ def forward(cfg: ModelConfig, params: Dict, tokens: jax.Array, *,
     x, new_cache, aux = _run_blocks(cfg, params["blocks"], x,
                                     positions=positions, memory=memory,
                                     cache=cache, pos=None,
-                                    page_table=page_table,
-                                    record=record_queries)
+                                    page_table=page_table)
     return _logits(cfg, params, x), new_cache, aux
 
 

@@ -43,8 +43,7 @@ from repro.sampling.prefix_cache import PrefixCache
 from repro.sampling.sample import mask_vocab, model_logp, sample_token_rows
 from repro.sampling.scheduler import (DECODE, PREFILL, ContinuousScheduler,
                                       GenRequest)
-from repro.sampling.spec import (DraftProposer, NGramDrafter, accept_drafts,
-                                 fused_rescore_diff)
+from repro.sampling.spec import DraftProposer, NGramDrafter, accept_drafts
 from repro.serving.api import (GenerationResult, Request, SamplingParams,
                                TokenEvent)
 
@@ -130,11 +129,11 @@ def _decode_chunk_jit(cfg: ModelConfig, rl: RLConfig, params, pool,
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "rl", "vocab_limit",
-                                             "fused", "plan"),
+                                             "plan"),
                    donate_argnums=(3,))
 def _verify_chunk_jit(cfg: ModelConfig, rl: RLConfig, params, pool,
                       page_table, packed, req_keys, max_new_v,
-                      vocab_limit: int, fused: bool, plan=None):
+                      vocab_limit: int, plan=None):
     """One speculative round over every slot in one executable: score the
     per-slot window ``[pending, d_1..d_k, pad]`` in ONE prefill-shaped
     target forward through the ``paged_prefill`` dispatcher (positions
@@ -156,42 +155,30 @@ def _verify_chunk_jit(cfg: ModelConfig, rl: RLConfig, params, pool,
     them (the append-only rollback: rewinding positions, no page
     copies). Inactive slots run at positions ``[0, W)`` (pos0 = 0)
     against the scratch page, the prefill-shaped twin of the decode
-    chunk's dead slots. With ``fused`` the forward also records
-    per-layer queries and attention outputs, and the acceptance rescore
-    replays all layers through one ``paged_prefill_layers`` launch — the
-    fused-layer kernels' consumer — returning max |fused − in-forward|
-    as a bit-exactness gauge.
+    chunk's dead slots.
 
     Returns (iout (B, W+2) int32 = [toks(W), n_emit, n_acc],
-    fout (B, W+1) f32 = [lps(W), rescore_diff], pool) — two packed
-    device->host transfers on the result side for the same reason.
+    lps (B, W) f32, pool) — two device->host transfers on the result
+    side for the same reason.
     """
     if plan is not None:
         params = plan.constrain_params(cfg, params)
         pool = plan.constrain_cache(cfg, pool)
-    b = packed.shape[0]
     w = packed.shape[1] - 4
     window_tokens = packed[:, :w]
     draft_len, gen_base, pos0 = packed[:, w], packed[:, w + 1], \
         packed[:, w + 2]
     active = packed[:, w + 3].astype(bool)
     positions = pos0[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
-    logits, pool, aux = forward(cfg, params, window_tokens,
-                                positions=positions, cache=pool,
-                                page_table=page_table,
-                                record_queries=fused)
+    logits, pool, _ = forward(cfg, params, window_tokens,
+                              positions=positions, cache=pool,
+                              page_table=page_table)
     toks, lps, n_emit, n_acc = accept_drafts(
         logits, window_tokens, draft_len, active, req_keys, gen_base,
         max_new_v, temperature=rl.temperature, top_k=rl.top_k,
         top_p=rl.top_p, vocab_limit=vocab_limit)
-    diff = jnp.float32(0.0)
-    if fused:
-        diff = fused_rescore_diff(cfg, pool, aux["q_tape"], aux["o_tape"],
-                                  page_table, positions)
     iout = jnp.concatenate([toks, n_emit[:, None], n_acc[:, None]], axis=1)
-    fout = jnp.concatenate([lps, jnp.full((b, 1), diff, jnp.float32)],
-                           axis=1)
-    return iout, fout, pool
+    return iout, lps, pool
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "rl", "vocab_limit",
@@ -304,7 +291,6 @@ class ContinuousEngine:
                  drafter: Optional[DraftProposer] = None,
                  spec_ngram_max: int = 3,
                  spec_ngram_min: int = 1,
-                 spec_rescore: bool = True,
                  key: Optional[jax.Array] = None) -> None:
         if not paged_cache_supported(cfg):
             raise ValueError(f"{cfg.name}: continuous engine needs an "
@@ -338,7 +324,6 @@ class ContinuousEngine:
         if spec_k < 0:
             raise ValueError(f"spec_k must be >= 0, got {spec_k}")
         self.spec_k = spec_k
-        self.spec_rescore = spec_rescore
         self.drafter: Optional[DraftProposer] = drafter
         if spec_k > 0 and self.drafter is None:
             self.drafter = NGramDrafter(max_ngram=spec_ngram_max,
@@ -387,10 +372,6 @@ class ContinuousEngine:
         self._g_draft_hit = m.gauge(
             "engine_spec_draft_hit_rate",
             "slot-rounds where the drafter proposed anything (cumulative)")
-        self._g_rescore_diff = m.gauge(
-            "engine_spec_rescore_max_diff",
-            "max |fused-layers rescore - in-forward attention| last round")
-        self._rescore_max_diff = 0.0
         # per-request acceptance EMA ([ema, rounds_since_draft]) — gates
         # drafting off on incompressible streams (periodic re-probe)
         self._spec_ema: Dict[int, List[float]] = {}
@@ -434,7 +415,6 @@ class ContinuousEngine:
                               / max(out["drafted_tokens_total"], 1))
         out["draft_hit_rate"] = (out["draft_hits"]
                                  / max(out["spec_slot_rounds"], 1))
-        out["spec_rescore_max_diff"] = self._rescore_max_diff
         if self.prefix_cache is not None:
             for k, v in self.prefix_cache.stats.items():
                 out[f"prefix_cache_{k}"] = v
@@ -765,7 +745,7 @@ class ContinuousEngine:
             self._spec_fallback_chunk(dec, now, events, per_slot)
             return
         # pow2-bucketed verification width (floor 2 keeps the window on
-        # the prefill-shaped recording path) — O(log spec_k) executables.
+        # the prefill-shaped attention path) — O(log spec_k) executables.
         # Everything that varies per round rides ONE packed int32 array:
         # [window(W), draft_len, gen_base, pos0, active] per row.
         w = max(2, _live_width(1 + max_k, self.spec_k + 1))
@@ -787,36 +767,32 @@ class ContinuousEngine:
                            window=w, width=width):
             bt = sched.block_table[:, :width].copy()
             bt[~self._active] = SCRATCH_PAGE
-            iout, fout, self.pool = _verify_chunk_jit(
+            iout, lps, self.pool = _verify_chunk_jit(
                 self.cfg, self.rl, self.params, self.pool,
                 self._dev("bt.verify", bt), jnp.asarray(packed),
                 self._dev("req_keys", self._req_keys),
                 self._dev("max_new", self._max_new),
-                self.vocab_limit, self.spec_rescore, plan=self.plan)
+                self.vocab_limit, plan=self.plan)
         sched.stats["decode_steps"] += 1
         sched.stats["spec_rounds"] += 1
         self._m_decode_steps.inc(1)
-        # two deliberate syncs per verify round (packed int/f32 results),
+        # two deliberate syncs per verify round (packed ints, log-probs),
         # the decode chunk's twin
         with self._tr.span("sync", track="engine"):
             io = np.asarray(iout)                          # noqa: RA003
-            fo = np.asarray(fout)                          # noqa: RA003
+            lp_np = np.asarray(lps)                        # noqa: RA003
         with self._tr.span("commit", track="engine"):
-            self._commit_verify(dec, per_slot, io, fo, w, now, events)
+            self._commit_verify(dec, per_slot, io, lp_np, w, now, events)
             self._publish_gauges()
 
     def _commit_verify(self, dec: List[GenRequest],
                        per_slot: Dict[int, tuple], io: np.ndarray,
-                       fo: np.ndarray, w: int, now: float,
+                       lp_np: np.ndarray, w: int, now: float,
                        events: List[TokenEvent]) -> None:
         """Commit a verify round's emitted tokens per slot and update the
         drafting gate and the acceptance counters."""
         sched = self.sched
         tok_np, ne, na = io[:, :w], io[:, w], io[:, w + 1]
-        lp_np = fo[:, :w]
-        if self.spec_rescore:
-            self._rescore_max_diff = max(self._rescore_max_diff,
-                                         float(fo[0, w]))
         drafted = accepted = hits = 0
         for r in dec:
             s = r.slot
@@ -852,7 +828,6 @@ class ContinuousEngine:
                                     / max(st["drafted_tokens_total"], 1))
             self._g_draft_hit.set(st["draft_hits"]
                                   / max(st["spec_slot_rounds"], 1))
-            self._g_rescore_diff.set(self._rescore_max_diff)
 
     def _spec_fallback_chunk(self, dec: List[GenRequest], now: float,
                              events: List[TokenEvent],

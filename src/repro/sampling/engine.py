@@ -29,7 +29,6 @@ the vLLM/FSDP log-prob mismatch.
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 import time
 import warnings
@@ -187,8 +186,6 @@ def build_engine(cfg: ModelConfig, params, serve: ServeConfig, *,
     cache can't serve and for encoder/memory models.
     """
     rl = rl or RLConfig(engine=serve.engine)
-    if serve.paged_attn_impl:
-        cfg = dataclasses.replace(cfg, paged_attn_impl=serve.paged_attn_impl)
     if serve.engine == "continuous":
         if memory is None and paged_cache_supported(cfg):
             return ContinuousEngine(
@@ -203,8 +200,7 @@ def build_engine(cfg: ModelConfig, params, serve: ServeConfig, *,
                 prefix_cache_entries=serve.prefix_cache_entries,
                 spec_k=serve.spec_k,
                 spec_ngram_max=serve.spec_ngram_max,
-                spec_ngram_min=serve.spec_ngram_min,
-                spec_rescore=serve.spec_rescore, key=key)
+                spec_ngram_min=serve.spec_ngram_min, key=key)
         warnings.warn(f"{cfg.name}: continuous engine unsupported for this "
                       "architecture/memory setup; serving static",
                       stacklevel=2)

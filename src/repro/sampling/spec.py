@@ -18,15 +18,6 @@ target model:
   incompressible ones.
 - :func:`accept_drafts` — the in-jit acceptance rule, shared by the
   engine's verification executable and the tests.
-- :func:`fused_rescore_diff` — the acceptance *rescore* through ONE
-  ``paged_prefill_layers`` launch instead of L per-layer launches (the
-  fused-layer kernels' first real consumer): replay every layer's
-  window attention from the recorded per-layer queries against the
-  freshly-scattered pools and report the max abs deviation from the
-  in-forward outputs. Bit-exactness is the invariant (same operands,
-  row-independent math); a nonzero value means the folded launch and
-  the scan disagree — a kernel regression surfaced at serve time on a
-  gauge instead of in a post-mortem.
 
 Acceptance rule (exact replay)
 ------------------------------
@@ -54,7 +45,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.config import LOCAL, ModelConfig
 from repro.data.tasks import EOS, PAD
 from repro.sampling.sample import mask_vocab, model_logp, sample_token_rows
 
@@ -182,55 +172,13 @@ def accept_drafts(logits: jax.Array, window_tokens: jax.Array,
     return toks, lps, n_emit, n_acc
 
 
-def stacked_pools(cfg: ModelConfig, pool) -> Tuple[jax.Array, jax.Array]:
-    """Assemble the (L, pages, Hkv, page, D) stacked-pool layout
-    ``paged_*_layers`` folds, from the engine pool's scanned-block
-    layout (per-pattern-position ``layer_{i}`` leaves each stacked on
-    the super-block axis). Layer order is block-major — exactly the
-    order ``_run_blocks`` records its q/o tapes in."""
-    period = len(cfg.block_pattern)
-    kp = jnp.stack([pool[f"layer_{i}"]["self"]["kp"] for i in range(period)],
-                   axis=1)
-    vp = jnp.stack([pool[f"layer_{i}"]["self"]["vp"] for i in range(period)],
-                   axis=1)
-    return (kp.reshape((-1,) + kp.shape[2:]),
-            vp.reshape((-1,) + vp.shape[2:]))
-
-
-def fused_rescore_diff(cfg: ModelConfig, pool, q_tape: jax.Array,
-                       o_tape: jax.Array, page_table: jax.Array,
-                       positions: jax.Array) -> jax.Array:
-    """Rescore every layer's window attention through ONE
-    ``paged_prefill_layers`` launch per mask kind (one, for uniform
-    patterns) and return max |fused − in-forward| — the fused-layer
-    kernels' consumer on the verification path. Layers sharing a mask
-    kind fold together; mixed ATTN/LOCAL patterns take one launch per
-    kind, still O(kinds) ≪ L."""
-    from repro.kernels.ops import paged_prefill_layers
-    kp, vp = stacked_pools(cfg, pool)
-    period = len(cfg.block_pattern)
-    kinds = [cfg.block_pattern[i % period] for i in range(kp.shape[0])]
-    diff = jnp.float32(0.0)
-    for kind in dict.fromkeys(kinds):
-        idx = jnp.asarray([i for i, k in enumerate(kinds) if k == kind],
-                          jnp.int32)
-        o = paged_prefill_layers(
-            q_tape[idx], kp[idx], vp[idx], page_table, positions,
-            kind=("local" if kind == LOCAL else "causal"),
-            window=cfg.sliding_window, softcap=cfg.attn_softcap,
-            impl=cfg.paged_attn_impl, attn_impl=cfg.attn_impl,
-            chunk=cfg.attn_chunk)
-        diff = jnp.maximum(diff, jnp.max(jnp.abs(o - o_tape[idx])))
-    return diff
-
-
 def verify_width_buckets(spec_k: int) -> int:
     """Distinct verification-window widths the engine can hand the
     jitted verify fn for a draft cap of ``spec_k``: widths are
     max(2, min(next_pow2(1 + k), spec_k + 1)) for k in 0..spec_k — the
     pow2 bucketing that keeps verify executables O(log spec_k). The
-    floor of 2 keeps the window on the prefill-shaped (query-recording)
-    attention path even when nothing was drafted."""
+    floor of 2 keeps the window on the prefill-shaped attention path
+    even when nothing was drafted."""
     widths = set()
     for k in range(spec_k + 1):
         w = 1
@@ -241,4 +189,4 @@ def verify_width_buckets(spec_k: int) -> int:
 
 
 __all__ = ["DraftProposer", "NGramDrafter", "accept_drafts",
-           "stacked_pools", "fused_rescore_diff", "verify_width_buckets"]
+           "verify_width_buckets"]

@@ -1,5 +1,7 @@
 """HeteroRL runtime: latency distributions, event-sim determinism,
 staleness-window enforcement, online synchrony."""
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -172,9 +174,10 @@ class TestSamplerTelemetry:
         assert s.tokens_per_s == s.tokens_generated / s.gen_seconds
 
     def test_paged_attn_impl_threads_into_cfg(self):
-        """The hetero A/B lever: HeteroConfig.paged_attn_impl (or the
-        explicit arg, which wins) rewrites the sampler's ModelConfig so
-        its engine dispatches the chosen paged-decode backend."""
+        """The paged-attention backend has one setting,
+        ``ModelConfig.paged_attn_impl``: a sampler node keeps the
+        config's choice as given, so its engine dispatches that
+        backend."""
         from repro.data import PromptPipeline
         from repro.hetero.nodes import SamplerNode
         task = ArithmeticTask(max_operand=9, ops="+", prompt_width=5,
@@ -182,18 +185,15 @@ class TestSamplerTelemetry:
         tok = Tokenizer()
         params = init_params(TINY, jax.random.PRNGKey(0))
 
-        def node(hcfg, **kw):
-            return SamplerNode(0, TINY, RL,
+        def node(cfg):
+            return SamplerNode(0, cfg, RL,
                                PromptPipeline(task, tok, 4, RL.group_size),
-                               task, tok, params, PolicyStore(), hcfg,
-                               seed=0, **kw)
+                               task, tok, params, PolicyStore(),
+                               HeteroConfig(), seed=0)
 
-        assert node(HeteroConfig()).cfg.paged_attn_impl == "auto"
-        s = node(HeteroConfig(paged_attn_impl="ref"))
-        assert s.cfg.paged_attn_impl == "ref"
-        s = node(HeteroConfig(paged_attn_impl="ref"),
-                 paged_attn_impl="pallas")
-        assert s.cfg.paged_attn_impl == "pallas"
+        assert node(TINY).cfg.paged_attn_impl == "auto"
+        ref = dataclasses.replace(TINY, paged_attn_impl="ref")
+        assert node(ref).cfg.paged_attn_impl == "ref"
 
 
 class TestCheckpoint:

@@ -11,8 +11,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ATTN, LOCAL, MLP, ModelConfig, RLConfig
-from repro.kernels.ops import (paged_decode, paged_decode_layers,
-                               paged_prefill, paged_prefill_layers)
+from repro.kernels.ops import layer_slab, paged_decode, paged_prefill
 from repro.kernels.paged_attention import paged_attention
 from repro.models import init_params
 from repro.sampling import generate, generate_continuous
@@ -395,67 +394,35 @@ class TestPrefillDispatcher:
         assert temp_bytes("ref") * 4 < temp_bytes("gather")
 
 
-class TestFusedLayers:
-    """One launch for all layers' pools: the folded (L→slot axis) call
-    must be bit-exact vs per-layer calls and issue exactly one
-    pallas_call."""
+class TestLayerSlab:
+    """The block scan attends layer ``l`` of the stacked (L, P, Hkv,
+    page, D) pools in place through ``layer_slab`` (pools folded to
+    (L·P, ...), table offset by l·P): every backend must return, bit for
+    bit, what it returns on that layer's own pool."""
 
-    def _stacked(self, lyr=3, seed=43):
-        qs, kps, vps = [], [], []
-        for l in range(lyr):
-            q, kp, vp, table, positions = make_prefill_case(
-                seed=seed + 7 * l, starts=[2, 0, 11])
-            qs.append(q), kps.append(kp), vps.append(vp)
-        return (jnp.stack(qs), jnp.stack(kps), jnp.stack(vps), table,
-                positions)
+    LAYERS = 3
 
+    @pytest.mark.parametrize("program", ["decode", "prefill"])
     @pytest.mark.parametrize("impl", ["gather", "ref", "pallas"])
-    def test_prefill_fused_bitexact(self, impl):
-        q, kp, vp, table, positions = self._stacked()
-        per = jnp.stack([paged_prefill(q[l], kp[l], vp[l], table, positions,
-                                       impl=impl, interpret=True)
-                         for l in range(q.shape[0])])
-        fused = paged_prefill_layers(q, kp, vp, table, positions,
-                                     impl=impl, interpret=True)
-        np.testing.assert_array_equal(
-            np.asarray(fused), np.asarray(per))  # noqa: RA003 — test sync
-
-    @pytest.mark.parametrize("impl", ["gather", "ref", "pallas"])
-    def test_decode_fused_bitexact(self, impl):
-        q, kp, vp, table, positions = self._stacked()
-        qd = q[:, :, :1]                                # (L, B, 1, Hq, D)
+    def test_slab_matches_own_pool(self, impl, program):
+        cases = [make_prefill_case(seed=43 + 7 * l, starts=[2, 0, 11])
+                 for l in range(self.LAYERS)]
+        table, positions = cases[0][3], cases[0][4]
+        kp = jnp.stack([c[1] for c in cases])
+        vp = jnp.stack([c[2] for c in cases])
         lengths = positions[:, -1] + 1
-        per = jnp.stack([paged_decode(qd[l], kp[l], vp[l], table, lengths,
-                                      impl=impl, interpret=True)
-                         for l in range(q.shape[0])])
-        fused = paged_decode_layers(qd, kp, vp, table, lengths,
-                                    impl=impl, interpret=True)
-        np.testing.assert_array_equal(
-            np.asarray(fused), np.asarray(per))  # noqa: RA003 — test sync
+        for l, (q, kp_l, vp_l, _, _) in enumerate(cases):
+            def attend(k, v, t):
+                if program == "decode":
+                    return paged_decode(q[:, :1], k, v, t, lengths,
+                                        impl=impl, interpret=True)
+                return paged_prefill(q, k, v, t, positions, impl=impl,
+                                     interpret=True)
 
-    def test_single_pallas_launch(self, monkeypatch):
-        import repro.kernels.ops as ops_mod
-        import repro.kernels.paged_attention as pa
-        q, kp, vp, table, positions = self._stacked()
-        lengths = positions[:, -1] + 1
-        calls = []
-        real = pa.pl.pallas_call
-
-        def counting(*a, **kw):
-            calls.append(1)
-            return real(*a, **kw)
-
-        monkeypatch.setattr(pa.pl, "pallas_call", counting)
-        lyr = q.shape[0]
-        qf, kpf, vpf, tbl, ln = ops_mod._fold_layers(
-            q[:, :, :1], kp, vp, table, lengths)
-        pa.paged_attention(qf[:, 0], kpf, vpf, tbl, ln, interpret=True)
-        assert len(calls) == 1                  # ONE launch for L layers
-        calls.clear()
-        for l in range(lyr):
-            pa.paged_attention(q[l, :, 0], kp[l], vp[l], table, lengths,
-                               interpret=True)
-        assert len(calls) == lyr
+            slab = attend(*layer_slab(kp, vp, table, jnp.int32(l)))
+            own = attend(kp_l, vp_l, table)
+            np.testing.assert_array_equal(
+                np.asarray(slab), np.asarray(own))  # noqa: RA003 — test sync
 
 
 TINY = ModelConfig(name="tiny-paged", family="dense", num_layers=2,
@@ -728,7 +695,7 @@ class TestTensorParallel:
 
     def test_serve_plan_ref_backend(self):
         """The GSPMD-native ref backend under a real 1x2 serve plan —
-        what `serve --mesh 1x4 --paged-attn-impl ref` runs."""
+        what a sharded serve mesh runs for a config that sets "ref"."""
         from repro.parallel import ExecutionPlan, make_debug_mesh
         plan = ExecutionPlan(mesh=make_debug_mesh(1, 2), mode="serve")
         cfg = dataclasses.replace(TINY, paged_attn_impl="ref")

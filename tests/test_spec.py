@@ -1,11 +1,10 @@
-"""Speculative decoding: exact-replay acceptance, drafter, fused rescore.
+"""Speculative decoding: exact-replay acceptance and the drafter.
 
-The contracts under test, in ISSUE order: greedy spec decode is
-bit-identical to the non-speculative engine; every reported logp is the
-*target* model's logp of the emitted token (GEPO App. B.1 — never the
-drafter's); rollback leaves the page pool balanced (append-only rewind,
-no allocator traffic); and the verification path really consumes the
-fused-layer kernels (``paged_prefill_layers`` launch-counted).
+The contracts under test: greedy spec decode is bit-identical to the
+non-speculative engine; every reported logp is the *target* model's
+logp of the emitted token (GEPO App. B.1 — never the drafter's); and
+rollback leaves the page pool balanced (append-only rewind, no
+allocator traffic).
 """
 import jax
 import jax.numpy as jnp
@@ -40,12 +39,12 @@ GREEDY = dict(temperature=1.0, top_k=1, top_p=1.0)
 
 
 def _run(cfg, params, rl, prompts, *, spec_k, key, max_new=12,
-         prefix_cache=True, spec_rescore=True, spec=True, sync_every=4):
+         prefix_cache=True, spec=True, sync_every=4):
     serve = ServeConfig(engine="continuous", num_slots=3, page_size=4,
                         sync_every=sync_every, prefix_cache=prefix_cache,
                         max_total_tokens=max(len(p) for p in prompts)
                         + max_new,
-                        spec_k=spec_k, spec_rescore=spec_rescore, seed=0)
+                        spec_k=spec_k, seed=0)
     eng = build_engine(cfg, params, serve, rl=rl,
                        vocab_limit=cfg.vocab_size, key=key)
     sp = SamplingParams.from_rl(rl)
@@ -283,56 +282,6 @@ class TestRollbackAndPool:
         held = len({pg for ent in eng.prefix_cache._entries.values()
                     for pg in ent.pages})
         assert eng.free_pages + held == eng.num_pages - 1
-
-
-class TestFusedRescore:
-    def test_verify_path_uses_fused_layers_launch(self, monkeypatch):
-        """The acceptance rescore must route through ONE
-        ``paged_prefill_layers`` launch (the fused-layer kernels'
-        consumer), and — same operands, row-independent math — agree
-        bit-exactly with the in-forward attention outputs."""
-        import repro.kernels.ops as ops
-        from repro.sampling.continuous import _verify_chunk_jit
-        calls = []
-        real = ops.paged_prefill_layers
-
-        def counted(q, kp, vp, *a, **kw):
-            calls.append(int(q.shape[0]))          # layers folded per launch
-            return real(q, kp, vp, *a, **kw)
-
-        monkeypatch.setattr(ops, "paged_prefill_layers", counted)
-        # the launch is only observable at trace time — drop executables
-        # warmed by earlier tests so this engine traces fresh regardless
-        # of suite order
-        _verify_chunk_jit.clear_cache()
-        key = jax.random.PRNGKey(6)
-        params = init_params(TINY, key)
-        rl = RLConfig(max_new_tokens=10, engine="continuous", **GREEDY)
-        prompts = _prompts(np.random.default_rng(6))
-        eng, _ = _run(TINY, params, rl, prompts, spec_k=4, key=key)
-        st = eng.stats()
-        assert st["spec_rounds"] > 0
-        # traced at least once (per verify-width executable), all L
-        # layers folded into each single launch
-        assert calls and all(n == TINY.num_layers for n in calls)
-        assert st["spec_rescore_max_diff"] == 0.0
-
-    def test_rescore_off_skips_launch(self, monkeypatch):
-        import repro.kernels.ops as ops
-        from repro.sampling.continuous import _verify_chunk_jit
-        calls = []
-        real = ops.paged_prefill_layers
-        monkeypatch.setattr(
-            ops, "paged_prefill_layers",
-            lambda *a, **kw: calls.append(1) or real(*a, **kw))
-        _verify_chunk_jit.clear_cache()   # force fresh fused=False traces
-        key = jax.random.PRNGKey(7)
-        params = init_params(TINY, key)
-        rl = RLConfig(max_new_tokens=6, engine="continuous", **GREEDY)
-        eng, _ = _run(TINY, params, rl,
-                      _prompts(np.random.default_rng(7), n=3),
-                      spec_k=4, key=key, spec_rescore=False)
-        assert eng.stats()["spec_rounds"] > 0 and not calls
 
 
 class TestBucketsAndConfig:
